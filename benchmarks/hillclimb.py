@@ -201,7 +201,7 @@ def conv_hillclimb(name: str, dataflows=("carry", "halo"), *,
     under the key ``ops.conv2d`` looks up for this layer's input.
     """
     from repro.core import autotune
-    from repro.core.conv_plan import STRIP_VMEM_BUDGET, ConvPlan
+    from repro.core.conv_plan import KERNEL_VMEM_BUDGET, ConvPlan
     from repro.core.roofline import conv_plan_roofline
     from repro.kernels.ops import kernel_input_shape
     layer = _conv_layer(name)
@@ -248,7 +248,7 @@ def conv_hillclimb(name: str, dataflows=("carry", "halo"), *,
                                 tile_cout=baseline.tile_cout,
                                 dataflow=baseline.dataflow,
                                 step_time_s=base_t,
-                                budget=STRIP_VMEM_BUDGET),
+                                budget=KERNEL_VMEM_BUDGET),
                   best=best, n_candidates=len(rows), sweep=rows)
     if write_cache and best is not None:
         key = autotune.make_key(x_shape, w_shape, stride=layer.stride,

@@ -46,6 +46,7 @@ from repro.core import (FusedGroupPlan, NetworkPlan, autotune,
                         compare_layer, guard, mobilenet_layers,
                         network_layers, scale_layers, vgg16_layers)
 from repro.core.roofline import conv_plan_roofline, network_roofline
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import layers
 from repro.models.base import init_params
 
@@ -225,6 +226,7 @@ def main() -> None:
                          "DESIGN.md §8) instead of one pallas_call per "
                          "layer; requires --net")
     args = ap.parse_args()
+    use_compile_cache()
     if args.fused and not args.net:
         raise SystemExit("--fused needs --net (the reduced-head demo "
                          "has no fusion plan)")

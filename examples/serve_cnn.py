@@ -47,6 +47,7 @@ from repro.core.conv_shard import ShardedConvPlan
 from repro.core.roofline import sharded_conv_roofline
 from repro.core.serving import Replica, ServingEngine, pow2_buckets, replay
 from repro.kernels import ops
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_conv_mesh
 from repro.models import layers
 from repro.models.base import init_params
@@ -58,7 +59,9 @@ CHANNELS = (8, 16)
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=1,
-                    help="force N host CPU devices (handled pre-import)")
+                    help="force N host CPU devices (handled pre-import) "
+                         "for the sharded path on a CPU-only machine; "
+                         "never on a TPU host, where it hides the chips")
     ap.add_argument("--data", type=int, default=1,
                     help="data-parallel shards (images over 'data')")
     ap.add_argument("--spatial", type=int, default=1,
@@ -81,6 +84,7 @@ def main() -> None:
                          "megakernels (DESIGN.md §8) instead of "
                          "per-layer plans")
     args = ap.parse_args()
+    use_compile_cache()
     if args.fused and not args.net:
         raise SystemExit("--fused needs --net (the small CNN serves the "
                          "sharded per-layer path)")

@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.core import autotune
 from repro.kernels import ops
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import layers
 from repro.models.base import init_params
 from repro.optim import AdamWConfig, adamw
@@ -96,12 +97,15 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--json", default=None, metavar="OUT.json")
     ap.add_argument("--devices", type=int, default=1,
-                    help="force N host CPU devices (handled pre-import)")
+                    help="force N host CPU devices (handled pre-import) "
+                         "for the sharded path on a CPU-only machine; "
+                         "never on a TPU host, where it hides the chips")
     ap.add_argument("--data", type=int, default=1,
                     help="data-parallel shards (images over 'data')")
     ap.add_argument("--spatial", type=int, default=1,
                     help="spatial shards (output H-strips over 'model')")
     args = ap.parse_args()
+    use_compile_cache()
     mesh = None
     if args.data * args.spatial > 1:
         from repro.launch.mesh import make_conv_mesh

@@ -27,7 +27,7 @@ from repro.core.dataflow import (  # noqa: F401
     TrimSliceSim, SliceStats, core_conv, reference_conv2d_valid,
 )
 from repro.core.tiling import (  # noqa: F401
-    subkernel_decomposition, plan_conv_tiles, ConvTilePlan,
+    subkernel_decomposition,
 )
 from repro.core.serving import (  # noqa: F401
     BucketGrid, QueueFull, Replica, ServingEngine, pow2_buckets, replay,

@@ -47,9 +47,9 @@ import os
 import time
 import warnings
 
-from repro.core.conv_plan import ConvPlan, input_grad_geometry
+from repro.core.conv_plan import (KERNEL_VMEM_BUDGET, ConvPlan,
+                                   input_grad_geometry)
 from repro.core.roofline import conv_plan_roofline, dtype_width
-from repro.core.tiling import VMEM_BYTES
 
 
 def _resolve_bytes(dtype_bytes, dtype: str) -> int:
@@ -280,10 +280,11 @@ def knobs_for(x_shape, w_shape, *, stride: int = 1, pad: int = 0,
                               tile_h=rec["tile_h"],
                               tile_cout=rec["tile_cout"],
                               dataflow=rec["dataflow"])
-        if plan.vmem_resident_bytes > VMEM_BYTES:
+        if plan.vmem_resident_bytes > KERNEL_VMEM_BUDGET:
             raise ValueError(
-                f"resident {plan.vmem_resident_bytes} > VMEM "
-                f"{VMEM_BYTES} (the tuner only writes feasible plans)")
+                f"resident {plan.vmem_resident_bytes} > VMEM budget "
+                f"{KERNEL_VMEM_BUDGET} (the tuner only writes feasible "
+                "plans)")
     except ValueError as e:
         _reject(key, f"knobs infeasible for current geometry: {e}", path)
         return None
@@ -321,10 +322,11 @@ def weight_grad_knobs_for(x_shape, w_shape, *, stride: int = 1,
                                           pad=pad, groups=groups,
                                           tile_go=rec["tile_go"],
                                           tile_cout=rec["tile_cout"])
-        if plan.vmem_resident_bytes > VMEM_BYTES:
+        if plan.vmem_resident_bytes > KERNEL_VMEM_BUDGET:
             raise ValueError(
-                f"resident {plan.vmem_resident_bytes} > VMEM "
-                f"{VMEM_BYTES} (the tuner only writes feasible plans)")
+                f"resident {plan.vmem_resident_bytes} > VMEM budget "
+                f"{KERNEL_VMEM_BUDGET} (the tuner only writes feasible "
+                "plans)")
     except ValueError as e:
         _reject(key, f"knobs infeasible for current geometry: {e}", path)
         return None
@@ -337,13 +339,15 @@ def weight_grad_knobs_for(x_shape, w_shape, *, stride: int = 1,
 
 def candidate_knobs(x_shape, w_shape, *, stride: int = 1, pad: int = 0,
                     groups: int = 1, dtype_bytes: int = 4,
-                    vmem_bytes: int = VMEM_BYTES) -> list[ConvPlan]:
+                    vmem_bytes: int = KERNEL_VMEM_BUDGET) -> list[ConvPlan]:
     """VMEM-feasible candidate plans over (tile_h, tile_cout, dataflow).
 
     Strip-height ticks cover powers of two plus the two structurally
     special points: the auto default and the full-height strip
     ``(h_out + delta) * stride`` that collapses the grid to one strip per
     (image, group) — zero carry/halo traffic and the fewest grid steps.
+    C_out ticks are whole 128-lane tiles or the whole per-group C_out:
+    the chip's compiler tiles no other block width.
     """
     base = ConvPlan.build(x_shape, w_shape, stride=stride, pad=pad,
                           groups=groups, dtype_bytes=dtype_bytes)
@@ -352,7 +356,7 @@ def candidate_knobs(x_shape, w_shape, *, stride: int = 1, pad: int = 0,
     h_ticks = sorted({t for t in (s, 2 * s, 4 * s, 8 * s, 16 * s, 32 * s,
                                   base.tile_h, full_h) if t <= full_h})
     cout_pg = base.cout_per_group
-    c_ticks = sorted({t for t in (32, 64, 128, 256, base.tile_cout,
+    c_ticks = sorted({t for t in (128, 256, base.tile_cout,
                                   cout_pg) if t <= cout_pg})
     plans = []
     for dataflow in DATAFLOWS:
@@ -473,7 +477,7 @@ def tune(x_shape, w_shape, *, stride: int = 1, pad: int = 0,
 def candidate_weight_grad_knobs(x_shape, w_shape, *, stride: int = 1,
                                 pad: int = 0, groups: int = 1,
                                 dtype_bytes: int = 4,
-                                vmem_bytes: int = VMEM_BYTES) -> list:
+                                vmem_bytes: int = KERNEL_VMEM_BUDGET) -> list:
     """VMEM-feasible ``WeightGradPlan`` candidates over
     (tile_go, tile_cout) — cotangent-strip ticks at powers of two plus
     the full-height strip, per-group C_out tiles as in the forward
@@ -484,7 +488,7 @@ def candidate_weight_grad_knobs(x_shape, w_shape, *, stride: int = 1,
     go_ticks = sorted({t for t in (1, 2, 4, 8, 16, 32, base.tile_go,
                                    base.h_out) if t <= base.h_out})
     cout_pg = base.cout_per_group
-    c_ticks = sorted({t for t in (32, 64, 128, base.tile_cout, cout_pg)
+    c_ticks = sorted({t for t in (128, base.tile_cout, cout_pg)
                       if t <= cout_pg})
     plans = []
     for tg in go_ticks:
@@ -577,7 +581,7 @@ def sharded_knobs_for(x_shape, w_shape, *, batch_shards: int = 1,
             tile_h=rec["tile_h"], tile_cout=rec["tile_cout"],
             dataflow=rec["dataflow"], batch_shards=batch_shards,
             spatial_shards=spatial_shards)
-        if plan.local_plan().vmem_resident_bytes > VMEM_BYTES:
+        if plan.local_plan().vmem_resident_bytes > KERNEL_VMEM_BUDGET:
             raise ValueError(
                 "per-shard resident bytes exceed VMEM "
                 "(the tuner only writes feasible plans)")

@@ -73,9 +73,48 @@ from dataclasses import dataclass
 
 from repro.core.roofline import dtype_width
 
-# Default budget for the auto-chosen input strip: half of a ~16 MiB VMEM
-# core, leaving headroom for the weight tile, accumulator and pipelining.
-STRIP_VMEM_BUDGET = 8 << 20
+# TPU v5e has 128 MiB of VMEM per TensorCore; a Mosaic kernel may use
+# its scoped limit, 16 MiB unless the kernel asks for more.  Every TrIM
+# kernel asks for KERNEL_VMEM_LIMIT (``CompilerParams(vmem_limit_bytes=
+# ...)``), and every plan that the default strip choice or the tuner
+# admits keeps its modeled resident set (``vmem_resident_bytes``) under
+# KERNEL_VMEM_BUDGET, leaving a quarter of the limit to Mosaic's internal
+# scratch and the temporaries the model does not itemize.
+KERNEL_VMEM_LIMIT = 32 << 20
+KERNEL_VMEM_BUDGET = KERNEL_VMEM_LIMIT * 3 // 4
+
+LANES = 128
+
+# An f32 matmul at full precision (the kernels' f32 taps) runs as bf16
+# passes over split operands; Mosaic keeps the splits and partial
+# products in VMEM.  Counted as this many f32 buffers of the wider
+# matmul operand — measured on the v5e compiler for the VGG-16 layers:
+# at most ~11 (C=64..128), fewer for C=512.
+F32_MATMUL_BUFFERS = 12
+
+
+def vmem_tile_bytes(shape, dtype_bytes: int) -> int:
+    """Bytes of one VMEM buffer of ``shape``: the last two dims padded to
+    the TPU's (sublane, lane) tile — 8 x 128 for 32-bit values, with
+    narrower dtypes packing 16 or 32 rows per sublane tile."""
+    *major, rows, lanes = shape
+    sub = 8 * max(4 // dtype_bytes, 1)
+    return (math.prod(major) * -(-rows // sub) * sub
+            * -(-lanes // LANES) * LANES * dtype_bytes)
+
+
+def _largest_fitting(lo: int, hi: int, fits) -> int:
+    """Largest ``m`` in ``[lo, hi]`` with ``fits(m)`` (monotone: true up
+    to some point, false after), or ``lo`` when none fits."""
+    if fits(hi):
+        return hi
+    while hi - lo > 1:
+        mid = (lo + hi) // 2
+        if fits(mid):
+            lo = mid
+        else:
+            hi = mid
+    return lo
 
 
 def resolve_dtype_bytes(dtype_bytes) -> int:
@@ -143,7 +182,7 @@ class ConvPlan:
     tile_h: int = 8            # strip height in *input* rows
     tile_cout: int = 128       # C_out tile per grid step (per group)
     dataflow: str = "carry"    # "carry" (shadow regs) | "halo" (over-fetch)
-    vmem_budget: int = STRIP_VMEM_BUDGET
+    vmem_budget: int = KERNEL_VMEM_BUDGET
 
     def __post_init__(self):
         if self.dataflow not in ("carry", "halo"):
@@ -179,12 +218,13 @@ class ConvPlan:
               groups: int = 1, dtype_bytes: int = 4,
               tile_h: int | None = None, tile_cout: int | None = None,
               dataflow: str = "carry",
-              vmem_budget: int = STRIP_VMEM_BUDGET) -> "ConvPlan":
+              vmem_budget: int = KERNEL_VMEM_BUDGET) -> "ConvPlan":
         """Plan from array shapes, auto-choosing tiles when not given.
 
         ``tile_cout`` defaults to an MXU-friendly 128 when it divides the
         per-group C_out, else the whole per-group C_out.  ``tile_h`` is the
-        largest stride multiple whose resident strip fits ``vmem_budget``.
+        largest stride multiple whose modeled resident set
+        (:attr:`vmem_resident_bytes`) fits ``vmem_budget``.
         """
         n, h, w, cin = x_shape
         kh, kw, cin_pg, cout = w_shape
@@ -197,23 +237,27 @@ class ConvPlan:
         cout_pg = cout // groups
         if tile_cout is None:
             tile_cout = min(cout_pg, 128 if cout_pg % 128 == 0 else cout_pg)
+
+        def plan(th: int) -> "ConvPlan":
+            return cls(n=n, h=h, w=w, cin=cin, cout=cout, kh=kh, kw=kw,
+                       stride=s, pad=pad, groups=groups,
+                       dtype_bytes=dtype_bytes, tile_h=th,
+                       tile_cout=tile_cout, dataflow=dataflow,
+                       vmem_budget=vmem_budget)
+
         if tile_h is None:
-            h_out = (h + 2 * pad - kh) // s + 1
-            wp_bytes = (w + 2 * pad + kh) * cin_pg * dtype_bytes
-            tile_h = max(s, min(h_out * s, vmem_budget // max(wp_bytes, 1)))
-            tile_h -= tile_h % s
-            tile_h = max(tile_h, s)
-        return cls(n=n, h=h, w=w, cin=cin, cout=cout, kh=kh, kw=kw,
-                   stride=s, pad=pad, groups=groups,
-                   dtype_bytes=dtype_bytes, tile_h=tile_h,
-                   tile_cout=tile_cout, dataflow=dataflow,
-                   vmem_budget=vmem_budget)
+            full = plan(s)
+            m = _largest_fitting(
+                1, full.h_out + full.delta,
+                lambda m: plan(m * s).vmem_resident_bytes <= vmem_budget)
+            tile_h = m * s
+        return plan(tile_h)
 
     @classmethod
     def from_layer(cls, layer, *, n: int = 1, dtype_bytes: int = 4,
                    tile_h: int | None = None, tile_cout: int | None = None,
                    dataflow: str = "carry",
-                   vmem_budget: int = STRIP_VMEM_BUDGET) -> "ConvPlan":
+                   vmem_budget: int = KERNEL_VMEM_BUDGET) -> "ConvPlan":
         """Plan from a ``core.model.ConvLayer`` description (duck-typed)."""
         groups = getattr(layer, "groups", 1)
         return cls.build(
@@ -230,7 +274,7 @@ class ConvPlan:
                          dtype_bytes: int = 4, tile_h: int | None = None,
                          tile_cout: int | None = None,
                          dataflow: str = "carry",
-                         vmem_budget: int = STRIP_VMEM_BUDGET
+                         vmem_budget: int = KERNEL_VMEM_BUDGET
                          ) -> "ConvPlan":
         """Plan for the *input-gradient* conv of a forward problem.
 
@@ -255,7 +299,7 @@ class ConvPlan:
                           dtype_bytes: int = 4,
                           tile_go: int | None = None,
                           tile_cout: int | None = None,
-                          vmem_budget: int = STRIP_VMEM_BUDGET
+                          vmem_budget: int = KERNEL_VMEM_BUDGET
                           ) -> "WeightGradPlan":
         """Plan for the *weight-gradient* conv of a forward problem.
 
@@ -275,17 +319,19 @@ class ConvPlan:
         cout_pg = cout // groups
         if tile_cout is None:
             tile_cout = cout_pg
+
+        def plan(tg: int) -> "WeightGradPlan":
+            return WeightGradPlan(
+                n=n, h=h, w=w, cin=cin, cout=cout, kh=kh, kw=kw,
+                stride=stride, pad=pad, groups=groups,
+                dtype_bytes=dtype_bytes, tile_go=min(tg, h_out),
+                tile_cout=min(tile_cout, cout_pg), vmem_budget=vmem_budget)
+
         if tile_go is None:
-            wp = w + 2 * pad
-            row_bytes = wp * cin_pg * dtype_bytes
-            tile_go = max(1, min(
-                h_out, (vmem_budget // max(row_bytes, 1) - kh)
-                // max(stride, 1) + 1))
-        return WeightGradPlan(
-            n=n, h=h, w=w, cin=cin, cout=cout, kh=kh, kw=kw,
-            stride=stride, pad=pad, groups=groups,
-            dtype_bytes=dtype_bytes, tile_go=min(tile_go, h_out),
-            tile_cout=min(tile_cout, cout_pg), vmem_budget=vmem_budget)
+            tile_go = _largest_fitting(
+                1, h_out,
+                lambda t: plan(t).vmem_resident_bytes <= vmem_budget)
+        return plan(tile_go)
 
     # -- problem geometry --------------------------------------------------
 
@@ -407,24 +453,38 @@ class ConvPlan:
 
     @property
     def vmem_resident_bytes(self) -> int:
-        """Resident set of one grid step (window + carry + weights + acc).
+        """Modeled VMEM of one grid step, as Mosaic allocates it.
 
-        ``"carry"``: a ``tile_h`` strip plus the K-1 carry scratch.
-        ``"halo"``: one overlapping window of ``tile_h + K - 1`` rows, no
-        scratch — same working set to within one row (the ``max(K-1, 1)``
-        floor of the scratch allocation).
+        Every buffer is padded to the (sublane, 128-lane) tile
+        (:func:`vmem_tile_bytes`).  The pipelined blocks — input strip
+        (``tile_h`` rows for ``"carry"``, ``tile_h + K-1`` for the
+        overlapping ``"halo"`` window), weight tile, output tile and the
+        bias / dequant-scale rows — are double-buffered.  On top: the
+        ``"carry"`` scratch of K-1 rows, the ``tile_h + K-1``-row window
+        value the taps slice, one tap's matmul operand and the f32 (or
+        int32) accumulator; for f32 operands the full-precision matmul's
+        split operands and partial products (:data:`F32_MATMUL_BUFFERS`).
+        The int8 route writes f32 outputs.
         """
         db = self.dtype_bytes
-        if self.dataflow == "halo":
-            window = (self.tile_h + self.kh - 1) * self.wp \
-                * self.cin_per_group * db
-        else:
-            strip = self.tile_h * self.wp * self.cin_per_group * db
-            carry = self.carry_shape[0] * self.wp * self.cin_per_group * db
-            window = strip + carry
-        wtile = self.kh * self.kw * self.cin_per_group * self.tile_cout * db
-        acc = self.th_out * self.w_out * self.tile_cout * 4   # fp32
-        return window + wtile + acc
+        cin, tc = self.cin_per_group, self.tile_cout
+        in_block = self.halo_in_block if self.dataflow == "halo" \
+            else self.in_block
+        rows_out = self.th_out * self.w_out
+        total = (
+            2 * vmem_tile_bytes(in_block[1:], db)
+            + 2 * vmem_tile_bytes(self.w_block, db)
+            + 2 * vmem_tile_bytes(self.out_block[1:], 4 if db == 1 else db)
+            + 2 * 2 * vmem_tile_bytes((1, tc), 4)
+            + vmem_tile_bytes((self.tile_h + self.kh - 1, self.wp, cin), db)
+            + vmem_tile_bytes((rows_out, cin), db)
+            + vmem_tile_bytes((rows_out, tc), 4))
+        if self.dataflow == "carry":
+            total += vmem_tile_bytes(self.carry_shape, db)
+        if db == 4:
+            total += F32_MATMUL_BUFFERS * vmem_tile_bytes(
+                (rows_out, max(cin, tc)), 4)
+        return total
 
     # -- arithmetic --------------------------------------------------------
 
@@ -584,7 +644,7 @@ class WeightGradPlan:
     dtype_bytes: int = 4
     tile_go: int = 8           # cotangent rows resident per grid step
     tile_cout: int = 128       # C_out tile per grid step (per group)
-    vmem_budget: int = STRIP_VMEM_BUDGET
+    vmem_budget: int = KERNEL_VMEM_BUDGET
 
     def __post_init__(self):
         if self.cin % self.groups or self.cout % self.groups:
@@ -675,7 +735,7 @@ class WeightGradPlan:
 
     @property
     def x_block(self) -> tuple[int, int, int, int]:
-        """Unblocked (element-offset) window: the strip's cotangent rows'
+        """Element-indexed (overlapping) window: the strip's cotangent rows'
         receptive field."""
         return (1, self.window_rows, self.wp, self.cin_per_group)
 
@@ -694,13 +754,25 @@ class WeightGradPlan:
 
     @property
     def vmem_resident_bytes(self) -> int:
-        """Resident set of one grid step: ifmap window + cotangent strip
-        + the fp32 weight-shaped accumulator block."""
+        """Modeled VMEM of one grid step, tile-padded as in
+        :attr:`ConvPlan.vmem_resident_bytes`: the double-buffered ifmap
+        window, cotangent strip and f32 weight-shaped output block, plus
+        the window value, one tap's operand and its transpose, the
+        flattened cotangent, one tap's f32 partial and, for f32
+        operands, the full-precision matmul's buffers."""
         db = self.dtype_bytes
-        window = self.window_rows * self.wp * self.cin_per_group * db
-        gstrip = self.tile_go * self.w_out * self.tile_cout * db
-        acc = self.kh * self.kw * self.cin_per_group * self.tile_cout * 4
-        return window + gstrip + acc
+        cin, tc = self.cin_per_group, self.tile_cout
+        rows = self.tile_go * self.w_out
+        split = 0 if db != 4 else F32_MATMUL_BUFFERS * max(
+            vmem_tile_bytes((cin, rows), 4), vmem_tile_bytes((rows, tc), 4))
+        return (split + 2 * vmem_tile_bytes(self.x_block[1:], db)
+                + 2 * vmem_tile_bytes(self.g_block[1:], db)
+                + 2 * vmem_tile_bytes(self.out_block, 4)
+                + vmem_tile_bytes(self.x_block[1:], db)
+                + vmem_tile_bytes((rows, cin), db)
+                + vmem_tile_bytes((cin, rows), db)
+                + vmem_tile_bytes((rows, tc), db)
+                + vmem_tile_bytes((cin, tc), 4))
 
     # -- arithmetic / analytical HBM traffic --------------------------------
 

@@ -39,16 +39,15 @@ an executable partition:
   ``fold_pooling=True`` models the paper's ASIC, not this engine).
 
 The megakernel keeps activations resident but *streams* weights: each
-stage's weight tensor stays in HBM (``pltpu.ANY``) and one (Cin, Cout)
+stage's weight tensor stays in HBM (``pl.ANY``) and one (Cin, Cout)
 tap slice at a time is DMA'd into a VMEM scratch buffer — so a group's
 VMEM working set is the stage-0 window + the per-stage fp32
 accumulators + one tap slice per stage, never the full weight chain.
 That is what makes 512-channel VGG-16 tails fusable at all, and it is
 why the feasibility check below counts windows and accumulators but
-only a single tap per stage.  The working set is compared against the
-*full* VMEM (``FUSED_VMEM_BUDGET``), not the half-VMEM strip budget:
-the fused kernel owns the whole core while it runs (the residency
-*decision* still uses the half-VMEM ``RESIDENCY_BUDGET``).
+only a single tap per stage.  The working set is compared against
+``FUSED_VMEM_BUDGET`` (the fused kernel owns the core while it runs;
+the residency *decision* still uses ``netplan.RESIDENCY_BUDGET``).
 
 The group-level tuning knob (fuse depth x strip height) lives in
 ``core/autotune.py`` under the ``conv2d_fused:`` key namespace; the
@@ -61,7 +60,7 @@ import math
 from dataclasses import dataclass
 
 from repro.core import roofline
-from repro.core.conv_plan import STRIP_VMEM_BUDGET
+from repro.core.conv_plan import KERNEL_VMEM_LIMIT
 from repro.core.netplan import (NetworkPlan, RESIDENCY_BUDGET, graph_nodes,
                                 infer_pools, layer_kernel_problem,
                                 network_layers, pool_between,
@@ -72,10 +71,10 @@ from repro.core.netplan import (NetworkPlan, RESIDENCY_BUDGET, graph_nodes,
 # core/ free of kernel imports).
 MAX_FUSED_K = 8
 
-# The megakernel's working set may use the whole ~16 MiB VMEM core (it
-# is the only kernel running), unlike the per-layer strip budget which
-# reserves half for weights/accumulators it doesn't count.
-FUSED_VMEM_BUDGET = 2 * STRIP_VMEM_BUDGET
+# The megakernel's working-set budget: half of the kernels' scoped-VMEM
+# limit (16 MiB).  Its resident model is not yet checked against the
+# chip's compiler (the megakernels do not compile on the chip yet).
+FUSED_VMEM_BUDGET = KERNEL_VMEM_LIMIT // 2
 
 
 def _same_pads(size: int, k: int, s: int) -> tuple[int, int]:

@@ -14,6 +14,12 @@ defined failure model underneath the whole stack:
   (its errors propagate — a genuinely invalid problem still fails
   loudly, from the simplest engine that can diagnose it).
 
+* **Bugs are not demoted.**  :data:`BUG_ERRORS` (``AttributeError``,
+  ``TypeError``, ``NameError``, ``ImportError``) come from this
+  repository's own code — a renamed API, a wrong argument — never from
+  a backend's lowering, compile or runtime.  They propagate from every
+  tier, so a broken fast path fails instead of running slower.
+
 * **Structured events.**  Every demotion appends one event to a bounded
   ring buffer (:data:`RING_SIZE`); :func:`events` returns them for
   tests, benchmarks (the ``guard`` column of ``benchmarks/run.py
@@ -56,6 +62,10 @@ import threading
 GUARD_ENV = "REPRO_CONV_GUARD"          # "1" -> NaN/Inf numerics guard on
 STRICT_ENV = "REPRO_CONV_GUARD_STRICT"  # "1" -> re-raise, never demote
 RING_SIZE = 256
+
+#: exception types that mean a bug in our code, not a tier fault: they
+#: propagate from every tier instead of demoting
+BUG_ERRORS = (AttributeError, TypeError, NameError, ImportError)
 
 #: canonical tier order, fastest first — chains are sub-sequences of
 #: this (the ``q8`` int8 kernel tier only appears in the quantized
@@ -172,7 +182,8 @@ def run_chain(key: str, attempts, *, layer: str | None = None):
     * A tier already memoized as broken for ``key`` is skipped silently
       (no new event — demotions are reported exactly once per problem).
     * A non-final tier that raises records a ``kind="error"`` demotion
-      event and falls through to the next tier.
+      event and falls through to the next tier — unless the exception
+      is one of :data:`BUG_ERRORS`, which propagates.
     * With the numerics guard on (``REPRO_CONV_GUARD=1``), a non-final
       tier whose concrete output contains NaN/Inf records a
       ``kind="numerics"`` demotion and recomputes on the next tier.
@@ -199,6 +210,8 @@ def run_chain(key: str, attempts, *, layer: str | None = None):
         to = attempts[i + 1][0]
         try:
             out = thunk()
+        except BUG_ERRORS:
+            raise
         except Exception as e:  # lowering/compile/runtime fault -> demote
             _record(tier, to, key, "error",
                     f"{type(e).__name__}: {e}", layer)

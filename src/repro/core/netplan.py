@@ -48,7 +48,7 @@ from dataclasses import dataclass
 from dataclasses import replace as _dc_replace
 
 from repro.core import roofline
-from repro.core.conv_plan import STRIP_VMEM_BUDGET, ConvPlan
+from repro.core.conv_plan import KERNEL_VMEM_LIMIT, ConvPlan
 from repro.core.conv_shard import ShardedConvPlan
 from repro.core.model import (ConvLayer, GraphNode, alexnet_layers,
                               mobilenet_layers, resnet18_graph, unet_graph,
@@ -62,10 +62,10 @@ NETWORKS = {"vgg16": vgg16_layers, "alexnet": alexnet_layers,
 # via linear_graph_nodes().
 GRAPHS = {"resnet18": resnet18_graph, "unet": unet_graph}
 
-# Default budget for keeping an inter-layer activation on chip: the same
-# half-VMEM budget ConvPlan uses for its resident strip — the other half
-# of the core is already committed to the consumer's working set.
-RESIDENCY_BUDGET = STRIP_VMEM_BUDGET
+# Default budget for keeping an inter-layer activation on chip: a quarter
+# of the kernels' scoped-VMEM limit (8 MiB) — the rest is committed to
+# the consumer's working set.
+RESIDENCY_BUDGET = KERNEL_VMEM_LIMIT // 4
 
 
 def network_layers(network) -> list[ConvLayer]:

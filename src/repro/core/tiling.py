@@ -1,26 +1,14 @@
-"""Tiling planners: kernel tiling (paper §III) and TPU VMEM block planning.
+"""Kernel tiling (paper §III).
 
-Two distinct concerns live here:
-
-* ``subkernel_decomposition`` — the paper's kernel-tiling trick: a K x K
-  kernel with K > native_k is split into ceil(K/3)^2 sub-kernels of at most
-  3 x 3 taps, each assigned to a different core; the adder trees accumulate
-  the partial results.  We use the same decomposition arithmetically in
-  ``kernels/ops.py`` for K > 8 (MXU-unfriendly kernels).
-
-* ``plan_conv_tiles`` — compatibility facade over
-  ``core.conv_plan.ConvPlan``, which is the single owner of strip/tile/
-  traffic math.  It sizes the resident set (ifmap strip + carry + weight
-  tile + psum block) against the VMEM of a TPU core.
+``subkernel_decomposition`` is the paper's kernel-tiling trick: a K x K
+kernel with K > native_k is split into ceil(K/3)^2 sub-kernels of at most
+3 x 3 taps, each assigned to a different core; the adder trees accumulate
+the partial results.  We use the same decomposition arithmetically in
+``kernels/ops.py`` for K > 8 (MXU-unfriendly kernels).  Strip, tile and
+VMEM planning live in ``core.conv_plan``.
 """
 
 from __future__ import annotations
-
-import math
-from dataclasses import dataclass
-
-VMEM_BYTES = 16 * 1024 * 1024      # per-core VMEM budget (v5e-like)
-MXU_ALIGN = 128                    # lane alignment for MXU operands
 
 
 def subkernel_decomposition(k: int, native_k: int = 3
@@ -39,51 +27,3 @@ def subkernel_decomposition(k: int, native_k: int = 3
         for c0 in range(0, k, native_k):
             subs.append((r0, c0, min(native_k, k - r0), min(native_k, k - c0)))
     return subs
-
-
-@dataclass(frozen=True)
-class ConvTilePlan:
-    """Block shapes for the trim_conv2d Pallas kernel."""
-
-    tile_h: int          # spatial strip height (output rows per block)
-    tile_cin: int        # input-channel tile
-    tile_cout: int       # output-channel tile
-    halo: int            # K - 1 rows kept resident across strips ("shadow")
-    vmem_bytes: int      # resident-set estimate
-
-    def grid(self, h_out: int, cin: int, cout: int) -> tuple[int, int, int]:
-        return (math.ceil(cout / self.tile_cout),
-                math.ceil(h_out / self.tile_h),
-                math.ceil(cin / self.tile_cin))
-
-
-def plan_conv_tiles(h: int, w: int, cin: int, cout: int, k: int,
-                    dtype_bytes: int = 4,
-                    vmem_budget: int = VMEM_BYTES) -> ConvTilePlan:
-    """Choose (TH, TCin, TCout) so the resident set fits VMEM.
-
-    Facade over ``ConvPlan.build`` for the strip/C_out geometry; when the
-    full channel slice still overflows the budget (huge C_in/C_out), the
-    C_in then C_out tiles are halved until the resident set fits — the
-    sizing contract callers rely on.
-    """
-    from repro.core.conv_plan import ConvPlan
-    plan = ConvPlan.build((1, h, w, cin), (k, k, cin, cout),
-                          dtype_bytes=dtype_bytes,
-                          vmem_budget=vmem_budget // 2)
-    tile_cin, tile_cout = cin, plan.tile_cout
-
-    def resident(tci: int, tco: int) -> int:
-        strip = plan.tile_h * plan.wp * tci * dtype_bytes
-        carry = plan.carry_shape[0] * plan.wp * tci * dtype_bytes
-        wtile = k * k * tci * tco * dtype_bytes
-        acc = plan.th_out * plan.w_out * tco * 4        # fp32 psums
-        return strip + carry + wtile + acc
-
-    while resident(tile_cin, tile_cout) > vmem_budget and tile_cin > 8:
-        tile_cin //= 2
-    while resident(tile_cin, tile_cout) > vmem_budget and tile_cout > 8:
-        tile_cout //= 2
-    return ConvTilePlan(tile_h=plan.tile_h, tile_cin=tile_cin,
-                        tile_cout=tile_cout, halo=k - 1,
-                        vmem_bytes=resident(tile_cin, tile_cout))

@@ -15,7 +15,7 @@ TPU-native re-expression of the paper's dataflow (DESIGN.md §2, §4):
   steps in a VMEM scratch (``carry_ref``) — zero halo traffic, serialized
   strips.  ``"halo"`` is the TrIM baseline re-expressed at strip level:
   every strip over-fetches its ``K-1`` predecessor rows through an
-  overlapping (unblocked) BlockSpec — it pays the halo bytes the shadow
+  overlapping (element-indexed) BlockSpec — it pays the halo bytes the shadow
   registers eliminate, but has no cross-step state, so batch / group /
   strip / cout grid axes can execute in any order (parallelizable).  The
   autotuner (``core/autotune.py``) picks per layer.
@@ -68,8 +68,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.conv_plan import ConvPlan, input_grad_geometry
+from repro.core.conv_plan import (KERNEL_VMEM_LIMIT, ConvPlan,
+                                   input_grad_geometry)
 from repro.kernels.runtime import resolve_interpret
+
+F32_DOT_PRECISION = jax.lax.Precision.HIGHEST
 
 ACTIVATIONS = {
     None: lambda a: a,
@@ -77,6 +80,24 @@ ACTIVATIONS = {
     "gelu": jax.nn.gelu,
     "silu": jax.nn.silu,
 }
+
+
+def _element_window(block, index_map, groups: int):
+    """BlockSpec of an overlapping ``(1, rows, W, C/groups)`` input window.
+
+    The row offset is an element offset (successive windows overlap), so
+    every dim is element-indexed: Mosaic takes all-``Element`` or
+    no-``Element`` blocks, with the batch dim squeezed.  ``index_map``
+    returns ``(n, row, group)``; the channel offset is a literal 0
+    without groups so the compiler can prove it lane-aligned."""
+    _, rows, w, c = block
+
+    def element_index(*grid):
+        n, row, gr = index_map(*grid)
+        return (n, row, 0, gr * c if groups > 1 else 0)
+
+    return pl.BlockSpec((None, pl.Element(rows), pl.Element(w),
+                         pl.Element(c)), element_index)
 
 
 def _tap_matmuls(window, w_ref, *, kh: int, kw: int, stride: int,
@@ -88,16 +109,19 @@ def _tap_matmuls(window, w_ref, *, kh: int, kw: int, stride: int,
     s = stride
     r = (kh - 1) % s  # static in-window row offset (ConvPlan.row_offset)
     cin = window.shape[-1]
-    # int8 inputs accumulate exactly in int32 on the MXU; floats in fp32
-    acc_dtype = (jnp.int32 if jnp.issubdtype(window.dtype, jnp.integer)
-                 else jnp.float32)
+    # int8 inputs accumulate exactly in int32 on the MXU; floats in fp32.
+    # f32 operands ask for full-precision products: Mosaic's default
+    # rounds them to bf16 (2.4e-3 relative error on a v5e)
+    integer = jnp.issubdtype(window.dtype, jnp.integer)
+    acc_dtype = jnp.int32 if integer else jnp.float32
+    precision = F32_DOT_PRECISION if window.dtype == jnp.float32 else None
     acc = jnp.zeros((th_out * w_out, n_out), acc_dtype)
     for ki in range(kh):
         for kj in range(kw):
             rows = window[ki + r: ki + r + (th_out - 1) * s + 1: s,
                           kj: kj + (w_out - 1) * s + 1: s, :]
             acc += jnp.dot(rows.reshape(th_out * w_out, cin),
-                           w_ref[ki, kj],
+                           w_ref[ki, kj], precision=precision,
                            preferred_element_type=acc_dtype)
     return acc
 
@@ -168,7 +192,7 @@ def _halo_kernel(x_ref, w_ref, *rest, kh: int, kw: int, stride: int,
     s_ref = rest[0] if has_scale else None
     b_ref = rest[has_scale] if has_bias else None
     (o_ref,) = rest[has_scale + has_bias:]
-    acc = _tap_matmuls(x_ref[0], w_ref, kh=kh, kw=kw, stride=stride,
+    acc = _tap_matmuls(x_ref[...], w_ref, kh=kh, kw=kw, stride=stride,
                        th_out=th_out, w_out=w_out, n_out=o_ref.shape[-1])
     _epilogue_store(acc, s_ref, b_ref, o_ref, th_out=th_out, w_out=w_out,
                     activation=activation)
@@ -275,18 +299,17 @@ def trim_conv2d(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
 
     co_tiles = plan.co_tiles
     if plan.dataflow == "halo":
-        # Overlapping strip windows (unblocked indexing, element offsets):
+        # Overlapping strip windows (element-indexed row axis):
         # strip g reads rows [g*TH, g*TH + TH + K-1) of the halo-padded
         # input, whose K-1 extra top zero rows are this strip-level image
         # of TrIM's re-fetched boundary — the halo bytes ConvPlan bills as
         # mode="trim".
         z = jnp.pad(z, ((0, 0), (plan.kh - 1, 0), (0, 0), (0, 0)))
         assert z.shape == plan.halo_padded_input_shape
-        th, cin_pg = plan.tile_h, plan.cin_per_group
+        th = plan.tile_h
         in_specs = [
-            pl.BlockSpec(plan.halo_in_block,
-                         lambda ni, gr, g, co: (ni, g * th, 0, gr * cin_pg),
-                         indexing_mode=pl.unblocked),
+            _element_window(plan.halo_in_block,
+                            lambda ni, gr, g, co: (ni, g * th, gr), groups),
         ]
         kernel = functools.partial(
             _halo_kernel, kh=plan.kh, kw=plan.kw, stride=plan.stride,
@@ -340,8 +363,9 @@ def trim_conv2d(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
         # sweep); halo: no cross-step state, all axes parallelizable.
         semantics = ("parallel",) * 4 if plan.dataflow == "halo" \
             else ("arbitrary",) * 4
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=semantics)
+        compiler_params = pltpu.CompilerParams(
+            dimension_semantics=semantics,
+            vmem_limit_bytes=KERNEL_VMEM_LIMIT)
 
     out_padded = pl.pallas_call(
         kernel,
@@ -439,7 +463,7 @@ def _weight_grad_kernel(x_ref, g_ref, o_ref, *, kh: int, kw: int,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    window = x_ref[0]                      # (window_rows, Wp, Cin/g)
+    window = x_ref[...]                    # (window_rows, Wp, Cin/g)
     cin = window.shape[-1]
     s = stride
     gv = g_ref[0].reshape(tile_go * w_out, -1)   # (TGo*Wo, TCout)
@@ -448,6 +472,8 @@ def _weight_grad_kernel(x_ref, g_ref, o_ref, *, kh: int, kw: int,
             rows = window[ki: ki + (tile_go - 1) * s + 1: s,
                           kj: kj + (w_out - 1) * s + 1: s, :]
             acc = jnp.dot(rows.reshape(tile_go * w_out, cin).T, gv,
+                          precision=F32_DOT_PRECISION
+                          if window.dtype == jnp.float32 else None,
                           preferred_element_type=jnp.float32)
             o_ref[ki, kj] = o_ref[ki, kj] + acc
 
@@ -507,10 +533,8 @@ def trim_conv2d_weight_grad(x: jax.Array, g: jax.Array, *,
     in_specs = [
         # overlapping ifmap window of the strip's receptive field
         # (element offsets: successive windows share KH - s rows)
-        pl.BlockSpec(plan.x_block,
-                     lambda gr, co, ni, gs: (ni, gs * tgo_s, 0,
-                                             gr * cin_pg),
-                     indexing_mode=pl.unblocked),
+        _element_window(plan.x_block,
+                        lambda gr, co, ni, gs: (ni, gs * tgo_s, gr), groups),
         pl.BlockSpec(plan.g_block,
                      lambda gr, co, ni, gs: (ni, gs, 0,
                                              gr * co_tiles + co)),
@@ -523,8 +547,9 @@ def trim_conv2d_weight_grad(x: jax.Array, g: jax.Array, *,
     if not interpret:
         # the weight-shaped output block accumulates across (N, strip):
         # every axis is cross-step state -> all arbitrary
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary",) * 4)
+        compiler_params = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 4,
+            vmem_limit_bytes=KERNEL_VMEM_LIMIT)
 
     dw_padded = pl.pallas_call(
         kernel,

@@ -28,7 +28,7 @@ Three design points keep this exactly equal to the per-layer path:
   conv row's window stays inside the 'same'-padded input.  W padding is
   applied in-kernel with ``jnp.pad`` (exact zeros).
 
-* **Streamed weights** — weight tensors stay in HBM (``pltpu.ANY``) and
+* **Streamed weights** — weight tensors stay in HBM (``pl.ANY``) and
   one ``(Cin, Cout)`` tap slice at a time is DMA'd into a VMEM scratch
   buffer, so the VMEM working set is windows + accumulators + one tap
   per stage.  That is what makes 512-channel groups feasible at all.
@@ -50,7 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.runtime import resolve_interpret
-from repro.kernels.trim_conv2d import ACTIVATIONS
+from repro.kernels.trim_conv2d import ACTIVATIONS, F32_DOT_PRECISION
 
 
 def _maxpool(x, stride, window):
@@ -78,7 +78,9 @@ def _stage_conv(buf, tap_load, b_ref, st, *, activation, dtype):
             rows = xp[ki: ki + (st.conv_rows - 1) * s + 1: s,
                       kj: kj + (st.w_conv - 1) * s + 1: s, :]
             acc += jnp.dot(rows.reshape(st.conv_rows * st.w_conv, st.cin),
-                           tap, preferred_element_type=jnp.float32)
+                           tap, precision=F32_DOT_PRECISION
+                           if buf.dtype == jnp.float32 else None,
+                           preferred_element_type=jnp.float32)
     acc += b_ref[0].astype(jnp.float32)
     acc = ACTIVATIONS[activation](acc)
     return acc.reshape(st.conv_rows, st.w_conv, st.cout).astype(dtype)
@@ -110,7 +112,7 @@ def _fused_kernel(group, activation, dtype, *refs):
     sem = refs[2 + 3 * depth]
     g = pl.program_id(1)
 
-    buf = x_ref[0]                                 # (in_rows0, W0, Cin0)
+    buf = x_ref[...]                               # (in_rows0, W0, Cin0)
     for i, st in enumerate(group.stages):
         w_ref, b_ref, tap_ref = wb[2 * i], wb[2 * i + 1], taps[i]
 
@@ -147,19 +149,21 @@ def _fused_forward(x, weights, biases, *, group, activation, interpret):
     xp = jnp.pad(x, ((0, 0), (group.extra_top, group.pad_bottom),
                      (0, 0), (0, 0)))
 
+    # overlapping row windows: Mosaic takes all-Element blocks (batch
+    # squeezed), see trim_conv2d._element_window
     in_specs = [pl.BlockSpec(
-        (1, s0.in_rows, s0.w_in, s0.cin),
-        lambda n, g: (n, group.in_row_offset(g), 0, 0),
-        indexing_mode=pl.unblocked)]
+        (None, pl.Element(s0.in_rows), pl.Element(s0.w_in),
+         pl.Element(s0.cin)),
+        lambda n, g: (n, group.in_row_offset(g), 0, 0))]
     for st in group.stages:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         in_specs.append(pl.BlockSpec((1, st.cout), lambda n, g: (0, 0)))
     scratch = [pltpu.VMEM((st.cin, st.cout), dtype) for st in group.stages]
     scratch.append(pltpu.SemaphoreType.DMA)
 
     compiler_params = None
     if not interpret:
-        compiler_params = pltpu.TPUCompilerParams(
+        compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"))
 
     operands = [xp]

@@ -44,20 +44,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core.conv_shard import ShardedConvPlan
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions (experimental home on 0.4.x)."""
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:                      # pragma: no cover - newer jax
-        from jax import shard_map
-    try:
-        return shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
-    except TypeError:                        # pragma: no cover - newer jax
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
-
-
 def make_sharded_plan(x_shape, w_shape, mesh, *, rules: dict | None = None,
                       **kw) -> ShardedConvPlan:
     """The exact plan :func:`sharded_conv2d` executes for these
@@ -152,7 +138,8 @@ def sharded_conv2d(x: jax.Array, w: jax.Array,
         in_specs.append(P())
         args.append(bias)
 
-    out = _shard_map(fn, mesh, tuple(in_specs),
-                     P(ba, sa, None, None))(*args)
+    out = jax.shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
+                        out_specs=P(ba, sa, None, None),
+                        check_vma=False)(*args)
     assert out.shape[1] == ss * plan.h_out_local, (out.shape, plan)
     return out[:, :plan.h_out]

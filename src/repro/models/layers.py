@@ -264,6 +264,21 @@ def depthwise_separable_apply(p: dict, x: jax.Array, *, stride: int = 1,
                         mesh=mesh, rules=rules)
 
 
+def _pooled_head(p: dict, x: jax.Array) -> jax.Array:
+    """Global mean pool + linear classifier: ``(N, H, W, C) -> (N,
+    classes)``.  One image at a time (``lax.map``), so every row runs
+    the same compiled arithmetic whatever the batch: a served row
+    equals the single-request forward bit for bit, where a batched
+    reduction or matmul may order its sums by batch size.  The
+    projection is an elementwise product summed over channels, full f32
+    on every backend (a TPU matmul at default precision rounds f32
+    operands to bf16)."""
+    def one(xi):
+        return (xi.mean(axis=(0, 1))[:, None] * p["w"]).sum(axis=0) \
+            + p["b"]
+    return jax.lax.map(one, x)
+
+
 def simple_cnn_params(*, cin: int = 3, channels=(8, 16), n_classes: int = 10,
                       k: int = 3, depthwise_stage: bool = True) -> dict:
     """A small CIFAR-shaped classifier running entirely on trim kernels.
@@ -304,8 +319,7 @@ def simple_cnn_apply(p: dict, x: jax.Array, *, impl: str = "pallas",
                              rules=rules)
         x = conv2d_apply(p[f"down{i}"], x, stride=2, activation="relu",
                          impl=impl, mesh=mesh, rules=rules)
-    x = x.mean(axis=(1, 2))                       # global mean pool
-    return x @ p["head"]["w"] + p["head"]["b"]
+    return _pooled_head(p["head"], x)
 
 
 def cnn_params_from_layers(layers_list, *, n_classes: int | None = None,
@@ -465,8 +479,7 @@ def cnn_apply_from_layers(p: dict, layers_list, x: jax.Array, *,
                                    mesh=mesh, rules=rules)
     if "head" not in p:
         return x
-    x = x.mean(axis=(1, 2))                       # global mean pool
-    return x @ p["head"]["w"] + p["head"]["b"]
+    return _pooled_head(p["head"], x)
 
 
 def cnn_params_from_graph(graph, *, n_classes: int | None = None,
@@ -627,8 +640,7 @@ def cnn_apply_from_graph(p: dict, graph, x: jax.Array, *,
     y = outs[last]
     if "head" not in p:
         return y
-    y = y.mean(axis=(1, 2))                       # global mean pool
-    return y @ p["head"]["w"] + p["head"]["b"]
+    return _pooled_head(p["head"], y)
 
 
 # ---------------------------------------------------------------------------

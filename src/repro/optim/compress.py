@@ -17,7 +17,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -43,8 +42,8 @@ def compressed_psum(x: jax.Array, axis_name: str, mesh):
     """All-reduce a replicated-per-shard partial ``x`` over one mesh axis
     with int8 wire format (shard_map wrapper for manual-DP train steps)."""
     fn = functools.partial(int8_allreduce, axis_name=axis_name)
-    return shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
-                     check_rep=False)(x)
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(x)
 
 
 def ef_quantize(grad: jax.Array, residual: jax.Array, bits: int = 8):

@@ -57,15 +57,29 @@ def _isolated_convtune_cache(tmp_path, monkeypatch):
     autotune.reset_memory_cache()
 
 
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers", "guard_events: the test provokes conv-tier demotions "
+                   "on purpose (fault injection); without it, any guard "
+                   "event fails the test")
+
+
 @pytest.fixture(autouse=True)
-def _guard_reset():
+def _guard_reset(request):
     """Fresh guard state (events + memoized demotions) per test: a
     demotion memoized by one test must never silently reroute another
-    test's conv dispatch."""
+    test's conv dispatch.  A test that ends with a demotion it did not
+    declare (``@pytest.mark.guard_events``) fails: a demoted fast path
+    would otherwise pass by comparing a slower tier with the oracle."""
     from repro.core import guard
     guard.reset()
     yield
+    events = guard.events()
     guard.reset()
+    if events and request.node.get_closest_marker("guard_events") is None:
+        pytest.fail("undeclared guard demotions: " + "; ".join(
+            f"{e['tier']}->{e['to']} {e['key']}: {e['error'][:200]}"
+            for e in events), pytrace=False)
 
 try:                                    # pragma: no cover - env-dependent
     import hypothesis  # noqa: F401

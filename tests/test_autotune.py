@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.core import autotune
-from repro.core.tiling import VMEM_BYTES
+from repro.core.conv_plan import KERNEL_VMEM_BUDGET
 from repro.kernels import ops, ref
 from repro.models import layers
 from repro.models.base import init_params
@@ -212,7 +212,7 @@ def test_geometry_insane_record_is_rejected():
 def test_candidates_cover_both_dataflows_and_fit_vmem():
     plans = autotune.candidate_knobs(X_SHAPE, W_SHAPE)
     assert {p.dataflow for p in plans} == set(autotune.DATAFLOWS)
-    assert all(p.vmem_resident_bytes <= VMEM_BYTES for p in plans)
+    assert all(p.vmem_resident_bytes <= KERNEL_VMEM_BUDGET for p in plans)
     # the full-height strip (one grid step along H) is always a candidate
     assert any(p.g_tiles == 1 for p in plans)
 
@@ -467,7 +467,7 @@ def test_conv2d_sharded_consults_namespaced_cache(monkeypatch):
 def test_weight_grad_candidates_fit_vmem():
     plans = autotune.candidate_weight_grad_knobs(X_SHAPE, W_SHAPE)
     assert plans
-    assert all(p.vmem_resident_bytes <= VMEM_BYTES for p in plans)
+    assert all(p.vmem_resident_bytes <= KERNEL_VMEM_BUDGET for p in plans)
     # the full-height cotangent strip (one sweep step per image) is
     # always a candidate
     assert any(p.go_tiles == 1 for p in plans)

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import CheckpointCorruptError, CheckpointManager
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import auto_mesh
 from repro.data import DataConfig, SyntheticStream, make_batch
 from repro.distributed import steps
 from repro.distributed.sharding import make_rules
@@ -190,7 +190,7 @@ def test_elastic_restore_new_mesh(tmp_path):
     state = {"w": jnp.arange(16.0).reshape(4, 4)}
     mgr.save(1, state, meta={"mesh": [1, 1]})
 
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     shardings = {"w": NamedSharding(mesh, P(None, "model"))}
     restored, _ = mgr.restore(state, shardings=shardings)
     np.testing.assert_array_equal(np.asarray(restored["w"]),

@@ -3,6 +3,7 @@ strip/tile/traffic math — the kernel's actual padded layouts and grids must
 be byte-identical to the analytical model, for dense, strided, grouped and
 depthwise geometries (VGG-16 and MobileNet layers included)."""
 
+import dataclasses
 import math
 
 import jax.numpy as jnp
@@ -10,7 +11,8 @@ import numpy as np
 import pytest
 
 from repro.core import ConvPlan, mobilenet_layers, vgg16_layers
-from repro.core.conv_plan import Conv1dPlan
+from repro.core.conv_plan import (KERNEL_VMEM_BUDGET, Conv1dPlan,
+                                   vmem_tile_bytes)
 from repro.core.roofline import conv_plan_roofline
 from repro.kernels import ops, ref
 from repro.kernels.trim_conv2d import (hbm_traffic_model, make_plan,
@@ -245,9 +247,10 @@ def test_halo_plan_geometry_and_traffic():
     assert plan.hbm_bytes()["input"] > carry.hbm_bytes()["input"]
     assert plan.halo_rows() == (plan.g_tiles - 1) * 2
     assert carry.halo_rows() == 0
-    # resident sets agree to within the kh=1 scratch floor
-    assert abs(plan.vmem_resident_bytes - carry.vmem_resident_bytes) \
-        <= plan.wp * plan.cin_per_group * plan.dtype_bytes
+    # resident sets differ by the K-1 boundary rows: the halo window
+    # block double-buffers them, the carry scratch holds one copy
+    assert plan.vmem_resident_bytes - carry.vmem_resident_bytes \
+        == vmem_tile_bytes((2, plan.wp, 16), plan.dtype_bytes)
     with pytest.raises(ValueError):
         ConvPlan(n=1, h=8, w=8, cin=4, cout=8, kh=3, kw=3,
                  dataflow="weird")
@@ -304,6 +307,30 @@ def test_fused_epilogue_kernel_tiled_path():
 # ---------------------------------------------------------------------------
 # 1D plan
 # ---------------------------------------------------------------------------
+
+def test_vmem_tile_padding():
+    """VMEM buffers pad the last two dims to the dtype's (sublane, 128)
+    tile: 8 rows of 32-bit, 16 of 16-bit, 32 of 8-bit values."""
+    assert vmem_tile_bytes((10, 3), 4) == 16 * 128 * 4
+    assert vmem_tile_bytes((10, 3), 2) == 16 * 128 * 2
+    assert vmem_tile_bytes((10, 3), 1) == 32 * 128 * 1
+    assert vmem_tile_bytes((2, 8, 128), 4) == 2 * 8 * 128 * 4
+    assert vmem_tile_bytes((4, 9, 129), 4) == 4 * 16 * 256 * 4
+
+
+@pytest.mark.parametrize("layer", range(13))
+@pytest.mark.parametrize("dtype_bytes", [4, 2])
+def test_default_strip_is_tallest_that_fits(layer, dtype_bytes):
+    """The default strip of every VGG-16 layer is the tallest whose
+    modeled VMEM set fits KERNEL_VMEM_BUDGET (or the full height)."""
+    plan = ConvPlan.from_layer(vgg16_layers()[layer],
+                               dtype_bytes=dtype_bytes)
+    assert plan.vmem_resident_bytes <= KERNEL_VMEM_BUDGET
+    full = (plan.h_out + plan.delta) * plan.stride
+    if plan.tile_h < full:
+        taller = dataclasses.replace(plan, tile_h=plan.tile_h + plan.stride)
+        assert taller.vmem_resident_bytes > KERNEL_VMEM_BUDGET
+
 
 def test_conv1d_plan_geometry():
     plan = Conv1dPlan.build((2, 100, 24), (4, 24))

@@ -24,8 +24,8 @@ from repro.configs.shapes import ShapePlan
 from repro.launch import dryrun
 from repro.models import ModelConfig
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((4, 4), ("data", "model"))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((4, 4), ("data", "model"))
 cfg = ModelConfig(family="dense", n_layers=6, d_model=128, n_heads=8,
                   n_kv_heads=4, d_ff=256, vocab=512, attn_impl="chunked",
                   attn_chunk=64)
@@ -74,8 +74,8 @@ from repro.configs.shapes import ShapePlan
 from repro.launch import dryrun
 from repro.models import ModelConfig
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((2, 2, 4), ("pod", "data", "model"))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((2, 2, 4), ("pod", "data", "model"))
 cfg = ModelConfig(family="dense", n_layers=2, d_model=128, n_heads=8,
                   n_kv_heads=4, d_ff=256, vocab=512, attn_impl="chunked",
                   attn_chunk=64)

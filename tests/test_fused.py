@@ -30,7 +30,11 @@ def _close(a, b, tol=1e-5):
 
 # hand-rolled chains exercising the geometry corners: 'same' stacks with
 # an even pool, a strided-valid head with an overlapping (odd) pool and
-# a pointwise tail, and a pool-free stack
+# a pointwise tail, and a pool-free stack.  Bitwise fused == per-layer
+# needs a backend whose dot rows do not depend on the matmul's row
+# count: XLA:CPU (interpret mode) picks shape-dependent dot algorithms
+# for narrow outputs, so the chains that fuse past their first conv
+# emit 64 channels, where its results are row-stable.
 def _chain_same():
     return [ConvLayer("c0", 12, 3, 4, 3, 1, 1),
             ConvLayer("c1", 12, 4, 6, 3, 1, 1),     # pool 2/2 -> 6
@@ -38,22 +42,22 @@ def _chain_same():
 
 
 def _chain_strided():
-    return [ConvLayer("s0", 17, 3, 4, 5, 2, 0),     # valid -> 7, pool 2/3
-            ConvLayer("s1", 3, 4, 8, 1, 1, 0),      # pointwise
-            ConvLayer("s2", 3, 8, 8, 3, 1, 1)]
+    return [ConvLayer("s0", 17, 3, 64, 5, 2, 0),    # valid -> 7, pool 2/3
+            ConvLayer("s1", 3, 64, 64, 1, 1, 0),    # pointwise
+            ConvLayer("s2", 3, 64, 64, 3, 1, 1)]
 
 
 def _chain_nopool():
-    return [ConvLayer("p0", 9, 2, 4, 3, 1, 1),
-            ConvLayer("p1", 9, 4, 4, 3, 1, 1),
-            ConvLayer("p2", 9, 4, 6, 3, 1, 1)]
+    return [ConvLayer("p0", 9, 2, 64, 3, 1, 1),
+            ConvLayer("p1", 9, 64, 64, 3, 1, 1),
+            ConvLayer("p2", 9, 64, 64, 3, 1, 1)]
 
 
 TOPOLOGIES = {
     "same_pool": _chain_same,
     "strided_valid": _chain_strided,
     "nopool": _chain_nopool,
-    "alexnet_x32": lambda: scale_layers(network_layers("alexnet"), 32),
+    "alexnet_x4": lambda: scale_layers(network_layers("alexnet"), 4),
 }
 
 

@@ -249,9 +249,9 @@ def test_resnet18_arch_golden_values():
     assert arch["improvement"] > 2.0           # the CI gate
     cmp = gp.compare()
     assert cmp["ops_per_macc_3dtrim"] == \
-        pytest.approx(161.41412898595303, rel=1e-6)
+        pytest.approx(313.6696436169219, rel=1e-6)
     assert cmp["ops_per_macc_trim"] == \
-        pytest.approx(161.38439581808308, rel=1e-6)
+        pytest.approx(312.77380260061716, rel=1e-6)
     # at batch 1 every edge fits the 8 MB budget
     assert all(e.resident for e in gp.edges)
     assert max(gp.boundary_occupancy()) == 3211264

@@ -93,15 +93,21 @@ def test_vgg16_arch_golden_values():
 
 
 def test_vgg16_plan_golden_values():
-    """The execution-engine accounting goldens for the first layers."""
-    cmp = NetworkPlan.build("vgg16").compare()
+    """The execution-engine accounting goldens for the first layers.
+    They follow the default strips: conv1 runs 21 strips of 11 rows,
+    the tallest whose tile-padded VMEM set fits KERNEL_VMEM_BUDGET."""
+    plan = NetworkPlan.build("vgg16")
+    assert (plan.steps[0].plan.tile_h, plan.steps[0].plan.g_tiles) \
+        == (11, 21)
+    cmp = plan.compare()
     rows = cmp["layers"]
-    assert rows[0]["ops_per_macc_3dtrim"] == pytest.approx(564.48,
-                                                           **APPROX)
+    assert rows[0]["ops_per_macc_3dtrim"] == \
+        pytest.approx(898.926192031352, **APPROX)
     assert rows[0]["ops_per_macc_trim"] == \
-        pytest.approx(561.9992999649983, **APPROX)
-    assert rows[0]["improvement"] == pytest.approx(1.0044140625, **APPROX)
-    assert cmp["improvement"] == pytest.approx(1.0008943523145661,
+        pytest.approx(788.1262032668866, **APPROX)
+    assert rows[0]["improvement"] == \
+        pytest.approx(1.140586606948462, **APPROX)
+    assert cmp["improvement"] == pytest.approx(1.024112720032531,
                                                **APPROX)
 
 

@@ -43,6 +43,7 @@ def _allclose(a, b, tol=1e-5):
 # Single fallback edges
 # ---------------------------------------------------------------------------
 
+@pytest.mark.guard_events
 def test_pallas_failure_demotes_to_ref():
     x, w = _conv_inputs()
     want = ref.conv2d(x, w, bias=jnp.ones(12), activation="relu")
@@ -81,6 +82,7 @@ def test_demotion_is_memoized_once_per_problem():
     assert guard.events() == []
 
 
+@pytest.mark.guard_events
 def test_packed_weights_failure_demotes_to_ref():
     x, w = _conv_inputs()
     pk = ops.pack_conv2d_weights(w, jnp.ones(12))
@@ -94,6 +96,7 @@ def test_packed_weights_failure_demotes_to_ref():
     assert (ev["tier"], ev["to"]) == ("pallas", "ref")
 
 
+@pytest.mark.guard_events
 def test_sharded_failure_demotes_to_pallas():
     from repro.launch.mesh import make_conv_mesh
     mesh = make_conv_mesh(1, 1)
@@ -108,6 +111,7 @@ def test_sharded_failure_demotes_to_pallas():
         == ("sharded", "pallas", "error")
 
 
+@pytest.mark.guard_events
 def test_sharded_and_pallas_failures_demote_to_ref():
     from repro.launch.mesh import make_conv_mesh
     mesh = make_conv_mesh(1, 1)
@@ -121,6 +125,7 @@ def test_sharded_and_pallas_failures_demote_to_ref():
     assert tiers == [("sharded", "pallas"), ("pallas", "ref")]
 
 
+@pytest.mark.guard_events
 def test_depthwise_conv_failure_demotes_to_ref():
     x = jnp.asarray(RNG.standard_normal((1, 10, 10, 6)), jnp.float32)
     w = jnp.asarray(RNG.standard_normal((3, 3, 1, 6)) * .3, jnp.float32)
@@ -132,6 +137,7 @@ def test_depthwise_conv_failure_demotes_to_ref():
     assert ev["layer"] == "dw" and ":g6:" in ev["key"]
 
 
+@pytest.mark.guard_events
 def test_fused_group_failure_demotes_to_per_layer():
     """A fused-megakernel failure falls back to the per-layer path and
     stays bit-identical to the unfused forward."""
@@ -159,6 +165,7 @@ def test_fused_group_failure_demotes_to_per_layer():
 # Acceptance: full VGG-16 forward under compound injected failures
 # ---------------------------------------------------------------------------
 
+@pytest.mark.guard_events
 def test_vgg16_forward_survives_fused_and_pallas_failures():
     """ISSUE 7 acceptance: with BOTH the fused megakernels and the
     per-layer Pallas kernels broken, a full VGG-16 forward completes via
@@ -198,6 +205,7 @@ def test_vgg16_forward_survives_fused_and_pallas_failures():
 # Numerics guard (REPRO_CONV_GUARD=1)
 # ---------------------------------------------------------------------------
 
+@pytest.mark.guard_events
 def test_nan_poison_demotes_with_numerics_guard(monkeypatch):
     monkeypatch.setenv(guard.GUARD_ENV, "1")
     x, w = _conv_inputs()
@@ -236,6 +244,7 @@ def test_numerics_guard_inert_under_jit(monkeypatch):
     assert guard.events() == []
 
 
+@pytest.mark.guard_events
 def test_lowering_failure_demotes_inside_jit_trace():
     """A tier that raises at trace time demotes within the jit trace —
     the compiled function is the fallback tier's."""
@@ -262,6 +271,24 @@ def test_strict_mode_restores_crash_semantics(monkeypatch):
     assert guard.events() == []
 
 
+@pytest.mark.parametrize("exc", list(guard.BUG_ERRORS))
+def test_bug_errors_propagate_without_demotion(exc):
+    """An error of our own code (a renamed API, a wrong argument) is not
+    a tier fault: it propagates from the fast tier, is not memoized, and
+    the slower tier never runs in its place."""
+    ran = []
+
+    def bug():
+        raise exc("bug in the fast tier")
+
+    with pytest.raises(exc, match="bug in the fast tier"):
+        guard.run_chain("k", [("pallas", bug),
+                              ("ref", lambda: ran.append(1))])
+    assert not ran
+    assert guard.events() == [] and guard.demotions() == {}
+
+
+@pytest.mark.guard_events
 def test_final_tier_errors_propagate():
     """The last tier runs unguarded: a genuinely invalid problem still
     raises (from the simplest engine), never returns garbage."""
@@ -274,6 +301,7 @@ def test_final_tier_errors_propagate():
     assert ev["tier"] == "pallas"
 
 
+@pytest.mark.guard_events
 def test_event_ring_is_bounded():
     for i in range(guard.RING_SIZE + 44):
         def boom(i=i):

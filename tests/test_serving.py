@@ -277,6 +277,7 @@ def test_unprewarmed_bucket_counts_as_cold_tune():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.chaos
+@pytest.mark.guard_events
 def test_replica_demoted_mid_load_keeps_serving():
     # eager replicas: the guarded tier chain dispatches per call, so a
     # fault injected mid-load demotes on the very next batch

@@ -7,13 +7,13 @@ from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import steps
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import auto_mesh
 from repro.distributed.sharding import make_rules
 from repro.models.base import Param, resolve_spec, tree_bytes_per_dev
 
 
 def _mesh():
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 class FakeMesh:
@@ -66,7 +66,7 @@ def test_seq_override_takes_axis_from_kv():
 
 
 def test_batch_shardings_divisibility():
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     rules = make_rules()
     tree = {"tokens": jax.ShapeDtypeStruct((1, 8), jnp.int32),
             "big": jax.ShapeDtypeStruct((16, 8), jnp.int32)}
