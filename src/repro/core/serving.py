@@ -47,11 +47,11 @@ from collections import deque
 
 import numpy as np
 
-from repro.core import guard
+from repro.core import guard, spans
 from repro.testing.load import TraceRecorder
 
 __all__ = ["QueueFull", "BucketGrid", "Replica", "ServingEngine",
-           "replay", "pow2_buckets"]
+           "replay", "pow2_buckets", "jit_replica_fn"]
 
 
 class QueueFull(RuntimeError):
@@ -129,6 +129,33 @@ class Replica:
     fn: object     # Callable[[np.ndarray], array-like]
 
 
+def jit_replica_fn(call, params, device=None):
+    """A replica's batch forward from a jitted ``call(params, x)`` that
+    returns one array: numpy batch in, numpy rows out, in four spans
+    (``core.spans``): ``replica.h2d`` (the copy of the batch onto
+    ``device``, the default device when ``None``, is started),
+    ``replica.dispatch`` (the program, and the copy of its output back,
+    are queued), ``replica.wait`` (until the output is ready; its child
+    ``replica.h2d_wait`` until the batch has landed) and ``replica.d2h``
+    (the rest of the copy back).  Both copies run while the host waits:
+    the copy back is queued behind the program, not started after it."""
+    import jax
+
+    def fn(batch):
+        with spans.span("replica.h2d"):
+            x = jax.device_put(np.asarray(batch), device)
+        with spans.span("replica.dispatch"):
+            y = call(params, x)
+            y.copy_to_host_async()
+        with spans.span("replica.wait"):
+            with spans.span("replica.h2d_wait"):
+                x.block_until_ready()
+            y.block_until_ready()
+        with spans.span("replica.d2h"):
+            return np.asarray(y)
+    return fn
+
+
 class ServingEngine:
     """Continuous batching over a bucket grid with bounded queueing,
     multi-replica dispatch and guard-aware degradation reporting.
@@ -167,6 +194,7 @@ class ServingEngine:
         self._warm: set[int] = set()
         self.cold_tunes = 0
         self.served = 0
+        self.steps_taken = 0              # next step id
         self._bucket_counts: dict[int, int] = {}
         self._replica_served = {r.name: 0 for r in self.replicas}
         self._replica_events: dict[str, list[dict]] = \
@@ -191,9 +219,10 @@ class ServingEngine:
         replica (spatial parallelism inside a replica composes with
         data parallelism across replicas).  ``fused=True`` serves the
         residency-group megakernels (guarded: a failing group demotes
-        to per-layer execution per DESIGN.md §9)."""
+        to per-layer execution per DESIGN.md §9).  Each replica's
+        forward is :func:`jit_replica_fn`; a distributed replica takes
+        its batch straight onto its own device."""
         import jax
-        import jax.numpy as jnp
         from repro.models import layers as mlayers
 
         topo = list(topo)
@@ -212,14 +241,8 @@ class ServingEngine:
                 p_i = jax.device_put(params, dev)
             else:
                 dev, p_i = None, params
-
-            def fn(batch, p=p_i, dev=dev):
-                xb = jnp.asarray(np.asarray(batch))
-                if dev is not None:
-                    xb = jax.device_put(xb, dev)
-                return np.asarray(call(p, xb))
-
-            replicas.append(Replica(name=f"replica{i}", fn=fn))
+            replicas.append(Replica(name=f"replica{i}",
+                                    fn=jit_replica_fn(call, p_i, dev)))
         first = topo[0]
         return cls(replicas, buckets, topo=topo, fused=fused,
                    input_shape=(first.ifmap, first.ifmap,
@@ -235,7 +258,7 @@ class ServingEngine:
         """Enqueue one request.  Raises :class:`QueueFull` at capacity
         (backpressure: the queue depth is bounded by ``max_queue``,
         always)."""
-        with self._lock:
+        with spans.span("engine.submit"), self._lock:
             if len(self._queue) >= self.max_queue:
                 raise QueueFull(
                     f"queue at capacity ({self.max_queue}); retry or "
@@ -293,38 +316,52 @@ class ServingEngine:
         ``service_model`` (a ``bucket -> seconds`` callable) the
         returned/recorded service time is injected — the deterministic
         virtual-clock mode; otherwise it is the measured wall time of
-        the forward.  An empty queue returns ``([], 0.0)``."""
-        with self._lock:
-            if not self._queue:
-                return [], 0.0
-            take = min(len(self._queue), self.grid.max_bucket)
-            reqs = [self._queue.popleft() for _ in range(take)]
-            if replica is None:
-                replica = self._rr % len(self.replicas)
-            self._rr += 1
-        bucket = self.grid.bucket_for(take)
-        self._ensure_warm(bucket)
-        rep = self.replicas[replica]
-        for rid, _, _ in reqs:
-            self.recorder.batch(rid, now, bucket=bucket, replica=rep.name,
-                                batch_real=take)
-            self.recorder.execute(rid, now)
-        batch = self._pad_batch([x for _, x, _ in reqs], bucket)
-        t0 = time.perf_counter()
-        out = np.asarray(rep.fn(batch))
-        measured = time.perf_counter() - t0
-        self._collect_guard(rep.name)
-        dt = float(service_model(bucket)) if service_model else measured
-        done = now + dt
-        results = []
-        for i, (rid, _, _) in enumerate(reqs):
-            self.recorder.complete(rid, done)
-            results.append((rid, out[i]))
-        with self._lock:
-            self.served += take
-            self._bucket_counts[bucket] = \
-                self._bucket_counts.get(bucket, 0) + 1
-            self._replica_served[rep.name] += take
+        the forward.  An empty queue returns ``([], 0.0)``.
+
+        A step that serves a batch takes the next step id
+        (``steps_taken - 1`` after it); its spans (``core.spans``) are
+        ``engine.step`` around ``engine.batch`` (queue pop, recorder
+        stamps, stack and pad), ``replica.forward`` (the replica call)
+        and ``engine.finish`` (guard events, completion stamps, rows)."""
+        with spans.span("engine.step") as sp:
+            with spans.span("engine.batch"):
+                with self._lock:
+                    if not self._queue:
+                        return [], 0.0
+                    take = min(len(self._queue), self.grid.max_bucket)
+                    reqs = [self._queue.popleft() for _ in range(take)]
+                    if replica is None:
+                        replica = self._rr % len(self.replicas)
+                    self._rr += 1
+                    sid = self.steps_taken
+                    self.steps_taken += 1
+                sp.set_step(sid)
+                bucket = self.grid.bucket_for(take)
+                self._ensure_warm(bucket)
+                rep = self.replicas[replica]
+                for rid, _, _ in reqs:
+                    self.recorder.batch(rid, step=sid, bucket=bucket,
+                                        replica=rep.name, batch_real=take)
+                    self.recorder.execute(rid, now)
+                batch = self._pad_batch([x for _, x, _ in reqs], bucket)
+            with spans.span("replica.forward"):
+                t0 = time.perf_counter()
+                out = np.asarray(rep.fn(batch))
+                measured = time.perf_counter() - t0
+            with spans.span("engine.finish"):
+                self._collect_guard(rep.name)
+                dt = float(service_model(bucket)) if service_model \
+                    else measured
+                done = now + dt
+                results = []
+                for i, (rid, _, _) in enumerate(reqs):
+                    self.recorder.complete(rid, done)
+                    results.append((rid, out[i]))
+                with self._lock:
+                    self.served += take
+                    self._bucket_counts[bucket] = \
+                        self._bucket_counts.get(bucket, 0) + 1
+                    self._replica_served[rep.name] += take
         return results, dt
 
     def forward_one(self, x) -> np.ndarray:

@@ -257,6 +257,7 @@ class _ConvVjpConfig(typing.NamedTuple):
     dataflow: str
     use_autotune_cache: bool
     packed_cout: int | None = None
+    name: str | None = None          # the kernel's name (layer)
 
 
 def _activation_bwd(activation: str | None, z: jax.Array | None,
@@ -312,14 +313,14 @@ def _conv2d_vjp_core(cfg: _ConvVjpConfig, x, w, bias):
     return trim_conv2d(x, w, bias, stride=cfg.stride, pad=cfg.pad,
                        tile_h=cfg.tile_h, tile_cout=cfg.tile_cout,
                        groups=cfg.groups, activation=cfg.activation,
-                       dataflow=cfg.dataflow)
+                       dataflow=cfg.dataflow, name=cfg.name)
 
 
 def _conv2d_vjp_fwd(cfg: _ConvVjpConfig, x, w, bias):
     z = trim_conv2d(x, w, bias, stride=cfg.stride, pad=cfg.pad,
                     tile_h=cfg.tile_h, tile_cout=cfg.tile_cout,
                     groups=cfg.groups, activation=None,
-                    dataflow=cfg.dataflow)
+                    dataflow=cfg.dataflow, name=cfg.name)
     y = z if cfg.activation is None else ACTIVATIONS[cfg.activation](z)
     # z is only a residual when the activation needs it in the backward
     return y, (x, w, bias, z if cfg.activation is not None else None)
@@ -358,14 +359,16 @@ def _conv2d_packed_vjp_core(cfg: _ConvVjpConfig, x, wp, bp):
     return trim_conv2d(x, wp, bp, stride=cfg.stride, pad=cfg.pad,
                        tile_h=cfg.tile_h, tile_cout=cfg.tile_cout,
                        groups=cfg.groups, activation=cfg.activation,
-                       dataflow=cfg.dataflow, packed_cout=cfg.packed_cout)
+                       dataflow=cfg.dataflow, packed_cout=cfg.packed_cout,
+                       name=cfg.name)
 
 
 def _conv2d_packed_vjp_fwd(cfg: _ConvVjpConfig, x, wp, bp):
     z = trim_conv2d(x, wp, bp, stride=cfg.stride, pad=cfg.pad,
                     tile_h=cfg.tile_h, tile_cout=cfg.tile_cout,
                     groups=cfg.groups, activation=None,
-                    dataflow=cfg.dataflow, packed_cout=cfg.packed_cout)
+                    dataflow=cfg.dataflow, packed_cout=cfg.packed_cout,
+                    name=cfg.name)
     y = z if cfg.activation is None else ACTIVATIONS[cfg.activation](z)
     return y, (x, wp, bp, z if cfg.activation is not None else None)
 
@@ -431,7 +434,9 @@ def conv2d(x: jax.Array, w, *, stride: int = 1,
     ``ref`` tier runs unguarded, so a genuinely invalid problem still
     raises.  ``REPRO_CONV_GUARD=1`` additionally finite-checks tier
     outputs (eager only) and demotes on NaN/Inf; ``layer`` names the
-    producing layer in those events.
+    producing layer in those events, and names the layer's Pallas
+    kernel (a profiler trace shows it as ``%<layer>.<n>``; autotune and
+    guard keys never see it).
 
     Runnable quickstart snippets for every path (dataflows, packing,
     autotune, ``mesh=``, guard) live in README.md and are executed by CI
@@ -478,7 +483,8 @@ def conv2d(x: jax.Array, w, *, stride: int = 1,
                               bias=bias, activation=activation,
                               tile_h=tile_h, tile_cout=tile_cout,
                               dataflow=dataflow,
-                              use_autotune_cache=use_autotune_cache)
+                              use_autotune_cache=use_autotune_cache,
+                              name=layer)
 
     def _ref_tier():
         return ref.conv2d(x, w, stride=stride, padding=padding,
@@ -494,7 +500,7 @@ def conv2d(x: jax.Array, w, *, stride: int = 1,
                                    tile_h=tile_h, tile_cout=tile_cout,
                                    dataflow=dataflow,
                                    use_autotune_cache=use_autotune_cache,
-                                   mesh=mesh, rules=rules)
+                                   mesh=mesh, rules=rules, name=layer)
         tiers.insert(0, ("sharded", _sharded_tier))
     key = guard.problem_key("conv2d", x.shape, w.shape, stride=stride,
                             padding=padding, groups=feature_group_count,
@@ -507,10 +513,11 @@ def _conv2d_pallas(x: jax.Array, w: jax.Array, *, stride: int,
                    bias: jax.Array | None, activation: str | None,
                    tile_h: int | None, tile_cout: int | None,
                    dataflow: str | None,
-                   use_autotune_cache: bool) -> jax.Array:
+                   use_autotune_cache: bool,
+                   name: str | None = None) -> jax.Array:
     """The single-device Pallas tier: 'same' pre-pad, autotune-cache
     knob fill, differentiable kernel core — or the K > MAX_NATIVE_K
-    adder-tree decomposition."""
+    adder-tree decomposition (every sub-kernel named ``name``)."""
     k = w.shape[0]
     if padding == "same":
         ph, pw = _same_pads(x.shape[1], k, stride), \
@@ -533,7 +540,8 @@ def _conv2d_pallas(x: jax.Array, w: jax.Array, *, stride: int,
                              activation=activation, tile_h=tile_h,
                              tile_cout=tile_cout,
                              dataflow=dataflow or "carry",
-                             use_autotune_cache=use_autotune_cache)
+                             use_autotune_cache=use_autotune_cache,
+                             name=name)
         return _conv2d_vjp_core(cfg, x, w, bias)
     # Kernel tiling (paper §III): split K x K into sub-kernels, accumulate.
     # The epilogue is applied once, after the adder tree.  Explicit tile
@@ -547,7 +555,7 @@ def _conv2d_pallas(x: jax.Array, w: jax.Array, *, stride: int,
                          groups=feature_group_count, activation=None,
                          tile_h=tile_h, tile_cout=tile_cout,
                          dataflow=dataflow or "carry",
-                         use_autotune_cache=use_autotune_cache)
+                         use_autotune_cache=use_autotune_cache, name=name)
     for r0, c0, kh, kw in subkernel_decomposition(k, native_k=3):
         zs = x[:, r0:r0 + (h_out - 1) * stride + kh,
                c0:c0 + (w_out - 1) * stride + kw, :]
@@ -564,7 +572,8 @@ def _conv2d_sharded(x: jax.Array, w: jax.Array, *, stride: int,
                     bias: jax.Array | None, activation: str | None,
                     tile_h: int | None, tile_cout: int | None,
                     dataflow: str | None, use_autotune_cache: bool,
-                    mesh, rules: dict | None) -> jax.Array:
+                    mesh, rules: dict | None,
+                    name: str | None = None) -> jax.Array:
     """The shard_map path (DESIGN.md §6): resolve the shard grid from
     the mesh + conv rules, plan with :class:`ShardedConvPlan`, and run
     the halo-exchange schedule with the *differentiable* conv core as
@@ -603,7 +612,7 @@ def _conv2d_sharded(x: jax.Array, w: jax.Array, *, stride: int,
                          activation=activation, tile_h=tile_h,
                          tile_cout=tile_cout,
                          dataflow=dataflow or "carry",
-                         use_autotune_cache=use_autotune_cache)
+                         use_autotune_cache=use_autotune_cache, name=name)
     return sharded_conv2d(x, w, bias, plan=plan, mesh=mesh,
                           local_conv=functools.partial(_conv2d_vjp_core,
                                                        cfg))
@@ -640,7 +649,7 @@ def _conv2d_packed(x: jax.Array, pk: PackedConv2dWeights, *,
         return _conv2d_packed_pallas(
             x, pk, stride=stride, padding=padding, activation=activation,
             tile_h=tile_h, dataflow=dataflow,
-            use_autotune_cache=use_autotune_cache)
+            use_autotune_cache=use_autotune_cache, name=layer)
 
     def _ref_tier():
         w_logical = _unpack_weights(pk.w, pk.groups, pk.cout)
@@ -666,7 +675,8 @@ def _conv2d_packed_pallas(x: jax.Array, pk: PackedConv2dWeights, *,
                           stride: int, padding: str,
                           activation: str | None, tile_h: int | None,
                           dataflow: str | None,
-                          use_autotune_cache: bool) -> jax.Array:
+                          use_autotune_cache: bool,
+                          name: str | None = None) -> jax.Array:
     k = pk.w.shape[0]
     if padding == "same":
         ph, pw = _same_pads(x.shape[1], k, stride), \
@@ -689,7 +699,7 @@ def _conv2d_packed_pallas(x: jax.Array, pk: PackedConv2dWeights, *,
                          tile_cout=pk.tile_cout,
                          dataflow=dataflow or "carry",
                          use_autotune_cache=use_autotune_cache,
-                         packed_cout=pk.cout)
+                         packed_cout=pk.cout, name=name)
     return _conv2d_packed_vjp_core(cfg, x, pk.w, pk.bias)
 
 
@@ -702,7 +712,8 @@ def _unpack_cout_row(row: jax.Array, groups: int, cout: int) -> jax.Array:
 def _q8_forward(x_q: jax.Array, pk: PackedConv2dWeights, *, stride: int,
                 activation: str | None, tile_h: int | None,
                 dataflow: str | None,
-                use_autotune_cache: bool) -> jax.Array:
+                use_autotune_cache: bool,
+                name: str | None = None) -> jax.Array:
     """The int8 Pallas tier: exact int32 MXU accumulation with the fused
     dequant epilogue.  ``x_q`` is already quantized and 'same'-pre-padded
     with the activation zero point.  Module-level so the fault harness
@@ -727,7 +738,8 @@ def _q8_forward(x_q: jax.Array, pk: PackedConv2dWeights, *, stride: int,
                        s_row.reshape(1, -1), stride=stride, pad=0,
                        tile_h=tile_h, tile_cout=pk.tile_cout,
                        groups=pk.groups, activation=activation,
-                       dataflow=dataflow or "carry", packed_cout=pk.cout)
+                       dataflow=dataflow or "carry", packed_cout=pk.cout,
+                       name=name)
 
 
 def _conv2d_q8(x: jax.Array, pk: PackedConv2dWeights, *, stride: int,
@@ -760,7 +772,8 @@ def _conv2d_q8(x: jax.Array, pk: PackedConv2dWeights, *, stride: int,
     def _q8_tier():
         return _q8_forward(x_pad, pk, stride=stride, activation=activation,
                            tile_h=tile_h, dataflow=dataflow,
-                           use_autotune_cache=use_autotune_cache)
+                           use_autotune_cache=use_autotune_cache,
+                           name=layer)
 
     w_q = _unpack_weights(pk.w, pk.groups, pk.cout)
     w_scale = _unpack_cout_row(pk.scale, pk.groups, pk.cout)
@@ -778,7 +791,8 @@ def _conv2d_q8(x: jax.Array, pk: PackedConv2dWeights, *, stride: int,
                               bias=b_logical, activation=activation,
                               tile_h=tile_h, tile_cout=pk.tile_cout,
                               dataflow=dataflow,
-                              use_autotune_cache=use_autotune_cache)
+                              use_autotune_cache=use_autotune_cache,
+                              name=layer)
 
     def _ref_tier():
         return ref.conv2d_quantized(

@@ -212,7 +212,7 @@ def make_plan(x_shape, w_shape, *, stride: int = 1, pad: int = 0,
 
 @functools.partial(jax.jit, static_argnames=(
     "stride", "pad", "tile_h", "tile_cout", "groups", "activation",
-    "dataflow", "packed_cout", "interpret"))
+    "dataflow", "packed_cout", "interpret", "name"))
 def trim_conv2d(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
                 scale: jax.Array | None = None,
                 *, stride: int = 1, pad: int = 0, tile_h: int | None = None,
@@ -220,7 +220,8 @@ def trim_conv2d(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
                 activation: str | None = None,
                 dataflow: str = "carry",
                 packed_cout: int | None = None,
-                interpret: bool | None = None) -> jax.Array:
+                interpret: bool | None = None,
+                name: str | None = None) -> jax.Array:
     """Strided (grouped) 2D convolution with fused bias + activation.
 
     x: (N, H, W, Cin); w: (K, K, Cin/groups, Cout); bias: (Cout,) or None.
@@ -247,6 +248,9 @@ def trim_conv2d(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
     ``packed_cout`` is the *logical* C_out the caller gets back.
 
     ``interpret=None`` auto-detects the backend (native on TPU).
+    ``name`` (the conv's layer name) names the kernel: it becomes the
+    custom call's instruction name in the compiled program, so a
+    profiler trace shows the kernel as ``%<name>.<n>``.
     Returns (N, H_out, W_out, Cout).
     """
     if activation not in ACTIVATIONS:
@@ -380,6 +384,7 @@ def trim_conv2d(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
         scratch_shapes=scratch_shapes,
         compiler_params=compiler_params,
         interpret=interpret,
+        name=name,
     )(*inputs)
 
     out = out_padded[:, plan.delta:plan.delta + plan.h_out]

@@ -28,6 +28,7 @@ import time
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.serving import QueueFull, ServingEngine
 
 
@@ -102,12 +103,20 @@ class AsyncConvServer:
             if (self.max_wait_s > 0
                     and self.engine.pending() < self.engine.grid.max_bucket):
                 await asyncio.sleep(self.max_wait_s)
-            out, _ = await loop.run_in_executor(
-                None, lambda: self.engine.step(now=self.clock()))
-            for rid, row in out:
-                fut = self._futures.pop(rid, None)
-                if fut is not None and not fut.done():
-                    fut.set_result(row)
+            # the step's spans hold its id; this one also covers the
+            # hops into and out of the executor thread
+            with spans.span("server.step") as sp:
+                before = self.engine.steps_taken
+                out, _ = await loop.run_in_executor(
+                    None, lambda: self.engine.step(now=self.clock()))
+                sid = self.engine.steps_taken - 1 \
+                    if self.engine.steps_taken > before else None
+                sp.set_step(sid)
+            with spans.span("server.resolve", step=sid):
+                for rid, row in out:
+                    fut = self._futures.pop(rid, None)
+                    if fut is not None and not fut.done():
+                        fut.set_result(row)
 
 
 # ---------------------------------------------------------------------------

@@ -12,10 +12,10 @@ share:
   caller, so the same seed always produces byte-identical traces.
 
 * **A request-lifecycle recorder** — :class:`TraceRecorder` holds one
-  :class:`RequestRecord` per request with its
-  enqueue/batch/execute/complete timestamps (plus the bucket and
-  replica that served it), and aggregates them into the latency
-  percentiles and throughput the benchmark emits.
+  :class:`RequestRecord` per request with its enqueue/execute/complete
+  timestamps (plus the engine step, bucket and replica that served
+  it), and aggregates them into the latency percentiles and throughput
+  the benchmark emits.
 
 Timestamps are plain floats on whatever clock the caller drives —
 :class:`VirtualClock` for the deterministic tests and replays,
@@ -129,16 +129,17 @@ def ramp_arrivals(rate0: float, rate1: float, n: int, *, seed: int = 0,
 class RequestRecord:
     """One request's lifecycle timestamps (all on the caller's clock).
 
-    ``t_enqueue`` is stamped at submission, ``t_batch`` when the batcher
-    pulled the request into a bucket, ``t_execute`` when its batch
-    started executing, ``t_complete`` when the batch's results were
-    published.  ``bucket``/``replica`` identify the compiled program and
-    replica that served it; ``batch_real`` is how many real (non-pad)
-    rows shared the batch."""
+    ``t_enqueue`` is stamped at submission, ``t_execute`` when the
+    engine pulled the request into a batch and started it,
+    ``t_complete`` when the batch's results were published.  ``step`` is
+    the id of the engine step that served it (the id its host spans in
+    ``core.spans`` carry); ``bucket``/``replica`` identify the compiled
+    program and replica; ``batch_real`` is how many real (non-pad) rows
+    shared the batch."""
 
     rid: int
     t_enqueue: float
-    t_batch: float | None = None
+    step: int | None = None
     t_execute: float | None = None
     t_complete: float | None = None
     bucket: int | None = None
@@ -178,10 +179,10 @@ class TraceRecorder:
         self.records[rid] = rec
         return rec
 
-    def batch(self, rid: int, t: float, *, bucket: int, replica: str,
+    def batch(self, rid: int, *, step: int, bucket: int, replica: str,
               batch_real: int) -> None:
         rec = self.records[rid]
-        rec.t_batch, rec.bucket = t, bucket
+        rec.step, rec.bucket = step, bucket
         rec.replica, rec.batch_real = replica, batch_real
 
     def execute(self, rid: int, t: float) -> None:

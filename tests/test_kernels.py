@@ -83,6 +83,18 @@ def test_conv2d_tile_boundaries():
             _allclose(trim_conv2d(x, wt, tile_h=th, dataflow=df), want)
 
 
+def test_layer_name_changes_nothing_computed():
+    """The layer name only names the kernel: the same rows, and no
+    guard event or memoized demotion."""
+    from repro.core import guard
+    x = jnp.asarray(RNG.standard_normal((2, 8, 8, 4)), jnp.float32)
+    wt = jnp.asarray(RNG.standard_normal((3, 3, 4, 8)) * 0.3, jnp.float32)
+    plain = ops.conv2d(x, wt, activation="relu")
+    named = ops.conv2d(x, wt, activation="relu", layer="conv7")
+    assert np.array_equal(np.asarray(plain), np.asarray(named))
+    assert guard.events() == [] and guard.demotions() == {}
+
+
 # ---------------------------------------------------------------------------
 # packed weights (load-time pad/reshape) vs the per-call path
 # ---------------------------------------------------------------------------

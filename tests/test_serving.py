@@ -228,7 +228,7 @@ def test_recorder_lifecycle_and_latency():
     r0 = rec.records[0]
     assert (r0.t_enqueue, r0.t_execute, r0.t_complete) == (1.0, 2.0, 2.5)
     assert r0.latency == 1.5 and r0.queue_wait == 1.0
-    assert r0.bucket == 2 and r0.batch_real == 2
+    assert r0.bucket == 2 and r0.batch_real == 2 and r0.step == 0
     s = rec.summary()
     assert s["count"] == 2 and s["buckets"][2]["count"] == 2
 

@@ -106,6 +106,30 @@ def test_q8_conv_compiles(one_chip):
         x, w, b, scale)
 
 
+@pytest.mark.parametrize("dataflow", ["carry", "halo"])
+def test_conv_kernel_carries_its_layer_name(one_chip, monkeypatch,
+                                            dataflow):
+    """``ops.conv2d(layer=...)`` lowered for the chip names its Pallas
+    kernel after the layer: the compiled custom call is ``%<layer>.<n>``,
+    the text a profiler trace shows for the kernel."""
+    from repro.kernels import ops, runtime
+    monkeypatch.setattr(runtime, "on_tpu", lambda: True)
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,  # noqa
+                                             sharding=one_chip)
+    layer = f"enc0{dataflow}"
+
+    def fwd(x, w, b):
+        return ops.conv2d(x, w, bias=b, activation="relu",
+                          dataflow=dataflow, layer=layer)
+
+    text = jax.jit(fwd).lower(sds((2, 32, 32, 16)), sds((3, 3, 16, 128)),
+                              sds((128,))).compile().as_text()
+    kernels = [ln.split("=", 1)[0].strip() for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels and all(k.startswith(f"%{layer}.") for k in kernels), \
+        kernels
+
+
 @pytest.mark.xfail(strict=True, raises=NotImplementedError,
                    reason="Mosaic: 'Only 2D gather is supported' (the "
                           "megakernel's strided max-pool slices)")
