@@ -662,7 +662,9 @@ def tune_network(network="vgg16", *, n: int = 1, dtype: str = "float32",
     looks up at call time), so after one sweep the whole forward pass of
     ``examples/cnn_inference.py --net ...`` runs on cached plans.  With
     a shard grid the records land under the ``conv2d_shard:`` namespace
-    instead.  Layers sharing a shape (VGG-16's repeated blocks) are
+    instead.  A layer whose taps ``ops.conv2d`` folds into the lanes
+    (``ops.tap_fold``) is tuned, and keyed, as the 1x1 problem it runs
+    as.  Layers sharing a shape (VGG-16's repeated blocks) are
     tuned once; layers with ``K > MAX_NATIVE_K`` (AlexNet's 11x11) run
     on the kernel-tiled path that never consults the cache and are
     recorded as skipped.  ``include_backward`` additionally seeds both
@@ -674,7 +676,7 @@ def tune_network(network="vgg16", *, n: int = 1, dtype: str = "float32",
     key written (or ``{"skipped": reason}``).
     """
     from repro.core.netplan import layer_kernel_problem, network_layers
-    from repro.kernels.ops import MAX_NATIVE_K
+    from repro.kernels.ops import MAX_NATIVE_K, tap_fold
     sharded = batch_shards > 1 or spatial_shards > 1
     if measure and sharded:
         raise ValueError(
@@ -697,15 +699,22 @@ def tune_network(network="vgg16", *, n: int = 1, dtype: str = "float32",
         # the shared layer -> executed-problem mapping (raises on
         # padding the execution path cannot reproduce)
         x_shape, pad, w_shape, _ = layer_kernel_problem(layer, n=n)
+        stride = layer.stride
+        # the single-device float path runs a tap-folded layer as its
+        # 1x1 problem (ops.tap_fold), and looks that problem up
+        folded = None if sharded or op != "conv2d" else \
+            tap_fold(x_shape, w_shape, stride, layer.groups)
+        if folded is not None:
+            (x_shape, w_shape), stride = folded, 1
         layer_op = op if not sharded \
             else sharded_key_op(batch_shards, spatial_shards)
-        key = make_key(x_shape, w_shape, stride=layer.stride, pad=pad,
+        key = make_key(x_shape, w_shape, stride=stride, pad=pad,
                        groups=layer.groups, dtype=dtype, backend=backend,
                        op=layer_op)
         if key in seen:
             results[layer.name] = seen[key]
             continue
-        common = dict(stride=layer.stride, pad=pad, groups=layer.groups,
+        common = dict(stride=stride, pad=pad, groups=layer.groups,
                       dtype=dtype, dtype_bytes=dtype_bytes,
                       backend=backend, write=write, path=path)
         if sharded:
@@ -717,6 +726,12 @@ def tune_network(network="vgg16", *, n: int = 1, dtype: str = "float32",
                        **common)
         rec = dict(rec, key=key)
         if include_backward and not sharded:
+            if folded is not None:     # the 1x1 kernel sees whole strips
+                rows = ConvPlan.build(
+                    x_shape, w_shape, dtype_bytes=dtype,
+                    tile_h=rec["tile_h"], tile_cout=rec["tile_cout"],
+                    dataflow=rec["dataflow"]).rows_padded
+                x_shape = (x_shape[0], rows) + x_shape[2:]
             rec["backward"] = tune_backward(x_shape, w_shape, **common)
         seen[key] = rec
         results[layer.name] = rec

@@ -357,10 +357,15 @@ def _layer_eligible(layer) -> bool:
         # one-strip output gains nothing from strip fusion anyway.
         return False
     try:
-        layer_kernel_problem(layer)
+        x_shape, _, w_shape, _ = layer_kernel_problem(layer)
     except ValueError:
         return False
-    return True
+    # A conv whose taps fold into the lanes (DESIGN.md §13) runs per
+    # layer as a 1x1 over its folded input: its one dot sums in another
+    # order than the megakernel's K*K tap dots, and would break the
+    # bitwise guarantee; the fold also spares it the K*K lane tiles.
+    from repro.kernels.ops import tap_fold
+    return tap_fold(x_shape, w_shape, layer.stride, layer.groups) is None
 
 
 def _strip_candidates(h_pool_last: int):

@@ -416,7 +416,19 @@ class ServingEngine:
                 "max_queue_depth": self.recorder.max_queue_depth,
                 "rejected": len(self.recorder.rejected),
                 "replicas": per_replica,
+                "tap_folded": self._tap_folded(),
             }
+
+    def _tap_folded(self) -> dict:
+        """``{layer: K*K*Cin}`` of the convs traced with their taps
+        folded (``ops.tap_folded``): the topology's layers, or every
+        named conv of the process for an engine without a topology."""
+        from repro.kernels.ops import tap_folded
+        folded = tap_folded()
+        if self.topo is None:
+            return folded
+        names = {layer.name for layer in self.topo}
+        return {k: v for k, v in folded.items() if k in names}
 
 
 # ---------------------------------------------------------------------------

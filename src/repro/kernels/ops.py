@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import typing
 
 from repro.core import autotune, guard
-from repro.core.conv_plan import ConvPlan, input_grad_geometry
+from repro.core.conv_plan import LANES, ConvPlan, input_grad_geometry
 from repro.core.conv_shard import ShardedConvPlan, resolve_conv_mesh
 from repro.core.tiling import subkernel_decomposition
 from repro.kernels import ref
@@ -55,6 +55,10 @@ from repro.kernels.trim_conv2d import (ACTIVATIONS, trim_conv2d,
 from repro.kernels.trim_conv2d_sharded import sharded_conv2d
 
 MAX_NATIVE_K = 8
+
+# {layer name: K*K*C_in} of every named conv traced with its taps folded
+# (``tap_folded``); static per compiled program, filled at trace time
+_TAP_FOLDED: dict[str, int] = {}
 
 
 # ---------------------------------------------------------------------------
@@ -218,6 +222,57 @@ def quantize_conv2d_weights(w: jax.Array, bias: jax.Array | None = None, *,
         pk, bias=bp, scale=sp,
         zero_point=jnp.asarray(x_zero_point, jnp.int32),
         input_scale=jnp.asarray(x_scale, jnp.float32))
+
+
+def tap_fold(x_shape, w_shape, stride: int, groups: int):
+    """The problem a conv runs as with its taps folded into the lanes
+    (DESIGN.md §13), or None when it is not folded.
+
+    ``x_shape`` is the kernel-seen input (:func:`kernel_input_shape`),
+    ``w_shape`` ``(K, K, Cin/groups, Cout)``.  A conv folds when
+    ``groups == 1``, ``1 < K <= MAX_NATIVE_K`` and ``K*K*Cin`` fits one
+    lane tile: its K*K shifted, strided views of the input, concatenated
+    on the channel axis (tap-major, then channel), make a stride-1 1x1
+    conv of ``(N, H_out, W_out, K*K*Cin)`` by ``(1, 1, K*K*Cin, Cout)``.
+    Returns that ``(x_shape, w_shape)``."""
+    n, h, w, cin = x_shape
+    kh, kw, _, cout = w_shape
+    if groups != 1 or not 1 < kh <= MAX_NATIVE_K \
+            or kh * kw * cin > LANES:
+        return None
+    h_out = (h - kh) // stride + 1
+    w_out = (w - kw) // stride + 1
+    return (n, h_out, w_out, kh * kw * cin), (1, 1, kh * kw * cin, cout)
+
+
+def tap_folded() -> dict[str, int]:
+    """``{layer: K*K*Cin}`` of every named conv this process traced
+    with its taps folded (:func:`tap_fold`)."""
+    return dict(_TAP_FOLDED)
+
+
+def _fold_taps(x: jax.Array, kh: int, kw: int, stride: int,
+               rows: int) -> jax.Array:
+    """The tap-folded input of :func:`tap_fold` (an XLA im2col): the
+    KH*KW shifted, strided views of the pre-padded ``x``, on the channel
+    axis, tap-major, with zero rows below ``H_out`` up to ``rows`` (the
+    1x1 plan's ``rows_padded``: whole strips).
+
+    The views are stacked channel-major, ``(N, KH*KW*C, rows, W_out)``,
+    where each is a block of compact rows, and moved to the channel-last
+    layout in one copy.  The barrier keeps XLA from choosing the
+    kernel's 128-lane layout for the stack itself, which turned each
+    view into a lane-padded copy (over 40 ms a step for U-Net's
+    ``(8, 512, 512, 1)`` input on a v5e)."""
+    h_out = (x.shape[1] - kh) // stride + 1
+    w_out = (x.shape[2] - kw) // stride + 1
+    x = jnp.pad(jnp.moveaxis(x, 3, 1),
+                ((0, 0), (0, 0), (0, (rows - h_out) * stride), (0, 0)))
+    taps = jnp.concatenate(
+        [x[:, :, ki:ki + (rows - 1) * stride + 1:stride,
+           kj:kj + (w_out - 1) * stride + 1:stride]
+         for ki in range(kh) for kj in range(kw)], axis=1)
+    return jnp.moveaxis(jax.lax.optimization_barrier(taps), 1, 3)
 
 
 def kernel_input_shape(x_shape, k: int, stride: int, padding: str):
@@ -515,18 +570,22 @@ def _conv2d_pallas(x: jax.Array, w: jax.Array, *, stride: int,
                    dataflow: str | None,
                    use_autotune_cache: bool,
                    name: str | None = None) -> jax.Array:
-    """The single-device Pallas tier: 'same' pre-pad, autotune-cache
-    knob fill, differentiable kernel core — or the K > MAX_NATIVE_K
-    adder-tree decomposition (every sub-kernel named ``name``)."""
+    """The single-device Pallas tier: 'same' pre-pad, tap folding
+    (:func:`tap_fold`), autotune-cache knob fill, differentiable kernel
+    core — or the K > MAX_NATIVE_K adder-tree decomposition (every
+    sub-kernel named ``name``)."""
     k = w.shape[0]
     if padding == "same":
         ph, pw = _same_pads(x.shape[1], k, stride), \
             _same_pads(x.shape[2], k, stride)
         x = jnp.pad(x, ((0, 0), ph, pw, (0, 0)))
+    folded = tap_fold(x.shape, w.shape, stride, feature_group_count)
+    x_shape, w_shape = folded or (x.shape, w.shape)
     if k <= MAX_NATIVE_K:
         if use_autotune_cache and (tile_h is None or tile_cout is None
                                    or dataflow is None):
-            rec = autotune.knobs_for(x.shape, w.shape, stride=stride,
+            rec = autotune.knobs_for(x_shape, w_shape,
+                                     stride=1 if folded else stride,
                                      pad=0, groups=feature_group_count,
                                      dtype=str(x.dtype))
             if rec is not None:
@@ -535,6 +594,17 @@ def _conv2d_pallas(x: jax.Array, w: jax.Array, *, stride: int,
                     else rec["tile_cout"]
                 dataflow = dataflow if dataflow is not None \
                     else rec["dataflow"]
+        if folded is not None:
+            # outside the custom_vjp core: autodiff runs through the
+            # slices and the concat, then the 1x1 kernel's own backward
+            # kernels.  The fold's fusion also writes the kernel's
+            # bottom rows up to whole strips, so no second pad is left.
+            plan = ConvPlan.build(x_shape, w_shape, dtype_bytes=x.dtype,
+                                  tile_h=tile_h, tile_cout=tile_cout,
+                                  dataflow=dataflow or "carry")
+            x = _fold_taps(x, k, k, stride, plan.rows_padded)
+            w, stride = w.reshape(w_shape), 1
+            tile_h, tile_cout = plan.tile_h, plan.tile_cout
         cfg = _ConvVjpConfig(stride=stride, pad=0,
                              groups=feature_group_count,
                              activation=activation, tile_h=tile_h,
@@ -542,7 +612,12 @@ def _conv2d_pallas(x: jax.Array, w: jax.Array, *, stride: int,
                              dataflow=dataflow or "carry",
                              use_autotune_cache=use_autotune_cache,
                              name=name)
-        return _conv2d_vjp_core(cfg, x, w, bias)
+        out = _conv2d_vjp_core(cfg, x, w, bias)
+        if folded is None:
+            return out
+        if name is not None:
+            _TAP_FOLDED[name] = w_shape[2]
+        return out[:, :x_shape[1]]
     # Kernel tiling (paper §III): split K x K into sub-kernels, accumulate.
     # The epilogue is applied once, after the adder tree.  Explicit tile
     # knobs apply to every sub-kernel; the autotune cache is NOT consulted

@@ -264,18 +264,32 @@ def depthwise_separable_apply(p: dict, x: jax.Array, *, stride: int = 1,
                         mesh=mesh, rules=rules)
 
 
+def _pairwise_sum(v: jax.Array) -> jax.Array:
+    """Sum over axis 0 as a fixed tree of halving adds.  XLA never
+    re-associates explicit adds, so the order is the same on every
+    backend and whatever the ops around it are fused with — unlike a
+    reduce, whose order the compiler picks per program."""
+    while v.shape[0] > 1:
+        h = v.shape[0] // 2
+        v = jnp.concatenate([v[:h] + v[h:2 * h], v[2 * h:]])
+    return v[0]
+
+
 def _pooled_head(p: dict, x: jax.Array) -> jax.Array:
     """Global mean pool + linear classifier: ``(N, H, W, C) -> (N,
     classes)``.  One image at a time (``lax.map``), so every row runs
     the same compiled arithmetic whatever the batch: a served row
     equals the single-request forward bit for bit, where a batched
-    reduction or matmul may order its sums by batch size.  The
-    projection is an elementwise product summed over channels, full f32
-    on every backend (a TPU matmul at default precision rounds f32
-    operands to bf16)."""
+    reduction or matmul may order its sums by batch size.  The spatial
+    sum is a :func:`_pairwise_sum` tree: at batch 1 the map's loop is
+    removed, and a reduce there may be fused into the last conv's ops
+    and summed in another order.  The projection is an elementwise
+    product summed over channels, full f32 on every backend (a TPU
+    matmul at default precision rounds f32 operands to bf16)."""
     def one(xi):
-        return (xi.mean(axis=(0, 1))[:, None] * p["w"]).sum(axis=0) \
-            + p["b"]
+        mean = _pairwise_sum(xi.reshape(-1, xi.shape[-1])) \
+            / (xi.shape[0] * xi.shape[1])
+        return (mean[:, None] * p["w"]).sum(axis=0) + p["b"]
     return jax.lax.map(one, x)
 
 

@@ -228,11 +228,16 @@ def test_measured_tune_records_wall_clock():
 # ops.conv2d consults the cache
 # ---------------------------------------------------------------------------
 
+# a 3x3 conv whose taps do not fold into one lane tile (9 * 16 > 128,
+# DESIGN.md §13): the TrIM kernel runs it as the key describes
+X_WIDE, W_WIDE = (1, 16, 16, 16), (3, 3, 16, 12)
+
+
 def test_conv2d_uses_cached_knobs(monkeypatch):
-    x = jnp.asarray(RNG.standard_normal((1, 14, 14, 8)), jnp.float32)
-    w = jnp.asarray(RNG.standard_normal(W_SHAPE) * .3, jnp.float32)
+    x = jnp.asarray(RNG.standard_normal((1, 14, 14, 16)), jnp.float32)
+    w = jnp.asarray(RNG.standard_normal(W_WIDE) * .3, jnp.float32)
     # 'same' K=3 s=1 pre-pads to 16x16; that's the key conv2d looks up
-    key = autotune.make_key((1, 16, 16, 8), W_SHAPE, stride=1, pad=0)
+    key = autotune.make_key(X_WIDE, W_WIDE, stride=1, pad=0)
     autotune.store(key, dict(tile_h=6, tile_cout=4, dataflow="halo",
                              source="model"))
 
@@ -479,13 +484,13 @@ def test_backward_pass_uses_cached_knobs(monkeypatch):
     key of its transformed shapes."""
     import jax
     from repro.kernels import trim_conv2d as tc
-    x = jnp.asarray(RNG.standard_normal(X_SHAPE), jnp.float32)
-    w = jnp.asarray(RNG.standard_normal(W_SHAPE) * .3, jnp.float32)
-    autotune.store(autotune.make_key(X_SHAPE, W_SHAPE, stride=1, pad=0,
+    x = jnp.asarray(RNG.standard_normal(X_WIDE), jnp.float32)
+    w = jnp.asarray(RNG.standard_normal(W_WIDE) * .3, jnp.float32)
+    autotune.store(autotune.make_key(X_WIDE, W_WIDE, stride=1, pad=0,
                                      op="conv2d_wgrad"),
                    dict(tile_go=3, tile_cout=6))
     from repro.core.conv_plan import input_grad_geometry
-    geo = input_grad_geometry(X_SHAPE, W_SHAPE, stride=1, pad=0)
+    geo = input_grad_geometry(X_WIDE, W_WIDE, stride=1, pad=0)
     autotune.store(autotune.make_key(geo["g_padded_shape"],
                                      geo["wt_shape"], stride=1, pad=0),
                    dict(tile_h=5, tile_cout=4, dataflow="halo",

@@ -367,7 +367,9 @@ def test_tune_graph_sweep_and_consumption(tmp_path):
     """One tune_graph sweep caches every conv node's knobs (and the
     fused segment records); the executor then runs on cached plans."""
     path = str(tmp_path / "tune.json")
-    nodes = graph_nodes(tiny_graph("unet"))
+    # 16 channels at the least: narrower convs fold their taps and
+    # never fuse (DESIGN.md §13); nothing is executed here
+    nodes = graph_nodes(unet_graph(image=16, base=16, depth=2))
     out = autotune.tune_graph(nodes, n=1, fused=True, path=path)
     convs = [nd for nd in nodes if nd.op == "conv"]
     assert len(out["layers"]) == len(convs)

@@ -260,10 +260,10 @@ def test_network_roofline_sums_steps():
 # ---------------------------------------------------------------------------
 
 def test_tune_network_sweep_and_consumption(tmp_path):
-    topo = [ConvLayer("c1", 12, 3, 4, kernel=3, padding=1),
-            ConvLayer("c2", 12, 4, 4, kernel=3, padding=1),   # repeat ↓
-            ConvLayer("c3", 12, 4, 4, kernel=3, padding=1),
-            ConvLayer("big", 12, 4, 4, kernel=9, padding=4)]
+    topo = [ConvLayer("c1", 12, 3, 16, kernel=3, padding=1),
+            ConvLayer("c2", 12, 16, 16, kernel=3, padding=1),  # repeat ↓
+            ConvLayer("c3", 12, 16, 16, kernel=3, padding=1),
+            ConvLayer("big", 12, 16, 16, kernel=9, padding=4)]
     recs = autotune.tune_network(topo)
     assert set(recs) == {"c1", "c2", "c3", "big"}
     # K=9 > MAX_NATIVE_K runs the kernel-tiled path: no cache record
@@ -273,14 +273,21 @@ def test_tune_network_sweep_and_consumption(tmp_path):
     assert recs["c2"]["key"] == recs["c3"]["key"]
     assert recs["c2"] is recs["c3"]
     assert recs["c1"]["key"] != recs["c2"]["key"]
-    # the records land where ops.conv2d looks them up (kernel-seen shape)
-    knobs = autotune.knobs_for((1, 14, 14, 3), (3, 3, 3, 4), stride=1,
+    # the records land where ops.conv2d looks them up (kernel-seen
+    # shape; c1's 27 lanes of taps fold into a 1x1, DESIGN.md §13)
+    knobs = autotune.knobs_for((1, 12, 12, 27), (1, 1, 27, 16), stride=1,
                                pad=0)
     assert knobs is not None
     assert knobs["tile_h"] == recs["c1"]["tile_h"]
-    # ... and where NetworkPlan(use_autotune_cache=True) looks too
+    knobs = autotune.knobs_for((1, 14, 14, 16), (3, 3, 16, 16), stride=1,
+                               pad=0)
+    assert knobs is not None
+    assert knobs["tile_h"] == recs["c2"]["tile_h"]
+    # ... and where NetworkPlan(use_autotune_cache=True) looks too (it
+    # plans the unfolded layers, so it finds c2's record, not c1's)
     plan = NetworkPlan.build(topo[:3], use_autotune_cache=True)
-    assert plan.steps[0].plan.dataflow == recs["c1"]["dataflow"]
+    assert plan.steps[1].plan.dataflow == recs["c2"]["dataflow"]
+    assert plan.steps[1].plan.tile_h == recs["c2"]["tile_h"]
 
 
 def test_tune_network_sharded_namespace():

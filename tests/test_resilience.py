@@ -144,12 +144,14 @@ def test_fused_group_failure_demotes_to_per_layer():
     from repro.core.model import ConvLayer
     from repro.models import layers as L
     from repro.models.base import init_params
-    net = [ConvLayer("c0", 12, 3, 4, 3, 1, 1),
-           ConvLayer("c1", 12, 4, 6, 3, 1, 1),      # pool 2/2 -> 6
-           ConvLayer("c2", 6, 6, 8, 3, 1, 1)]
+    # 16 channels in: narrower convs fold their taps and never fuse
+    # (DESIGN.md §13), so there would be no group to fail
+    net = [ConvLayer("c0", 12, 16, 16, 3, 1, 1),
+           ConvLayer("c1", 12, 16, 16, 3, 1, 1),    # pool 2/2 -> 6
+           ConvLayer("c2", 6, 16, 8, 3, 1, 1)]
     p = init_params(L.cnn_params_from_layers(net),
                     jax.random.PRNGKey(0))
-    x = jnp.asarray(RNG.standard_normal((2, 12, 12, 3)), jnp.float32)
+    x = jnp.asarray(RNG.standard_normal((2, 12, 12, 16)), jnp.float32)
     want = L.cnn_apply_from_layers(p, net, x)       # per-layer pallas
     with faults.lowering_failure("fused") as fault:
         got = L.cnn_apply_from_layers(p, net, x, fused=True)

@@ -5,8 +5,9 @@ compile for the chip catches a block the compiler cannot tile, a plan
 that overflows VMEM, or a Pallas API the installed JAX no longer has.
 These tests compile, for one chip of a ``v5e:2x2`` topology described
 without the chip, the kernels of the VGG-16 serving path at published
-widths (with the default and the tuner-chosen plans), the weight-grad
-kernel and the int8 kernel.  Nothing runs: they prove compilation, not
+widths (with the default and the tuner-chosen plans), the tap-folded
+first convs of VGG-16 and U-Net, the weight-grad kernel and the int8
+kernel.  Nothing runs: they prove compilation, not
 results or times.
 
 The topology is described inside a fixture, never at import: only one
@@ -128,6 +129,43 @@ def test_conv_kernel_carries_its_layer_name(one_chip, monkeypatch,
                if 'custom_call_target="tpu_custom_call"' in ln]
     assert kernels and all(k.startswith(f"%{layer}.") for k in kernels), \
         kernels
+
+
+# first convs whose taps fold into the lanes (ops.tap_fold), as their
+# layers see them: (layer, batch, size, C_in, C_out)
+FOLDED = {
+    "vgg16_conv1_b32": ("conv1", 32, 224, 3, 64),
+    "vgg16_conv1_b1": ("conv1", 1, 224, 3, 64),
+    "unet_enc0a_b8": ("enc0a", 8, 512, 1, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FOLDED))
+@pytest.mark.parametrize("plan", ["default", "tuned"])
+def test_folded_first_conv_compiles(one_chip, monkeypatch, case, plan):
+    """The tap-folded first conv (XLA im2col, then the 1x1 TrIM kernel)
+    compiles at published size with the default and the tuner-chosen
+    plan, and its one kernel keeps the layer's name."""
+    from repro.core.model import ConvLayer
+    from repro.kernels import ops, runtime
+    monkeypatch.setattr(runtime, "on_tpu", lambda: True)
+    layer, n, hw, cin, cout = FOLDED[case]
+    if plan == "tuned":
+        autotune.tune_network([ConvLayer(layer, hw, cin, cout, kernel=3,
+                                         padding=1)], n=n)
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,  # noqa
+                                             sharding=one_chip)
+
+    def fwd(x, w, b):
+        return ops.conv2d(x, w, bias=b, activation="relu", layer=layer)
+
+    text = jax.jit(fwd).lower(sds((n, hw, hw, cin)), sds((3, 3, cin, cout)),
+                              sds((cout,))).compile().as_text()
+    kernels = [ln.split("=", 1)[0].strip() for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(kernels) == 1 and kernels[0].startswith(f"%{layer}."), \
+        kernels
+    assert ops.tap_folded()[layer] == 9 * cin
 
 
 @pytest.mark.xfail(strict=True, raises=NotImplementedError,
