@@ -203,16 +203,11 @@ def conv_hillclimb(name: str, dataflows=("carry", "halo"), *,
     from repro.core import autotune
     from repro.core.conv_plan import KERNEL_VMEM_BUDGET, ConvPlan
     from repro.core.roofline import conv_plan_roofline
-    from repro.kernels.ops import kernel_input_shape
+    from repro.core.netplan import layer_kernel_problem
     layer = _conv_layer(name)
-    w_shape = (layer.kernel, layer.kernel,
-               layer.in_channels // layer.groups, layer.out_channels)
-    # sweep (and key) the problem ops.conv2d actually runs: the 'same'
-    # pre-pad folded into the input shape — asymmetric for stride > 1,
-    # NOT the layer's symmetric paper padding — with residual pad 0
-    x_shape, pad = kernel_input_shape(
-        (1, layer.ifmap, layer.ifmap, layer.in_channels), layer.kernel,
-        layer.stride, "same" if layer.padding else "valid")
+    # sweep (and key) the problem ops.conv2d actually runs: the layer's
+    # padding folded into the input shape, residual pad 0
+    x_shape, pad, w_shape, _ = layer_kernel_problem(layer)
     baseline = ConvPlan.build(x_shape, w_shape, stride=layer.stride,
                               pad=pad, groups=layer.groups)
     base_t = conv_plan_roofline(layer.name, baseline).step_time_s

@@ -2,7 +2,8 @@
 tiling, roofline."""
 
 from repro.core.conv_plan import (  # noqa: F401
-    ConvPlan, Conv1dPlan, WeightGradPlan, input_grad_geometry,
+    ConvPlan, Conv1dPlan, DepthwisePlan, WeightGradPlan,
+    input_grad_geometry,
     slice_reads_per_channel,
 )
 from repro.core.conv_shard import (  # noqa: F401
@@ -21,7 +22,7 @@ from repro.core.model import (  # noqa: F401
     ConvLayer, GraphNode, HWConfig, TRIM, TRIM_3D,
     ifmap_reads_per_channel, ifmap_overhead_pct, fig1_curve,
     layer_accesses, compare_layer, fig6, vgg16_layers, alexnet_layers,
-    mobilenet_layers, resnet18_graph, unet_graph,
+    mobilenet_layers, mobilenet_v1_layers, resnet18_graph, unet_graph,
 )
 from repro.core.dataflow import (  # noqa: F401
     TrimSliceSim, SliceStats, core_conv, reference_conv2d_valid,

@@ -43,12 +43,13 @@ from __future__ import annotations
 
 import contextlib
 import json
+import math
 import os
 import time
 import warnings
 
 from repro.core.conv_plan import (KERNEL_VMEM_BUDGET, ConvPlan,
-                                   input_grad_geometry)
+                                   DepthwisePlan, input_grad_geometry)
 from repro.core.roofline import conv_plan_roofline, dtype_width
 
 
@@ -471,6 +472,107 @@ def tune(x_shape, w_shape, *, stride: int = 1, pad: int = 0,
 
 
 # ---------------------------------------------------------------------------
+# The depthwise VPU kernel (DESIGN.md §14): its own knobs and keys
+# ---------------------------------------------------------------------------
+
+DEPTHWISE_OP = "conv2d_dw"
+
+
+def depthwise_key(x_shape, k: int, *, stride: int = 1,
+                  dtype: str = "float32",
+                  backend: str | None = None) -> str:
+    """Cache key of one depthwise problem (``x_shape`` padded, as the
+    kernel sees it) in the ``conv2d_dw:`` namespace."""
+    return make_key(x_shape, (k, k, 1, x_shape[3]), stride=stride, pad=0,
+                    groups=x_shape[3], dtype=dtype, backend=backend,
+                    op=DEPTHWISE_OP)
+
+
+def depthwise_knobs_for(x_shape, k: int, *, stride: int = 1,
+                        dtype: str = "float32",
+                        backend: str | None = None,
+                        path: str | None = None) -> dict | None:
+    """The cached (validated) ``tile_h``/``tile_c``/``tile_n`` of one
+    depthwise problem, or None — the lookup ``ops.conv2d`` makes for a
+    conv it runs on the depthwise kernel.  Honors
+    ``REPRO_CONV_AUTOTUNE=0``; a record whose knobs do not build a plan
+    within the VMEM budget is a miss and one warning."""
+    if os.environ.get(AUTOTUNE_ENV, "1") == "0":
+        return None
+    key = depthwise_key(x_shape, k, stride=stride, dtype=dtype,
+                        backend=backend)
+    rec = lookup(key, path)
+    if rec is None:
+        return None
+    try:
+        plan = DepthwisePlan.build(x_shape, k, stride=stride,
+                                   dtype_bytes=dtype_width(dtype),
+                                   tile_h=rec["tile_h"],
+                                   tile_c=rec["tile_c"],
+                                   tile_n=rec["tile_n"])
+        if plan.vmem_resident_bytes > KERNEL_VMEM_BUDGET:
+            raise ValueError(f"resident {plan.vmem_resident_bytes} > "
+                             f"VMEM budget {KERNEL_VMEM_BUDGET}")
+    except (KeyError, TypeError, ValueError) as e:
+        _reject(key, f"knobs infeasible for current geometry: {e!r}", path)
+        return None
+    return rec
+
+
+def candidate_depthwise_knobs(x_shape, k: int, *, stride: int = 1,
+                              dtype_bytes: int = 4,
+                              vmem_bytes: int = KERNEL_VMEM_BUDGET
+                              ) -> list[DepthwisePlan]:
+    """VMEM-feasible depthwise plans: strips that divide the output rows
+    (no row is computed only to be dropped), channel tiles of whole
+    128-lane multiples or all of C, and powers of two of images."""
+    base = DepthwisePlan.build(x_shape, k, stride=stride,
+                               dtype_bytes=dtype_bytes)
+    n, c = x_shape[0], base.c
+    h_ticks = [d for d in range(1, base.h_out + 1) if base.h_out % d == 0]
+    c_ticks = sorted({t for t in (128, 256, 512, c)
+                      if c % t == 0 and (t % 128 == 0 or t == c)})
+    n_ticks = [t for t in (1, 2, 4, 8, 16, 32) if n % t == 0]
+    plans = []
+    for th in h_ticks:
+        for tc in c_ticks:
+            for tn in n_ticks:
+                try:
+                    plan = DepthwisePlan.build(
+                        x_shape, k, stride=stride, dtype_bytes=dtype_bytes,
+                        tile_h=th, tile_c=tc, tile_n=tn)
+                except ValueError:
+                    continue
+                if plan.vmem_resident_bytes <= vmem_bytes:
+                    plans.append(plan)
+    return plans
+
+
+def tune_depthwise(x_shape, k: int, *, stride: int = 1,
+                   dtype: str = "float32", dtype_bytes: int | None = None,
+                   backend: str | None = None, write: bool = True,
+                   path: str | None = None) -> dict:
+    """Tune one depthwise problem by the plan's model
+    (:attr:`DepthwisePlan.model_step_time_s`; ties go to fewer grid
+    steps) and (by default) persist the winner under its
+    ``conv2d_dw:`` key."""
+    plans = candidate_depthwise_knobs(
+        x_shape, k, stride=stride,
+        dtype_bytes=_resolve_bytes(dtype_bytes, dtype))
+    if not plans:
+        raise ValueError(f"no feasible depthwise plan for {x_shape}, K={k}")
+    best = min(plans, key=lambda p: (p.model_step_time_s,
+                                     math.prod(p.grid), p.tile_n))
+    record = dict(tile_h=best.tile_h, tile_c=best.tile_c,
+                  tile_n=best.tile_n, source="model",
+                  model_step_time_s=best.model_step_time_s)
+    if write:
+        store(depthwise_key(x_shape, k, stride=stride, dtype=dtype,
+                            backend=backend), record, path)
+    return record
+
+
+# ---------------------------------------------------------------------------
 # Backward shapes (DESIGN.md §5)
 # ---------------------------------------------------------------------------
 
@@ -664,7 +766,9 @@ def tune_network(network="vgg16", *, n: int = 1, dtype: str = "float32",
     a shard grid the records land under the ``conv2d_shard:`` namespace
     instead.  A layer whose taps ``ops.conv2d`` folds into the lanes
     (``ops.tap_fold``) is tuned, and keyed, as the 1x1 problem it runs
-    as.  Layers sharing a shape (VGG-16's repeated blocks) are
+    as; a layer it runs on the depthwise VPU kernel
+    (``ops.runs_depthwise``) by :func:`tune_depthwise`, under its
+    ``conv2d_dw:`` key.  Layers sharing a shape (VGG-16's repeated blocks) are
     tuned once; layers with ``K > MAX_NATIVE_K`` (AlexNet's 11x11) run
     on the kernel-tiled path that never consults the cache and are
     recorded as skipped.  ``include_backward`` additionally seeds both
@@ -676,7 +780,7 @@ def tune_network(network="vgg16", *, n: int = 1, dtype: str = "float32",
     key written (or ``{"skipped": reason}``).
     """
     from repro.core.netplan import layer_kernel_problem, network_layers
-    from repro.kernels.ops import MAX_NATIVE_K, tap_fold
+    from repro.kernels.ops import MAX_NATIVE_K, runs_depthwise, tap_fold
     sharded = batch_shards > 1 or spatial_shards > 1
     if measure and sharded:
         raise ValueError(
@@ -700,6 +804,23 @@ def tune_network(network="vgg16", *, n: int = 1, dtype: str = "float32",
         # padding the execution path cannot reproduce)
         x_shape, pad, w_shape, _ = layer_kernel_problem(layer, n=n)
         stride = layer.stride
+        if not sharded and op == "conv2d" \
+                and runs_depthwise(x_shape, w_shape, layer.groups, dtype):
+            key = depthwise_key(x_shape, layer.kernel, stride=stride,
+                                dtype=dtype, backend=backend)
+            if key not in seen:
+                seen[key] = dict(tune_depthwise(
+                    x_shape, layer.kernel, stride=stride, dtype=dtype,
+                    dtype_bytes=dtype_bytes, backend=backend, write=write,
+                    path=path), key=key)
+            results[layer.name] = seen[key]
+            if include_backward and "backward" not in seen[key]:
+                seen[key]["backward"] = tune_backward(
+                    x_shape, w_shape, stride=stride, pad=pad,
+                    groups=layer.groups, dtype=dtype,
+                    dtype_bytes=dtype_bytes, backend=backend, write=write,
+                    path=path)
+            continue
         # the single-device float path runs a tap-folded layer as its
         # 1x1 problem (ops.tap_fold), and looks that problem up
         folded = None if sharded or op != "conv2d" else \

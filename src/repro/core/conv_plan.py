@@ -52,6 +52,12 @@ This module owns all of it (DESIGN.md §3):
     the (batch, strip) sweep.  The plan owns the strip/grid/padded
     layouts and the analytical HBM bytes of that schedule.
 
+* :class:`DepthwisePlan` — a depthwise conv (one filter per channel) on
+  the VPU kernel ``kernels/trim_depthwise.py`` (DESIGN.md §14): strips
+  of output rows with the ``K - stride`` carried input rows, channel
+  and image tiles, its VMEM model and its HBM bytes, which
+  ``core/autotune.py`` ranks its knobs by.
+
 * :class:`Conv1dPlan` — the 1D image of the same plan, consumed by
   ``kernels/trim_conv1d.py``.
 
@@ -71,7 +77,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 
-from repro.core.roofline import dtype_width
+from repro.core.roofline import HBM_BW, dtype_width
 
 # TPU v5e has 128 MiB of VMEM per TensorCore; a Mosaic kernel may use
 # its scoped limit, 16 MiB unless the kernel asks for more.  Every TrIM
@@ -821,6 +827,204 @@ class WeightGradPlan:
                     vmem_resident_bytes=self.vmem_resident_bytes,
                     flops=self.flops, hbm_total=t["total"],
                     arithmetic_intensity=self.arithmetic_intensity())
+
+
+# ---------------------------------------------------------------------------
+# Depthwise 2D plan (the VPU kernel, kernels/trim_depthwise.py)
+# ---------------------------------------------------------------------------
+
+#: Fixed cost of one Pallas grid step on a TPU (pipeline bookkeeping and
+#: DMA issue), the term that lets the depthwise tuner weigh many small
+#: blocks against fewer large ones.
+GRID_STEP_S = 0.35e-6
+
+
+def _divisors(m: int) -> list[int]:
+    return [d for d in range(1, m + 1) if m % d == 0]
+
+
+@dataclass(frozen=True)
+class DepthwisePlan:
+    """Geometry + traffic plan of one depthwise conv (``groups == C``,
+    one filter per channel) on the VPU kernel (DESIGN.md §14).
+
+    ``(n, h, w, c)`` is the input as the kernel sees it, already padded.
+    A strip is ``tile_h`` *output* rows, ``stride * tile_h`` fresh input
+    rows; the ``carry_rows`` = ``K - stride`` input rows above them come
+    from a VMEM scratch the previous strip wrote (the shadow registers;
+    the first strip of a sweep reads them from the input's ``head``
+    rows).  Channels ride the lanes in tiles of ``tile_c``; ``tile_n``
+    images share a grid step.  A strided conv reads its columns with
+    strided loads, which the chip's compiler gives one lane tile at
+    most: ``tile_c <= 128`` there.
+    """
+
+    n: int
+    h: int
+    w: int
+    c: int
+    k: int
+    stride: int = 1
+    dtype_bytes: int = 4
+    tile_h: int = 8            # output rows per strip
+    tile_c: int = 128          # channels per grid step
+    tile_n: int = 1            # images per grid step
+    vmem_budget: int = KERNEL_VMEM_BUDGET
+
+    def __post_init__(self):
+        if self.h_out < 1 or self.w_out < 1:
+            raise ValueError("empty output: input smaller than kernel")
+        if self.tile_h < 1:
+            raise ValueError(f"tile_h={self.tile_h} must be >= 1")
+        if self.c % self.tile_c or (self.tile_c % LANES
+                                    and self.tile_c != self.c):
+            raise ValueError(
+                f"tile_c={self.tile_c} must divide C={self.c} and be a "
+                f"multiple of {LANES} lanes or the whole C")
+        if self.stride > 1 and self.tile_c > LANES:
+            raise ValueError(
+                f"tile_c={self.tile_c}: strided loads take at most "
+                f"{LANES} lanes")
+        if self.n % self.tile_n:
+            raise ValueError(f"tile_n={self.tile_n} must divide "
+                             f"N={self.n}")
+        # one strip covering every output row is the largest there is
+        if self.tile_h > self.h_out:
+            object.__setattr__(self, "tile_h", self.h_out)
+        if self.g_tiles > 1 and self.strip_rows < self.carry_rows:
+            raise ValueError(f"tile_h={self.tile_h} reads fewer fresh rows "
+                             f"than the {self.carry_rows} carried")
+
+    @classmethod
+    def build(cls, x_shape, k: int, *, stride: int = 1,
+              dtype_bytes: int = 4, tile_h: int | None = None,
+              tile_c: int | None = None, tile_n: int | None = None,
+              vmem_budget: int = KERNEL_VMEM_BUDGET) -> "DepthwisePlan":
+        """Plan for the padded input ``x_shape`` ``(N, H, W, C)``.
+        Defaults: one image, 128-lane channel tiles (the whole C when it
+        is not a multiple of 128) and the tallest strip that divides the
+        output rows and fits ``vmem_budget``.  A strided conv over more
+        than one lane tile of channels, but not a whole number of them,
+        runs on whole tiles: ``c`` is then rounded up (the kernel pads
+        zero channels and drops them)."""
+        n, h, w, c = x_shape
+        dtype_bytes = resolve_dtype_bytes(dtype_bytes)
+        if stride > 1 and c > LANES and c % LANES:
+            c = -(-c // LANES) * LANES
+        if tile_c is None:
+            tile_c = LANES if c % LANES == 0 else c
+
+        def plan(th: int) -> "DepthwisePlan":
+            return cls(n=n, h=h, w=w, c=c, k=k, stride=stride,
+                       dtype_bytes=dtype_bytes, tile_h=th, tile_c=tile_c,
+                       tile_n=tile_n or 1, vmem_budget=vmem_budget)
+
+        if tile_h is None:
+            h_out = (h - k) // stride + 1
+            fits = [d for d in _divisors(h_out)
+                    if (d * stride >= k - stride or d == h_out)
+                    and plan(d).vmem_resident_bytes <= vmem_budget]
+            tile_h = max(fits) if fits else h_out
+        return plan(tile_h)
+
+    # -- geometry ----------------------------------------------------------
+
+    @property
+    def h_out(self) -> int:
+        return (self.h - self.k) // self.stride + 1
+
+    @property
+    def w_out(self) -> int:
+        return (self.w - self.k) // self.stride + 1
+
+    @property
+    def carry_rows(self) -> int:
+        """Input rows a strip shares with its predecessor."""
+        return max(self.k - self.stride, 0)
+
+    @property
+    def strip_rows(self) -> int:
+        """Fresh input rows of one strip."""
+        return self.stride * self.tile_h
+
+    @property
+    def g_tiles(self) -> int:
+        return math.ceil(self.h_out / self.tile_h)
+
+    @property
+    def main_rows(self) -> int:
+        """Input rows after the head, in whole strips."""
+        return self.g_tiles * self.strip_rows
+
+    @property
+    def grid(self) -> tuple[int, int, int]:
+        """(image tiles, channel tiles, strips) — strips innermost, so
+        the carry is valid within one (images, channels) sweep."""
+        return (self.n // self.tile_n, self.c // self.tile_c, self.g_tiles)
+
+    @property
+    def main_block(self) -> tuple[int, int, int, int]:
+        return (self.tile_n, self.strip_rows, self.w, self.tile_c)
+
+    @property
+    def head_block(self) -> tuple[int, int, int, int]:
+        return (self.tile_n, self.carry_rows, self.w, self.tile_c)
+
+    @property
+    def out_block(self) -> tuple[int, int, int, int]:
+        return (self.tile_n, self.tile_h, self.w_out, self.tile_c)
+
+    @property
+    def padded_output_shape(self) -> tuple[int, int, int, int]:
+        return (self.n, self.g_tiles * self.tile_h, self.w_out, self.c)
+
+    @property
+    def vmem_resident_bytes(self) -> int:
+        """Modeled VMEM of one grid step, every buffer padded to the
+        (sublane, lane) tile: the double-buffered strip, head rows,
+        weight taps, bias row and output block; the carry scratch (only
+        with more than one strip); and the row temporaries of the taps
+        (loaded window row, product, f32 accumulator, epilogue)."""
+        db = self.dtype_bytes
+        total = (2 * vmem_tile_bytes(self.main_block, db)
+                 + 2 * vmem_tile_bytes((self.k * self.k, self.tile_c), db)
+                 + 2 * vmem_tile_bytes((1, self.tile_c), db)
+                 + 2 * vmem_tile_bytes(self.out_block, db)
+                 + 4 * vmem_tile_bytes((self.w_out, self.tile_c), 4))
+        if self.carry_rows:
+            total += 2 * vmem_tile_bytes(self.head_block, db)
+            if self.g_tiles > 1:
+                total += vmem_tile_bytes(self.head_block, db)
+        return total
+
+    # -- arithmetic and traffic -------------------------------------------
+
+    @property
+    def flops(self) -> int:
+        return 2 * self.n * self.h_out * self.w_out * self.c * self.k ** 2
+
+    def hbm_bytes(self) -> dict:
+        """HBM bytes of the schedule: every input row read once (the
+        carry re-reads nothing), the taps and bias once per (image tile,
+        channel tile) sweep, the output written once."""
+        db = self.dtype_bytes
+        in_bytes = (self.n * (self.carry_rows + self.main_rows) * self.w
+                    * self.c * db)
+        w_bytes = (self.k * self.k + 1) * self.c * db * self.grid[0]
+        out_bytes = self.n * self.h_out * self.w_out * self.c * db
+        return dict(input=in_bytes, weights=w_bytes, output=out_bytes,
+                    total=in_bytes + w_bytes + out_bytes)
+
+    @property
+    def model_step_time_s(self) -> float:
+        """What the tuner ranks by: the HBM time of the schedule (the
+        VPU's K*K multiply-adds per output take far less), the first
+        block's copy that nothing overlaps, and the fixed cost of every
+        grid step."""
+        first = (vmem_tile_bytes(self.main_block, self.dtype_bytes)
+                 / HBM_BW)
+        return (self.hbm_bytes()["total"] / HBM_BW + first
+                + math.prod(self.grid) * GRID_STEP_S)
 
 
 # ---------------------------------------------------------------------------

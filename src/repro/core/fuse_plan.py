@@ -77,15 +77,6 @@ MAX_FUSED_K = 8
 FUSED_VMEM_BUDGET = KERNEL_VMEM_LIMIT // 2
 
 
-def _same_pads(size: int, k: int, s: int) -> tuple[int, int]:
-    """TF-style asymmetric 'same' padding — must mirror
-    ``kernels/ops._same_pads`` exactly (the fused kernel's in-kernel
-    padding has to reproduce the per-layer pre-pad bit-for-bit)."""
-    out = -(-size // s)
-    total = max((out - 1) * s + k - size, 0)
-    return total // 2, total - total // 2
-
-
 # ---------------------------------------------------------------------------
 # Static per-stage description + strip geometry
 # ---------------------------------------------------------------------------
@@ -101,7 +92,7 @@ class FusedStage:
     the conv output, ``pool_*`` the pooled output.  Rows outside the
     valid extent (``h_in`` / ``h_conv`` / ``h_pool``) are zeros — the
     kernel's post-pool mask makes them so, and they double as the next
-    stage's 'same' H-padding.
+    stage's H-padding.
     """
 
     name: str
@@ -112,7 +103,7 @@ class FusedStage:
     cout: int
     kernel: int
     stride: int
-    pad_lo: int          # 'same' H/W pad (asymmetric), 0 for 'valid'
+    pad_lo: int          # H/W pad: the layer's symmetric padding
     pad_hi: int
     h_conv: int          # valid conv output rows (== layer.out_size)
     w_conv: int
@@ -157,12 +148,14 @@ class FusedStage:
 
 def _stage_problems(layers, pools):
     """Per-layer (layer, pad_lo, pad_hi, h_conv, ps, pw, h_pool) tuples,
-    validating each layer is 'same'/'valid'-executable."""
+    validating each layer's padding is executable.  The pads are the
+    layer's symmetric ``(p, p)``, as the per-layer pre-pad
+    (``ops.conv_pads``) applies them: the fused kernel's in-kernel
+    padding has to reproduce it bit-for-bit."""
     probs = []
     for layer, (ps, pw) in zip(layers, pools):
-        layer_kernel_problem(layer)     # raises if not 'same'/'valid'
-        lo, hi = (_same_pads(layer.ifmap, layer.kernel, layer.stride)
-                  if layer.padding else (0, 0))
+        layer_kernel_problem(layer)     # raises on pads it cannot run
+        lo = hi = layer.padding
         h_conv = layer.out_size
         probs.append((layer, lo, hi, h_conv, ps, pw,
                       pooled_out_size(h_conv, ps, pw)))
@@ -175,7 +168,7 @@ def _strip_geometry(probs, strip_rows):
 
     A pooled range needs conv rows ``[a*ps, a*ps + (c-1)*ps + pw)``; a
     conv range needs padded-input rows ``[a*s, a*s + (c-1)*s + K)``;
-    un-padding subtracts the top 'same' pad.  The resulting stage-0
+    un-padding subtracts the top pad.  The resulting stage-0
     input range is what one grid step fetches from HBM.
     """
     stages = []

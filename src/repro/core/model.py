@@ -263,6 +263,30 @@ def mobilenet_layers() -> list[ConvLayer]:
     return layers
 
 
+def mobilenet_v1_layers(image: int = 224) -> list[ConvLayer]:
+    """MobileNetV1 at width multiplier 1.0 (Howard et al., arXiv:1704.04861,
+    Table 1): a 3x3/s2 stem 3 -> 32, then 13 depthwise-separable blocks
+    (a depthwise 3x3, ``groups == C``, then a pointwise 1x1), 27 convs
+    in all, every 3x3 padded (1, 1).  Table 1's "s2" on the last
+    depthwise conv at 7x7 is an erratum: it runs at stride 1, as in the
+    reference implementations, so the feature map stays 7x7 for the
+    7x7 average pool.  ``image`` shrinks the input for the CPU tests."""
+    stem = ConvLayer("conv1", image, 3, 32, kernel=3, stride=2, padding=1)
+    layers = [stem]
+    size, c = stem.out_size, 32
+    for i, (cout, s) in enumerate([
+            (64, 1), (128, 2), (128, 1), (256, 2), (256, 1), (512, 2),
+            (512, 1), (512, 1), (512, 1), (512, 1), (512, 1), (1024, 2),
+            (1024, 1)]):
+        dw = ConvLayer(f"dw{i + 1}", size, c, c, kernel=3, stride=s,
+                       padding=1, groups=c)
+        layers.append(dw)
+        size = dw.out_size
+        layers.append(ConvLayer(f"pw{i + 1}", size, c, cout, kernel=1))
+        c = cout
+    return layers
+
+
 # ---------------------------------------------------------------------------
 # DAG topologies (NetworkGraph nodes — core.netplan generalizes the linear
 # chains above to these)

@@ -51,11 +51,12 @@ from repro.core import roofline
 from repro.core.conv_plan import KERNEL_VMEM_LIMIT, ConvPlan
 from repro.core.conv_shard import ShardedConvPlan
 from repro.core.model import (ConvLayer, GraphNode, alexnet_layers,
-                              mobilenet_layers, resnet18_graph, unet_graph,
-                              vgg16_layers)
+                              mobilenet_layers, mobilenet_v1_layers,
+                              resnet18_graph, unet_graph, vgg16_layers)
 
 NETWORKS = {"vgg16": vgg16_layers, "alexnet": alexnet_layers,
-            "mobilenet": mobilenet_layers}
+            "mobilenet": mobilenet_layers,
+            "mobilenet_v1": mobilenet_v1_layers}
 
 # DAG topologies: name -> builder returning list[GraphNode] (topological
 # order).  Linear chains from NETWORKS are also valid NetworkGraph inputs
@@ -70,8 +71,8 @@ RESIDENCY_BUDGET = KERNEL_VMEM_LIMIT // 4
 
 def network_layers(network) -> list[ConvLayer]:
     """Resolve a topology: a name from :data:`NETWORKS` ("vgg16",
-    "alexnet", "mobilenet") or an explicit ``list[ConvLayer]`` passed
-    through unchanged."""
+    "alexnet", "mobilenet", "mobilenet_v1") or an explicit
+    ``list[ConvLayer]`` passed through unchanged."""
     if isinstance(network, str):
         if network not in NETWORKS:
             raise ValueError(
@@ -200,39 +201,41 @@ def pooled_out_size(h_out: int, stride: int, window: int) -> int:
 def layer_kernel_problem(layer: ConvLayer, *, n: int = 1):
     """The conv problem ``ops.conv2d`` actually executes for one
     topology layer: ``(x_shape, pad, w_shape, padding)`` with
-    ``x_shape`` the kernel-seen input (the ``padding`` mode's pre-pad
-    folded in), ``pad`` the residual symmetric padding (0) and
-    ``padding`` the ``ops.conv2d`` argument (``"same"`` for
-    ``layer.padding > 0``, else ``"valid"``).
+    ``x_shape`` the kernel-seen input (the padding applied in HBM),
+    ``pad`` the residual padding (0) and ``padding`` the ``ops.conv2d``
+    argument: ``layer.padding`` itself, the symmetric ``(p, p)`` the
+    layer states (``ops.conv_pads``).
 
     This is the single place the layer -> executed-problem mapping
     lives: ``autotune.tune_network`` keys its records over these shapes,
     ``NetworkPlan(use_autotune_cache=True)`` looks them up over the same
     shapes, and ``models/layers.py cnn_*_from_layers`` run the same
-    ``padding`` mode — so records can never be written under one key and
+    ``padding`` — so records can never be written under one key and
     read under another.
 
-    Raises ``ValueError`` when the layer's symmetric paper padding is
-    not reproduced by that mode (executed output size would differ from
-    ``layer.out_size``) — the execution engine supports
-    'same'-equivalent or zero padding, and anything else must fail
-    loudly instead of silently running a different network.
+    Raises ``ValueError`` for pads the execution path cannot run as the
+    layer states them: a pad below 0 or of K or more (border outputs
+    that read nothing but padding), or any whose executed pads or
+    output size differ from the layer's.
     """
-    from repro.kernels.ops import kernel_input_shape
-    padding = "same" if layer.padding else "valid"
-    x_shape, pad = kernel_input_shape(
-        (n, layer.ifmap, layer.ifmap, layer.in_channels), layer.kernel,
-        layer.stride, padding)
-    out = (x_shape[1] + 2 * pad - layer.kernel) // layer.stride + 1
-    if out != layer.out_size:
+    from repro.kernels.ops import conv_pads, kernel_input_shape
+    p, k = layer.padding, layer.kernel
+    if not 0 <= p < k:
         raise ValueError(
-            f"layer {layer.name}: padding={layer.padding} is not "
-            f"{padding!r}-equivalent (executed output {out} != planned "
-            f"{layer.out_size}); the execution engine runs 'same' or "
-            f"zero padding only")
-    w_shape = (layer.kernel, layer.kernel,
-               layer.in_channels // layer.groups, layer.out_channels)
-    return x_shape, pad, w_shape, padding
+            f"layer {layer.name}: padding={p} with K={k}: the execution "
+            f"engine runs symmetric pads 0 <= p < K only")
+    x_shape, pad = kernel_input_shape(
+        (n, layer.ifmap, layer.ifmap, layer.in_channels), k, layer.stride,
+        p)
+    pads = conv_pads(layer.ifmap, layer.ifmap, k, layer.stride, p)
+    out = (x_shape[1] + 2 * pad - k) // layer.stride + 1
+    if pads != ((p, p), (p, p)) or out != layer.out_size:
+        raise ValueError(
+            f"layer {layer.name}: executed pads {pads} and output {out} "
+            f"differ from the stated padding={p} and output "
+            f"{layer.out_size}")
+    w_shape = (k, k, layer.in_channels // layer.groups, layer.out_channels)
+    return x_shape, pad, w_shape, p
 
 
 # ---------------------------------------------------------------------------

@@ -399,6 +399,7 @@ class ServingEngine:
         """Serving counters + per-replica degradation report.  A replica
         with guard events kept serving on a fallback tier — degraded,
         labeled, never silent (DESIGN.md §9/§10)."""
+        from repro.kernels import ops
         with self._lock:
             per_replica = {
                 name: {"served": self._replica_served[name],
@@ -416,19 +417,20 @@ class ServingEngine:
                 "max_queue_depth": self.recorder.max_queue_depth,
                 "rejected": len(self.recorder.rejected),
                 "replicas": per_replica,
-                "tap_folded": self._tap_folded(),
+                "tap_folded": self._own(ops.tap_folded()),
+                "depthwise": self._own(ops.depthwise()),
             }
 
-    def _tap_folded(self) -> dict:
-        """``{layer: K*K*Cin}`` of the convs traced with their taps
-        folded (``ops.tap_folded``): the topology's layers, or every
-        named conv of the process for an engine without a topology."""
-        from repro.kernels.ops import tap_folded
-        folded = tap_folded()
+    def _own(self, by_layer: dict) -> dict:
+        """A per-layer counter of ``kernels.ops`` (``tap_folded``:
+        ``{layer: K*K*Cin}`` of the convs traced with their taps folded;
+        ``depthwise``: ``{layer: C}`` of those traced on the depthwise
+        VPU kernel) kept to the topology's layers; every named conv of
+        the process for an engine without a topology."""
         if self.topo is None:
-            return folded
+            return by_layer
         names = {layer.name for layer in self.topo}
-        return {k: v for k, v in folded.items() if k in names}
+        return {k: v for k, v in by_layer.items() if k in names}
 
 
 # ---------------------------------------------------------------------------

@@ -53,12 +53,17 @@ from repro.kernels.trim_conv2d import (ACTIVATIONS, trim_conv2d,
                                        trim_conv2d_input_grad,
                                        trim_conv2d_weight_grad)
 from repro.kernels.trim_conv2d_sharded import sharded_conv2d
+from repro.kernels.trim_depthwise import trim_depthwise_conv2d
 
 MAX_NATIVE_K = 8
 
 # {layer name: K*K*C_in} of every named conv traced with its taps folded
 # (``tap_folded``); static per compiled program, filled at trace time
 _TAP_FOLDED: dict[str, int] = {}
+
+# {layer name: C} of every named conv traced on the depthwise VPU kernel
+# (``depthwise``), filled the same way
+_DEPTHWISE: dict[str, int] = {}
 
 
 # ---------------------------------------------------------------------------
@@ -70,6 +75,32 @@ def _same_pads(size: int, k: int, s: int) -> tuple[int, int]:
     out = -(-size // s)
     total = max((out - 1) * s + k - size, 0)
     return total // 2, total - total // 2
+
+
+def conv_pads(h: int, w: int, k: int, stride: int, padding):
+    """``((top, bottom), (left, right))`` zero padding of a conv.
+    ``padding`` is ``"same"`` (XLA's: ``ceil(size/stride)`` outputs,
+    asymmetric where the stride leaves a remainder), ``"valid"``, or an
+    int ``p``: the symmetric ``(p, p)`` a network layer states (a
+    3x3/s2 conv on an even input pads ``(1, 1)`` where 'same' pads
+    ``(0, 1)``)."""
+    if padding == "same":
+        return _same_pads(h, k, stride), _same_pads(w, k, stride)
+    if padding == "valid":
+        return (0, 0), (0, 0)
+    if isinstance(padding, int) and not isinstance(padding, bool) \
+            and padding >= 0:
+        return (padding, padding), (padding, padding)
+    raise ValueError(f"padding={padding!r} must be 'same', 'valid' or a "
+                     "symmetric pad >= 0")
+
+
+def _prepad(x: jax.Array, k: int, stride: int, padding) -> jax.Array:
+    """``x`` zero-padded in HBM as :func:`conv_pads` says."""
+    ph, pw = conv_pads(x.shape[1], x.shape[2], k, stride, padding)
+    if ph == pw == (0, 0):
+        return x
+    return jnp.pad(x, ((0, 0), ph, pw, (0, 0)))
 
 
 @jax.tree_util.register_pytree_node_class
@@ -131,7 +162,7 @@ def pack_conv2d_weights(w: jax.Array, bias: jax.Array | None = None, *,
                         tile_h: int | None = None,
                         dataflow: str | None = None,
                         x_shape=None, stride: int = 1,
-                        padding: str = "same",
+                        padding: str | int = "same",
                         dtype: str | None = None,
                         op: str = "conv2d") -> PackedConv2dWeights:
     """Pad/reshape conv weights to the kernel layout once, at load time.
@@ -191,7 +222,8 @@ def quantize_conv2d_weights(w: jax.Array, bias: jax.Array | None = None, *,
                             tile_h: int | None = None,
                             dataflow: str | None = None,
                             x_shape=None, stride: int = 1,
-                            padding: str = "same") -> PackedConv2dWeights:
+                            padding: str | int = "same"
+                            ) -> PackedConv2dWeights:
     """Quantize + pack conv weights for the int8 route (DESIGN.md §11).
 
     w: f32 (K, K, Cin/groups, Cout); bias: (Cout,) or None.
@@ -275,16 +307,32 @@ def _fold_taps(x: jax.Array, kh: int, kw: int, stride: int,
     return jnp.moveaxis(jax.lax.optimization_barrier(taps), 1, 3)
 
 
-def kernel_input_shape(x_shape, k: int, stride: int, padding: str):
-    """(shape, residual_pad) the Pallas kernel actually sees: 'same'
-    pre-pads in HBM (possibly asymmetric for stride > 1) and calls the
-    kernel with pad=0.  This is the shape autotune cache keys are built
-    over (used by ``benchmarks/hillclimb.py --write-cache``)."""
+def runs_depthwise(x_shape, w_shape, groups: int, dtype) -> bool:
+    """Whether the single-device float path runs a conv on the depthwise
+    VPU kernel (DESIGN.md §14): one ``K x K`` filter per channel
+    (``groups == C_in == C_out > 1``), ``K <= MAX_NATIVE_K``, float
+    inputs.  Grouped convs with more than one channel per group, and
+    depthwise multipliers above 1, stay on the MXU kernel."""
+    k, _, cin_pg, cout = w_shape
+    return (groups > 1 and cin_pg == 1 and groups == x_shape[3] == cout
+            and k <= MAX_NATIVE_K
+            and jnp.issubdtype(jnp.dtype(dtype), jnp.floating))
+
+
+def depthwise() -> dict[str, int]:
+    """``{layer: C}`` of every named conv this process traced on the
+    depthwise VPU kernel (:func:`runs_depthwise`)."""
+    return dict(_DEPTHWISE)
+
+
+def kernel_input_shape(x_shape, k: int, stride: int, padding):
+    """(shape, residual_pad) the Pallas kernel actually sees: the
+    padding (:func:`conv_pads`) is applied in HBM and the kernel called
+    with pad=0.  This is the shape autotune cache keys are built over
+    (used by ``benchmarks/hillclimb.py --write-cache``)."""
     n, h, w, cin = x_shape
-    if padding == "same":
-        ph, pw = _same_pads(h, k, stride), _same_pads(w, k, stride)
-        return (n, h + sum(ph), w + sum(pw), cin), 0
-    return (n, h, w, cin), 0
+    ph, pw = conv_pads(h, w, k, stride, padding)
+    return (n, h + sum(ph), w + sum(pw), cin), 0
 
 
 # ---------------------------------------------------------------------------
@@ -448,8 +496,75 @@ _conv2d_packed_vjp_core.defvjp(_conv2d_packed_vjp_fwd,
                                _conv2d_packed_vjp_bwd)
 
 
+class _DepthwiseConfig(typing.NamedTuple):
+    """Static knobs of one depthwise VPU kernel call."""
+
+    stride: int
+    activation: str | None
+    tile_h: int | None
+    tile_c: int | None
+    tile_n: int | None
+    use_autotune_cache: bool
+    name: str | None = None
+
+
+def _depthwise_call(cfg: _DepthwiseConfig, x, w, bias, activation):
+    return trim_depthwise_conv2d(x, w, bias, stride=cfg.stride,
+                                 tile_h=cfg.tile_h, tile_c=cfg.tile_c,
+                                 tile_n=cfg.tile_n, activation=activation,
+                                 name=cfg.name)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _depthwise_vjp_core(cfg: _DepthwiseConfig, x, w, bias):
+    """Primal: the depthwise VPU kernel, epilogue fused."""
+    return _depthwise_call(cfg, x, w, bias, cfg.activation)
+
+
+def _depthwise_vjp_fwd(cfg: _DepthwiseConfig, x, w, bias):
+    z = _depthwise_call(cfg, x, w, bias, None)
+    y = z if cfg.activation is None else ACTIVATIONS[cfg.activation](z)
+    return y, (x, w, bias, z if cfg.activation is not None else None)
+
+
+def _depthwise_vjp_bwd(cfg: _DepthwiseConfig, res, gy):
+    # both cotangents run on the grouped TrIM backward kernels
+    x, w, bias, z = res
+    conv = _ConvVjpConfig(stride=cfg.stride, pad=0, groups=x.shape[3],
+                          activation=cfg.activation, tile_h=None,
+                          tile_cout=None, dataflow="carry",
+                          use_autotune_cache=cfg.use_autotune_cache)
+    dx, dw, db, _ = _conv_grads(conv, x, w, bias, z, gy)
+    return dx, dw, db
+
+
+_depthwise_vjp_core.defvjp(_depthwise_vjp_fwd, _depthwise_vjp_bwd)
+
+
+def _depthwise_pallas(x: jax.Array, w: jax.Array, *, stride: int,
+                      bias: jax.Array | None, activation: str | None,
+                      use_autotune_cache: bool,
+                      name: str | None) -> jax.Array:
+    """The depthwise VPU kernel over the pre-padded ``x``, its knobs
+    from the ``conv2d_dw`` autotune records (defaults without one)."""
+    knobs = dict(tile_h=None, tile_c=None, tile_n=None)
+    if use_autotune_cache:
+        rec = autotune.depthwise_knobs_for(x.shape, w.shape[0],
+                                           stride=stride,
+                                           dtype=str(x.dtype))
+        if rec is not None:
+            knobs = {key: rec[key] for key in knobs}
+    cfg = _DepthwiseConfig(stride=stride, activation=activation,
+                           use_autotune_cache=use_autotune_cache,
+                           name=name, **knobs)
+    out = _depthwise_vjp_core(cfg, x, w, bias)
+    if name is not None:
+        _DEPTHWISE[name] = x.shape[3]
+    return out
+
+
 def conv2d(x: jax.Array, w, *, stride: int = 1,
-           padding: str = "same", impl: str = "pallas",
+           padding: str | int = "same", impl: str = "pallas",
            feature_group_count: int = 1, bias: jax.Array | None = None,
            activation: str | None = None,
            tile_h: int | None = None, tile_cout: int | None = None,
@@ -461,8 +576,10 @@ def conv2d(x: jax.Array, w, *, stride: int = 1,
 
     x: (N, H, W, Cin); w: (K, K, Cin/groups, Cout) or a
     :class:`PackedConv2dWeights`; bias: (Cout,) or None;
-    ``feature_group_count=Cin`` gives depthwise convolution.  The Pallas
-    path fuses the epilogue into the kernel's accumulator store.
+    ``padding``: ``"same"``, ``"valid"`` or a symmetric int pad
+    (:func:`conv_pads`); ``feature_group_count=Cin`` gives depthwise
+    convolution.  The Pallas path fuses the epilogue into the kernel's
+    accumulator store.
 
     Tile/dataflow knobs left as ``None`` are filled from the autotune
     cache (``core/autotune.py``) when a record exists for this problem
@@ -564,21 +681,25 @@ def conv2d(x: jax.Array, w, *, stride: int = 1,
 
 
 def _conv2d_pallas(x: jax.Array, w: jax.Array, *, stride: int,
-                   padding: str, feature_group_count: int,
+                   padding: str | int, feature_group_count: int,
                    bias: jax.Array | None, activation: str | None,
                    tile_h: int | None, tile_cout: int | None,
                    dataflow: str | None,
                    use_autotune_cache: bool,
                    name: str | None = None) -> jax.Array:
-    """The single-device Pallas tier: 'same' pre-pad, tap folding
-    (:func:`tap_fold`), autotune-cache knob fill, differentiable kernel
-    core — or the K > MAX_NATIVE_K adder-tree decomposition (every
-    sub-kernel named ``name``)."""
+    """The single-device Pallas tier: the pre-pad (:func:`conv_pads`),
+    then the depthwise VPU kernel (:func:`runs_depthwise`; the MXU
+    kernel's tile and dataflow knobs do not apply to it), or tap
+    folding (:func:`tap_fold`), autotune-cache knob fill,
+    differentiable kernel core — or the K > MAX_NATIVE_K adder-tree
+    decomposition (every sub-kernel named ``name``)."""
     k = w.shape[0]
-    if padding == "same":
-        ph, pw = _same_pads(x.shape[1], k, stride), \
-            _same_pads(x.shape[2], k, stride)
-        x = jnp.pad(x, ((0, 0), ph, pw, (0, 0)))
+    x = _prepad(x, k, stride, padding)
+    if runs_depthwise(x.shape, w.shape, feature_group_count, x.dtype):
+        return _depthwise_pallas(x, w, stride=stride, bias=bias,
+                                 activation=activation,
+                                 use_autotune_cache=use_autotune_cache,
+                                 name=name)
     folded = tap_fold(x.shape, w.shape, stride, feature_group_count)
     x_shape, w_shape = folded or (x.shape, w.shape)
     if k <= MAX_NATIVE_K:
@@ -643,7 +764,7 @@ def _conv2d_pallas(x: jax.Array, w: jax.Array, *, stride: int,
 
 
 def _conv2d_sharded(x: jax.Array, w: jax.Array, *, stride: int,
-                    padding: str, feature_group_count: int,
+                    padding: str | int, feature_group_count: int,
                     bias: jax.Array | None, activation: str | None,
                     tile_h: int | None, tile_cout: int | None,
                     dataflow: str | None, use_autotune_cache: bool,
@@ -659,10 +780,7 @@ def _conv2d_sharded(x: jax.Array, w: jax.Array, *, stride: int,
         raise ValueError(
             f"sharded conv2d supports K <= {MAX_NATIVE_K}; decompose "
             "large kernels before sharding (ops.conv2d adder-tree path)")
-    if padding == "same":
-        ph, pw = _same_pads(x.shape[1], k, stride), \
-            _same_pads(x.shape[2], k, stride)
-        x = jnp.pad(x, ((0, 0), ph, pw, (0, 0)))
+    x = _prepad(x, k, stride, padding)
     ba, bs, sa, ss = resolve_conv_mesh(mesh, rules)
     if use_autotune_cache and (tile_h is None or tile_cout is None
                                or dataflow is None):
@@ -694,7 +812,7 @@ def _conv2d_sharded(x: jax.Array, w: jax.Array, *, stride: int,
 
 
 def _conv2d_packed(x: jax.Array, pk: PackedConv2dWeights, *,
-                   stride: int, padding: str, impl: str,
+                   stride: int, padding: str | int, impl: str,
                    bias: jax.Array | None, activation: str | None,
                    tile_h: int | None, dataflow: str | None,
                    use_autotune_cache: bool,
@@ -747,16 +865,13 @@ def _conv2d_packed(x: jax.Array, pk: PackedConv2dWeights, *,
 
 
 def _conv2d_packed_pallas(x: jax.Array, pk: PackedConv2dWeights, *,
-                          stride: int, padding: str,
+                          stride: int, padding: str | int,
                           activation: str | None, tile_h: int | None,
                           dataflow: str | None,
                           use_autotune_cache: bool,
                           name: str | None = None) -> jax.Array:
     k = pk.w.shape[0]
-    if padding == "same":
-        ph, pw = _same_pads(x.shape[1], k, stride), \
-            _same_pads(x.shape[2], k, stride)
-        x = jnp.pad(x, ((0, 0), ph, pw, (0, 0)))
+    x = _prepad(x, k, stride, padding)
     tile_h = tile_h if tile_h is not None else pk.tile_h
     dataflow = dataflow if dataflow is not None else pk.dataflow
     if use_autotune_cache and (tile_h is None or dataflow is None):
@@ -818,8 +933,9 @@ def _q8_forward(x_q: jax.Array, pk: PackedConv2dWeights, *, stride: int,
 
 
 def _conv2d_q8(x: jax.Array, pk: PackedConv2dWeights, *, stride: int,
-               padding: str, activation: str | None, tile_h: int | None,
-               dataflow: str | None, use_autotune_cache: bool,
+               padding: str | int, activation: str | None,
+               tile_h: int | None, dataflow: str | None,
+               use_autotune_cache: bool,
                layer: str | None = None) -> jax.Array:
     """The quantized tier chain (DESIGN.md §11): ``q8 -> pallas -> ref``.
 
@@ -833,16 +949,10 @@ def _conv2d_q8(x: jax.Array, pk: PackedConv2dWeights, *, stride: int,
         x_q = x
     else:
         x_q = ref.quantize_int8(x, pk.input_scale, pk.zero_point)
-    if padding == "same":
-        ph, pw = _same_pads(x.shape[1], k, stride), \
-            _same_pads(x.shape[2], k, stride)
-        zp = pk.zero_point.astype(x_q.dtype)
-        x_pad = jax.lax.pad(x_q, zp, ((0, 0, 0), (*ph, 0), (*pw, 0),
-                                      (0, 0, 0)))
-    elif padding == "valid":
-        x_pad = x_q
-    else:
-        raise ValueError(f"padding={padding!r} must be 'same' or 'valid'")
+    ph, pw = conv_pads(x.shape[1], x.shape[2], k, stride, padding)
+    zp = pk.zero_point.astype(x_q.dtype)
+    x_pad = jax.lax.pad(x_q, zp, ((0, 0, 0), (*ph, 0), (*pw, 0),
+                                  (0, 0, 0)))
 
     def _q8_tier():
         return _q8_forward(x_pad, pk, stride=stride, activation=activation,
@@ -886,7 +996,7 @@ def _conv2d_q8(x: jax.Array, pk: PackedConv2dWeights, *, stride: int,
 
 
 def depthwise_conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1,
-                     padding: str = "same", impl: str = "pallas",
+                     padding: str | int = "same", impl: str = "pallas",
                      bias: jax.Array | None = None,
                      activation: str | None = None,
                      mesh=None, rules: dict | None = None,
@@ -899,8 +1009,9 @@ def depthwise_conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1,
     x: (N, H, W, Cin); w: (K, K, 1, Cin * multiplier).  Everything else
     — fused bias/activation epilogue, autotune-cache consultation, the
     ``custom_vjp`` backward kernels, the ``mesh=`` sharded path — is
-    inherited from :func:`conv2d`; the group axis rides the kernel grid
-    so a depthwise conv is still a single ``pallas_call``.
+    inherited from :func:`conv2d`.  On one device a multiplier-1
+    depthwise conv runs the VPU kernel (:func:`runs_depthwise`); a
+    higher multiplier rides the MXU kernel's group grid axis.
     """
     return conv2d(x, w, stride=stride, padding=padding, impl=impl,
                   feature_group_count=x.shape[-1], bias=bias,

@@ -22,17 +22,27 @@ def epilogue(y: jax.Array, bias: jax.Array | None = None,
     return fn(y)
 
 
+def lax_padding(padding):
+    """The ``lax.conv_general_dilated`` padding of a conv ``padding``:
+    ``"same"`` / ``"valid"``, or an int ``p``, the symmetric ``(p, p)``
+    on both spatial axes."""
+    if isinstance(padding, str):
+        return padding.upper()
+    return [(padding, padding), (padding, padding)]
+
+
 def conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1,
-           padding: str = "same", feature_group_count: int = 1,
+           padding: str | int = "same", feature_group_count: int = 1,
            bias: jax.Array | None = None,
            activation: str | None = None) -> jax.Array:
     """2D (grouped) convolution oracle.
 
-    x: (N, H, W, Cin); w: (K, K, Cin/groups, Cout); bias: (Cout,) or None.
+    x: (N, H, W, Cin); w: (K, K, Cin/groups, Cout); bias: (Cout,) or None;
+    ``padding`` as :func:`lax_padding` takes it.
     """
-    pad = padding.upper()
     y = jax.lax.conv_general_dilated(
-        x, w, window_strides=(stride, stride), padding=pad,
+        x, w, window_strides=(stride, stride),
+        padding=lax_padding(padding),
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
         feature_group_count=feature_group_count)
     return epilogue(y, bias, activation)
@@ -104,7 +114,8 @@ def dequant_params(w_q: jax.Array, w_scale: jax.Array, x_scale,
 def conv2d_quantized(x_q: jax.Array, w_q: jax.Array, *, x_scale,
                      x_zero_point, w_scale: jax.Array,
                      bias: jax.Array | None = None, stride: int = 1,
-                     padding: str = "same", feature_group_count: int = 1,
+                     padding: str | int = "same",
+                     feature_group_count: int = 1,
                      activation: str | None = None) -> jax.Array:
     """Int8 quantized conv oracle: int32 accumulation, f32 dequant epilogue.
 
@@ -118,11 +129,15 @@ def conv2d_quantized(x_q: jax.Array, w_q: jax.Array, *, x_scale,
         kh, kw = w_q.shape[0], w_q.shape[1]
         ph = _same_pads(x_q.shape[1], kh, stride)
         pw = _same_pads(x_q.shape[2], kw, stride)
-        zp = jnp.asarray(x_zero_point, x_q.dtype)
-        x_q = jax.lax.pad(x_q, zp, ((0, 0, 0), (*ph, 0), (*pw, 0),
-                                    (0, 0, 0)))
-    elif padding != "valid":
-        raise ValueError(f"padding={padding!r} must be 'same' or 'valid'")
+    elif isinstance(padding, int) and padding >= 0:
+        ph = pw = (padding, padding)
+    elif padding == "valid":
+        ph = pw = (0, 0)
+    else:
+        raise ValueError(f"padding={padding!r} must be 'same', 'valid' "
+                         "or a symmetric pad >= 0")
+    zp = jnp.asarray(x_zero_point, x_q.dtype)
+    x_q = jax.lax.pad(x_q, zp, ((0, 0, 0), (*ph, 0), (*pw, 0), (0, 0, 0)))
     acc = jax.lax.conv_general_dilated(
         x_q.astype(jnp.int32), w_q.astype(jnp.int32),
         window_strides=(stride, stride), padding="VALID",
@@ -135,7 +150,7 @@ def conv2d_quantized(x_q: jax.Array, w_q: jax.Array, *, x_scale,
 
 
 def conv2d_grads(x: jax.Array, w: jax.Array, gy: jax.Array, *,
-                 stride: int = 1, padding: str = "same",
+                 stride: int = 1, padding: str | int = "same",
                  feature_group_count: int = 1) -> tuple:
     """Canonical (dx, dw) oracle: ``jax.vjp`` on the XLA convolution.
 
@@ -144,7 +159,8 @@ def conv2d_grads(x: jax.Array, w: jax.Array, gy: jax.Array, *,
     """
     def f(x, w):
         return jax.lax.conv_general_dilated(
-            x, w, window_strides=(stride, stride), padding=padding.upper(),
+            x, w, window_strides=(stride, stride),
+            padding=lax_padding(padding),
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
             feature_group_count=feature_group_count)
     _, vjp = jax.vjp(f, x, w)
@@ -152,7 +168,7 @@ def conv2d_grads(x: jax.Array, w: jax.Array, gy: jax.Array, *,
 
 
 def conv2d_input_grad(x: jax.Array, w: jax.Array, gy: jax.Array, *,
-                      stride: int = 1, padding: str = "same",
+                      stride: int = 1, padding: str | int = "same",
                       feature_group_count: int = 1) -> jax.Array:
     """Input cotangent of the conv2d oracle."""
     return conv2d_grads(x, w, gy, stride=stride, padding=padding,
@@ -160,7 +176,7 @@ def conv2d_input_grad(x: jax.Array, w: jax.Array, gy: jax.Array, *,
 
 
 def conv2d_weight_grad(x: jax.Array, w: jax.Array, gy: jax.Array, *,
-                       stride: int = 1, padding: str = "same",
+                       stride: int = 1, padding: str | int = "same",
                        feature_group_count: int = 1) -> jax.Array:
     """Weight cotangent of the conv2d oracle."""
     return conv2d_grads(x, w, gy, stride=stride, padding=padding,

@@ -65,7 +65,7 @@ def _maxpool(x, stride, window):
 # ---------------------------------------------------------------------------
 
 def _stage_conv(buf, tap_load, b_ref, st, *, activation, dtype):
-    """One conv stage on a resident row buffer: 'same' W-pad, the
+    """One conv stage on a resident row buffer: the zero W-pad, the
     ``(ki, kj)``-ordered tap matmuls of ``trim_conv2d._tap_matmuls``
     (weights arriving via ``tap_load``), then the exact per-layer
     epilogue (fp32 bias add, activation, cast)."""
@@ -189,13 +189,12 @@ def _fused_forward(x, weights, biases, *, group, activation, interpret):
 def reference_chain(x, weights, biases, *, group, activation="relu",
                     impl="pallas", use_autotune_cache=False):
     """The per-layer execution of the same group: ``ops.conv2d`` (with
-    its 'same' pre-pad and TrIM kernels) + a separate max-pool per
-    stage.  This is both the differential-test oracle for the megakernel
-    and the recompute path of its backward pass."""
+    its pre-pad and TrIM kernels) + a separate max-pool per stage.  This
+    is both the differential-test oracle for the megakernel and the
+    recompute path of its backward pass."""
     from repro.kernels import ops
     for st, w, b in zip(group.stages, weights, biases):
-        padding = "same" if (st.pad_lo or st.pad_hi) else "valid"
-        x = ops.conv2d(x, w, stride=st.stride, padding=padding,
+        x = ops.conv2d(x, w, stride=st.stride, padding=st.pad_lo,
                        impl=impl, bias=b, activation=activation,
                        use_autotune_cache=use_autotune_cache)
         if st.pooled:

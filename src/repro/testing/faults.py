@@ -64,12 +64,15 @@ class FaultHandle:
         self.calls = 0
 
 
-#: tier name -> (module, attribute) the dispatch layer resolves per call
+#: tier name -> the (module, attribute) pairs the dispatch layer
+#: resolves per call: the ``pallas`` tier is the MXU kernel and, for a
+#: depthwise conv, the VPU kernel
 TIER_TARGETS = {
-    "fused": ("repro.kernels.trim_conv2d_fused", "_fused_forward"),
-    "pallas": ("repro.kernels.ops", "trim_conv2d"),
-    "sharded": ("repro.kernels.ops", "sharded_conv2d"),
-    "q8": ("repro.kernels.ops", "_q8_forward"),
+    "fused": (("repro.kernels.trim_conv2d_fused", "_fused_forward"),),
+    "pallas": (("repro.kernels.ops", "trim_conv2d"),
+               ("repro.kernels.ops", "trim_depthwise_conv2d")),
+    "sharded": (("repro.kernels.ops", "sharded_conv2d"),),
+    "q8": (("repro.kernels.ops", "_q8_forward"),),
 }
 
 #: persistence path -> (module, attribute) of its patchable publish alias
@@ -80,23 +83,27 @@ PUBLISH_TARGETS = {
 
 
 @contextlib.contextmanager
-def _patched(module_name: str, attr: str, make_replacement):
-    """Patch ``module.attr`` with ``make_replacement(original)`` for the
-    duration of the block; always restore."""
-    mod = importlib.import_module(module_name)
-    orig = getattr(mod, attr)
-    setattr(mod, attr, make_replacement(orig))
+def _patched(targets, make_replacement):
+    """Patch every ``(module, attr)`` of ``targets`` with
+    ``make_replacement(original)`` for the duration of the block; always
+    restore."""
+    saved = []
     try:
+        for module_name, attr in targets:
+            mod = importlib.import_module(module_name)
+            saved.append((mod, attr, getattr(mod, attr)))
+            setattr(mod, attr, make_replacement(saved[-1][2]))
         yield
     finally:
-        setattr(mod, attr, orig)
+        for mod, attr, orig in reversed(saved):
+            setattr(mod, attr, orig)
 
 
 @contextlib.contextmanager
 def lowering_failure(tier: str, message: str = "injected lowering failure"):
     """Make the named conv tier (``fused``/``pallas``/``sharded``) raise
     :class:`InjectedFault` on every call."""
-    mod, attr = TIER_TARGETS[tier]
+    targets = TIER_TARGETS[tier]
     handle = FaultHandle()
 
     def make(orig):
@@ -105,7 +112,7 @@ def lowering_failure(tier: str, message: str = "injected lowering failure"):
             raise InjectedFault(f"{tier}: {message}")
         return boom
 
-    with _patched(mod, attr, make):
+    with _patched(targets, make):
         yield handle
 
 
@@ -114,7 +121,7 @@ def nan_poison(tier: str = "pallas"):
     """Make the named tier compute normally, then poison one output
     element to NaN — detectable only by the ``REPRO_CONV_GUARD=1``
     numerics guard (eager execution)."""
-    mod, attr = TIER_TARGETS[tier]
+    targets = TIER_TARGETS[tier]
     handle = FaultHandle()
 
     def make(orig):
@@ -125,7 +132,7 @@ def nan_poison(tier: str = "pallas"):
             return out.at[(0,) * out.ndim].set(jnp.nan)
         return poisoned
 
-    with _patched(mod, attr, make):
+    with _patched(targets, make):
         yield handle
 
 
@@ -134,7 +141,7 @@ def crash_before_publish(target: str):
     """Make the named persistence path (``autotune``/``checkpoint``)
     raise :class:`InjectedCrash` instead of performing its atomic rename:
     the write happened to the temp location, the publish never did."""
-    mod, attr = PUBLISH_TARGETS[target]
+    targets = (PUBLISH_TARGETS[target],)
     handle = FaultHandle()
 
     def make(orig):
@@ -143,7 +150,7 @@ def crash_before_publish(target: str):
             raise InjectedCrash(f"{target}: crashed before publish")
         return crash
 
-    with _patched(mod, attr, make):
+    with _patched(targets, make):
         yield handle
 
 

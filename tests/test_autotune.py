@@ -267,10 +267,12 @@ def test_conv2d_uses_cached_knobs(monkeypatch):
 @pytest.mark.parametrize("lname", ["pw1", "dw2"])
 def test_hillclimb_write_cache_feeds_conv2d(lname):
     """The sweep->cache->conv2d loop: benchmarks/hillclimb.py --conv
-    --write-cache stores a record under the exact key ops.conv2d looks
-    up — including the stride-2 'same' case where the kernel-seen
-    pre-pad is asymmetric (dw2: 112 -> 113 rows, not the layer's
-    symmetric 114)."""
+    --write-cache stores a record under the exact key the MXU kernel's
+    lookup in ops.conv2d uses — including the stride-2 case, where the
+    kernel-seen input carries the layer's symmetric padding (dw2: 112
+    -> 114 rows, not XLA 'same''s 113).  (Unpacked, a depthwise conv
+    such as dw2 runs the VPU kernel under its own conv2d_dw: key; the
+    MXU record is what packed depthwise weights consult.)"""
     import importlib
     hillclimb = importlib.import_module("benchmarks.hillclimb")
     res = hillclimb.conv_hillclimb(f"mobilenet:{lname}",
@@ -285,7 +287,8 @@ def test_hillclimb_write_cache_feeds_conv2d(lname):
                layer.in_channels // layer.groups, layer.out_channels)
     x_shape, pad = ops.kernel_input_shape(
         (1, layer.ifmap, layer.ifmap, layer.in_channels), layer.kernel,
-        layer.stride, "same" if layer.padding else "valid")
+        layer.stride, layer.padding)
+    assert x_shape[1] == layer.ifmap + 2 * layer.padding
     got = autotune.knobs_for(x_shape, w_shape, stride=layer.stride,
                              pad=pad, groups=layer.groups)
     assert got == rec

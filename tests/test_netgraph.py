@@ -50,14 +50,17 @@ def _inputs(nodes, n=2, seed=0):
 def ref_graph_apply(p, nodes, x):
     """Independent DAG oracle: ``ref.conv2d`` (+ bias/relu epilogue and
     reduce_window pooling) per conv node, jnp joins — written against
-    the GraphNode spec, not against the production executor."""
+    the GraphNode spec, not against the production executor.  Each conv
+    pads as its layer states, symmetrically: ResNet-18's 7x7/s2 stem
+    pads (3, 3) and its 3x3/s2 convs (1, 1), where XLA's 'same' would
+    pad (2, 3) and (0, 1)."""
     outs = {}
     for nd in nodes:
         if nd.op == "conv":
             v = x if not nd.inputs else outs[nd.inputs[0]]
             l = nd.layer
             v = ref.conv2d(v, p[nd.name]["w"], stride=l.stride,
-                           padding="same" if l.padding else "valid",
+                           padding=l.padding,
                            bias=p[nd.name].get("b"), activation="relu")
             if nd.pool > 1 or nd.pool_window > 1:
                 v = jax.lax.reduce_window(

@@ -352,32 +352,37 @@ def test_topology_execution_overlapping_pool():
 
 
 def test_non_same_equivalent_padding_fails_loudly():
-    """A topology whose symmetric paper padding the execution path
-    cannot reproduce (K=5 with pad=1: 'same' would pad 2) must raise —
-    in the tuner, the pack path and the apply path — instead of
-    silently executing a different network than NetworkPlan bills."""
+    """The executor runs the symmetric padding a layer states, so a
+    padding that XLA's 'same' would not reproduce (K=5 with pad=1:
+    'same' would pad 2) now runs as stated; a topology whose padding
+    the execution path cannot run at all (a pad of K or more) must
+    raise — in the tuner, the pack path and the apply path — instead
+    of silently executing a different network than NetworkPlan
+    bills."""
     import jax
     import jax.numpy as jnp
     from repro.core.netplan import layer_kernel_problem
     from repro.models import layers
     from repro.models.base import init_params
-    bad = ConvLayer("odd", 16, 3, 8, kernel=5, padding=1)
-    with pytest.raises(ValueError, match="not 'same'-equivalent"):
+    odd = ConvLayer("odd", 16, 3, 8, kernel=5, padding=1)
+    assert layer_kernel_problem(odd)[0] == (1, 18, 18, 3)
+    bad = ConvLayer("odd", 16, 3, 8, kernel=3, padding=3)
+    with pytest.raises(ValueError, match="symmetric pads 0 <= p < K"):
         layer_kernel_problem(bad)
-    with pytest.raises(ValueError, match="not 'same'-equivalent"):
+    with pytest.raises(ValueError, match="symmetric pads 0 <= p < K"):
         autotune.tune_network([bad])
     p = init_params(layers.cnn_params_from_layers([bad]),
                     jax.random.PRNGKey(0))
-    with pytest.raises(ValueError, match="not 'same'-equivalent"):
+    with pytest.raises(ValueError, match="symmetric pads 0 <= p < K"):
         layers.cnn_pack_params(p, [bad])
     x = jnp.zeros((1, 16, 16, 3), jnp.float32)
-    with pytest.raises(ValueError, match="not 'same'-equivalent"):
+    with pytest.raises(ValueError, match="symmetric pads 0 <= p < K"):
         layers.cnn_apply_from_layers(p, [bad], x)
     # NetworkPlan still *plans* it (analytical, exact padding), but the
     # cache lookup knows nothing was executable to tune
     NetworkPlan.build([bad], use_autotune_cache=True)
     # built-in topologies are all executable as planned
-    for net in ("vgg16", "alexnet", "mobilenet"):
+    for net in ("vgg16", "alexnet", "mobilenet", "mobilenet_v1"):
         for l in network_layers(net):
             layer_kernel_problem(l)
 

@@ -6,9 +6,9 @@ that overflows VMEM, or a Pallas API the installed JAX no longer has.
 These tests compile, for one chip of a ``v5e:2x2`` topology described
 without the chip, the kernels of the VGG-16 serving path at published
 widths (with the default and the tuner-chosen plans), the tap-folded
-first convs of VGG-16 and U-Net, the weight-grad kernel and the int8
-kernel.  Nothing runs: they prove compilation, not
-results or times.
+first convs of VGG-16 and U-Net, MobileNetV1's depthwise convs on the
+VPU kernel, the weight-grad kernel and the int8 kernel.  Nothing runs:
+they prove compilation, not results or times.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and pytest-xdist workers import every
@@ -166,6 +166,48 @@ def test_folded_first_conv_compiles(one_chip, monkeypatch, case, plan):
     assert len(kernels) == 1 and kernels[0].startswith(f"%{layer}."), \
         kernels
     assert ops.tap_folded()[layer] == 9 * cin
+
+
+# MobileNetV1's depthwise convs as their layers see them, at the
+# benchmark's batch: (layer, batch, size, C, stride)
+DEPTHWISE = {
+    "dw1_b128": ("dw1", 128, 112, 32, 1),
+    "dw2_b128": ("dw2", 128, 112, 64, 2),
+    "dw12_b128": ("dw12", 128, 14, 512, 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DEPTHWISE))
+@pytest.mark.parametrize("plan", ["default", "tuned"])
+def test_depthwise_conv_compiles(one_chip, monkeypatch, case, plan):
+    """A MobileNetV1 depthwise conv through ``ops.conv2d`` compiles for
+    the chip on the VPU kernel, strided loads and all, with the default
+    and the tuner-chosen plan: one kernel, named after its layer, and
+    no XLA convolution."""
+    from repro.core.model import ConvLayer
+    from repro.kernels import ops, runtime
+    monkeypatch.setattr(runtime, "on_tpu", lambda: True)
+    layer, n, hw, c, stride = DEPTHWISE[case]
+    if plan == "tuned":
+        autotune.tune_network([ConvLayer(layer, hw, c, c, kernel=3,
+                                         stride=stride, padding=1,
+                                         groups=c)], n=n)
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,  # noqa
+                                             sharding=one_chip)
+
+    def fwd(x, w, b):
+        return ops.conv2d(x, w, stride=stride, padding=1,
+                          feature_group_count=c, bias=b, activation="relu",
+                          layer=layer)
+
+    text = jax.jit(fwd).lower(sds((n, hw, hw, c)), sds((3, 3, 1, c)),
+                              sds((c,))).compile().as_text()
+    kernels = [ln.split("=", 1)[0].strip() for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(kernels) == 1 and kernels[0].startswith(f"%{layer}."), \
+        kernels
+    assert " convolution(" not in text
+    assert ops.depthwise()[layer] == c
 
 
 @pytest.mark.xfail(strict=True, raises=NotImplementedError,
