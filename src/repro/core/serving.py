@@ -25,8 +25,9 @@ Three design rules keep the engine testable and production-shaped:
 
 * **No cold paths after prewarm.**  ``prewarm()`` sweeps
   ``autotune.prewarm_buckets`` over the bucket grid (every layer of the
-  topology tuned at every bucket's batch shape, fused groups included
-  when fused execution is on) and runs one throwaway forward per
+  topology tuned at every bucket's batch shape and at the rows of the
+  chunks a large bucket is split into, fused groups included when
+  fused execution is on) and runs one throwaway forward per
   (bucket, replica) so every compiled program exists before the first
   request.  A bucket served without prewarm is a *cold tune* — counted
   in ``stats()`` and asserted zero by the benchmark.
@@ -51,7 +52,13 @@ from repro.core import guard, spans
 from repro.testing.load import TraceRecorder
 
 __all__ = ["QueueFull", "BucketGrid", "Replica", "ServingEngine",
-           "replay", "pow2_buckets", "jit_replica_fn"]
+           "replay", "pow2_buckets", "jit_replica_fn", "chunk_rows",
+           "CHUNK_BYTES"]
+
+# Input bytes a replica forward copies to the device in one piece
+# (DESIGN.md §15): a larger batch is split into chunks of at most this
+# size, so the program on one chunk runs while the next one lands.
+CHUNK_BYTES = 5 << 20
 
 
 class QueueFull(RuntimeError):
@@ -129,6 +136,23 @@ class Replica:
     fn: object     # Callable[[np.ndarray], array-like]
 
 
+def chunk_rows(shape, itemsize: int, target_bytes: int | None = None) -> int:
+    """Rows of each chunk a replica forward splits a batch of ``shape``
+    (``itemsize`` bytes an element) into: the largest power of two that
+    divides the batch and keeps a chunk within ``target_bytes``
+    (:data:`CHUNK_BYTES` when ``None``), at least one row.  A batch of
+    one row, or of no more than ``target_bytes``, stays whole."""
+    n = int(shape[0])
+    target = CHUNK_BYTES if target_bytes is None else int(target_bytes)
+    row = int(itemsize) * int(np.prod(shape[1:], dtype=np.int64))
+    if n <= 1 or n * row <= target:
+        return n
+    rows = 1
+    while n % (2 * rows) == 0 and 2 * rows * row <= target:
+        rows *= 2
+    return rows
+
+
 def jit_replica_fn(call, params, device=None):
     """A replica's batch forward from a jitted ``call(params, x)`` that
     returns one array: numpy batch in, numpy rows out, in four spans
@@ -138,12 +162,23 @@ def jit_replica_fn(call, params, device=None):
     are queued), ``replica.wait`` (until the output is ready; its child
     ``replica.h2d_wait`` until the batch has landed) and ``replica.d2h``
     (the rest of the copy back).  Both copies run while the host waits:
-    the copy back is queued behind the program, not started after it."""
+    the copy back is queued behind the program, not started after it.
+
+    A batch that :func:`chunk_rows` splits runs as a pipeline
+    (DESIGN.md §15): every chunk's copy is started, then the program is
+    queued once per chunk, each waiting on its own chunk alone, so it
+    runs while the later chunks land; ``replica.h2d_wait`` is the wait
+    for the first chunk, the one copy left exposed, and the rows are
+    joined in order."""
     import jax
 
     def fn(batch):
+        batch = np.asarray(batch)
+        rows = chunk_rows(batch.shape, batch.itemsize)
+        if rows != len(batch):
+            return _chunked(batch, rows)
         with spans.span("replica.h2d"):
-            x = jax.device_put(np.asarray(batch), device)
+            x = jax.device_put(batch, device)
         with spans.span("replica.dispatch"):
             y = call(params, x)
             y.copy_to_host_async()
@@ -153,6 +188,23 @@ def jit_replica_fn(call, params, device=None):
             y.block_until_ready()
         with spans.span("replica.d2h"):
             return np.asarray(y)
+
+    def _chunked(batch, rows):
+        with spans.span("replica.h2d"):
+            xs = jax.device_put([batch[i:i + rows]
+                                 for i in range(0, len(batch), rows)],
+                                device)
+        with spans.span("replica.dispatch"):
+            ys = [call(params, x) for x in xs]
+            for y in ys:
+                y.copy_to_host_async()
+        with spans.span("replica.wait"):
+            with spans.span("replica.h2d_wait"):
+                xs[0].block_until_ready()
+            for y in ys:
+                y.block_until_ready()
+        with spans.span("replica.d2h"):
+            return np.concatenate([np.asarray(y) for y in ys])
     return fn
 
 
@@ -221,7 +273,7 @@ class ServingEngine:
         residency-group megakernels (guarded: a failing group demotes
         to per-layer execution per DESIGN.md §9).  Each replica's
         forward is :func:`jit_replica_fn`; a distributed replica takes
-        its batch straight onto its own device."""
+        its batch, every chunk of it, straight onto its own device."""
         import jax
         from repro.models import layers as mlayers
 
@@ -292,12 +344,28 @@ class ServingEngine:
         self.cold_tunes += 1
         if self.topo is not None:
             from repro.core import autotune
-            autotune.tune_network(self.topo, n=bucket,
-                                  **self.tune_kwargs)
-            if self.fused:
-                autotune.tune_fused_network(self.topo, n=bucket,
-                                            **self.tune_kwargs)
+            for n in self._program_batches([bucket]):
+                autotune.tune_network(self.topo, n=n, **self.tune_kwargs)
+                if self.fused:
+                    autotune.tune_fused_network(self.topo, n=n,
+                                                **self.tune_kwargs)
         self._warm.add(bucket)
+
+    def _chunk_rows(self, bucket: int) -> int:
+        """Rows of each program a forward of ``bucket`` runs: the
+        :func:`chunk_rows` rule over the f32 batch ``prewarm`` compiles,
+        for the replicas of a topology (:func:`jit_replica_fn`); the
+        whole bucket for any other replica."""
+        if self.topo is None or self.input_shape is None:
+            return bucket
+        return chunk_rows((bucket,) + self.input_shape,
+                          np.dtype(np.float32).itemsize)
+
+    def _program_batches(self, buckets) -> list[int]:
+        """Every batch size a program runs at for ``buckets``: each
+        bucket and the rows of its chunks, ascending."""
+        return sorted({m for b in buckets
+                       for m in (b, self._chunk_rows(b))})
 
     def _collect_guard(self, replica_name: str) -> None:
         new = [e for e in guard.events() if e["seq"] > self._guard_seq]
@@ -374,17 +442,19 @@ class ServingEngine:
 
     def prewarm(self, *, tune: bool = True, compile: bool = True) -> dict:
         """Make every (bucket, replica) path hot before the first
-        request: sweep the autotune cache over the bucket grid
-        (:func:`repro.core.autotune.prewarm_buckets` — skipped for
+        request: sweep the autotune cache over the bucket grid and the
+        rows of each bucket's chunks (:func:`chunk_rows`;
+        :func:`repro.core.autotune.prewarm_buckets` — skipped for
         engines without a topology) and run one throwaway forward per
-        bucket per replica to populate the JIT cache.  Returns the
-        per-bucket tune records."""
+        bucket per replica to populate the JIT cache (a chunked bucket
+        compiles its chunk's program).  Returns the tune records by
+        batch size."""
         records: dict = {}
         if tune and self.topo is not None:
             from repro.core import autotune
             records = autotune.prewarm_buckets(
-                self.topo, self.grid.buckets, fused=self.fused,
-                **self.tune_kwargs)
+                self.topo, self._program_batches(self.grid.buckets),
+                fused=self.fused, **self.tune_kwargs)
         if compile and self.input_shape is not None:
             for b in self.grid.buckets:
                 zeros = np.zeros((b,) + self.input_shape, np.float32)
@@ -398,7 +468,9 @@ class ServingEngine:
     def stats(self) -> dict:
         """Serving counters + per-replica degradation report.  A replica
         with guard events kept serving on a fallback tier — degraded,
-        labeled, never silent (DESIGN.md §9/§10)."""
+        labeled, never silent (DESIGN.md §9/§10).  ``replica_chunks``:
+        {bucket: programs a forward of that bucket runs}, from the
+        :func:`chunk_rows` rule every forward takes (DESIGN.md §15)."""
         from repro.kernels import ops
         with self._lock:
             per_replica = {
@@ -417,6 +489,8 @@ class ServingEngine:
                 "max_queue_depth": self.recorder.max_queue_depth,
                 "rejected": len(self.recorder.rejected),
                 "replicas": per_replica,
+                "replica_chunks": {b: b // self._chunk_rows(b)
+                                   for b in self.grid.buckets},
                 "tap_folded": self._own(ops.tap_folded()),
                 "depthwise": self._own(ops.depthwise()),
             }

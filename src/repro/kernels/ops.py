@@ -295,14 +295,20 @@ def _fold_taps(x: jax.Array, kh: int, kw: int, stride: int,
     layout in one copy.  The barrier keeps XLA from choosing the
     kernel's 128-lane layout for the stack itself, which turned each
     view into a lane-padded copy (over 40 ms a step for U-Net's
-    ``(8, 512, 512, 1)`` input on a v5e)."""
+    ``(8, 512, 512, 1)`` input on a v5e).  Each view is a strided
+    ``lax.slice``: strided indexing lowers to a gather, which the chip's
+    compiler lays out with the batch in the lanes, 16x padded in a
+    program of 8 images (DESIGN.md §15)."""
     h_out = (x.shape[1] - kh) // stride + 1
     w_out = (x.shape[2] - kw) // stride + 1
     x = jnp.pad(jnp.moveaxis(x, 3, 1),
                 ((0, 0), (0, 0), (0, (rows - h_out) * stride), (0, 0)))
+    n, c = x.shape[:2]
     taps = jnp.concatenate(
-        [x[:, :, ki:ki + (rows - 1) * stride + 1:stride,
-           kj:kj + (w_out - 1) * stride + 1:stride]
+        [jax.lax.slice(x, (0, 0, ki, kj),
+                       (n, c, ki + (rows - 1) * stride + 1,
+                        kj + (w_out - 1) * stride + 1),
+                       (1, 1, stride, stride))
          for ki in range(kh) for kj in range(kw)], axis=1)
     return jnp.moveaxis(jax.lax.optimization_barrier(taps), 1, 3)
 

@@ -99,6 +99,19 @@ def test_folded_shape_with_stride():
         ((1, 7, 7, 27), (1, 1, 27, 64))
 
 
+@pytest.mark.parametrize("stride", [1, 2])
+def test_fold_takes_its_views_without_a_gather(stride):
+    """The im2col's K*K views are strided slices, never a gather: the
+    chip's compiler lays a gather out with the batch in the lanes, which
+    a chunk of 8 images fills to a sixteenth."""
+    x = jnp.zeros((8, 17, 17, 3), jnp.float32)
+    rows = (17 - 3) // stride + 1
+    jaxpr = str(jax.make_jaxpr(
+        lambda x: ops._fold_taps(x, 3, 3, stride, rows))(x))
+    assert "gather" not in jaxpr
+    assert jaxpr.count("slice[") == 9
+
+
 def test_folded_kernel_keeps_the_layer_name():
     """The folded conv is one Pallas kernel named after its layer, the
     name a profiler trace shows (``%<layer>.<n>`` on the chip)."""
