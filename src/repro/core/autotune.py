@@ -549,13 +549,12 @@ def candidate_depthwise_knobs(x_shape, k: int, *, stride: int = 1,
 
 
 def tune_depthwise(x_shape, k: int, *, stride: int = 1,
-                   dtype: str = "float32", dtype_bytes: int | None = None,
-                   backend: str | None = None, write: bool = True,
-                   path: str | None = None) -> dict:
-    """Tune one depthwise problem by the plan's model
+                   dtype: str = "float32",
+                   dtype_bytes: int | None = None) -> dict:
+    """The record of one depthwise problem, tuned by the plan's model
     (:attr:`DepthwisePlan.model_step_time_s`; ties go to fewer grid
-    steps) and (by default) persist the winner under its
-    ``conv2d_dw:`` key."""
+    steps); :func:`tune_network` stores it under its ``conv2d_dw:``
+    key."""
     plans = candidate_depthwise_knobs(
         x_shape, k, stride=stride,
         dtype_bytes=_resolve_bytes(dtype_bytes, dtype))
@@ -563,13 +562,9 @@ def tune_depthwise(x_shape, k: int, *, stride: int = 1,
         raise ValueError(f"no feasible depthwise plan for {x_shape}, K={k}")
     best = min(plans, key=lambda p: (p.model_step_time_s,
                                      math.prod(p.grid), p.tile_n))
-    record = dict(tile_h=best.tile_h, tile_c=best.tile_c,
-                  tile_n=best.tile_n, source="model",
-                  model_step_time_s=best.model_step_time_s)
-    if write:
-        store(depthwise_key(x_shape, k, stride=stride, dtype=dtype,
-                            backend=backend), record, path)
-    return record
+    return dict(tile_h=best.tile_h, tile_c=best.tile_c,
+                tile_n=best.tile_n, source="model",
+                model_step_time_s=best.model_step_time_s)
 
 
 # ---------------------------------------------------------------------------
@@ -764,23 +759,22 @@ def tune_network(network="vgg16", *, n: int = 1, dtype: str = "float32",
     looks up at call time), so after one sweep the whole forward pass of
     ``examples/cnn_inference.py --net ...`` runs on cached plans.  With
     a shard grid the records land under the ``conv2d_shard:`` namespace
-    instead.  A layer whose taps ``ops.conv2d`` folds into the lanes
-    (``ops.tap_fold``) is tuned, and keyed, as the 1x1 problem it runs
-    as; a layer it runs on the depthwise VPU kernel
-    (``ops.runs_depthwise``) by :func:`tune_depthwise`, under its
-    ``conv2d_dw:`` key.  Layers sharing a shape (VGG-16's repeated blocks) are
-    tuned once; layers with ``K > MAX_NATIVE_K`` (AlexNet's 11x11) run
-    on the kernel-tiled path that never consults the cache and are
-    recorded as skipped.  ``include_backward`` additionally seeds both
-    cotangent records per layer (:func:`tune_backward`).  ``op`` selects
-    the single-device key namespace (``"conv2d_q8"`` seeds the int8
-    inference path; pair it with ``dtype="int8"``).
+    instead.  One device, float: each layer is tuned as the problem its
+    ``ops.conv_route`` runs, under the key that route reads (DESIGN.md
+    §16); a ``tiled`` layer (AlexNet's 11x11) reads none and is recorded
+    as skipped.  The sharded and ``conv2d_q8`` namespaces tune every
+    other layer unfolded, on the MXU kernel.  Layers sharing a key
+    (VGG-16's repeated blocks) are tuned once.  ``include_backward``
+    additionally seeds both cotangent records per layer
+    (:func:`tune_backward`).  ``op`` selects the single-device key
+    namespace (``"conv2d_q8"`` seeds the int8 inference path; pair it
+    with ``dtype="int8"``).
 
     Returns ``{layer_name: record}`` with ``record["key"]`` the cache
     key written (or ``{"skipped": reason}``).
     """
     from repro.core.netplan import layer_kernel_problem, network_layers
-    from repro.kernels.ops import MAX_NATIVE_K, runs_depthwise, tap_fold
+    from repro.kernels.ops import ConvRoute, conv_route
     sharded = batch_shards > 1 or spatial_shards > 1
     if measure and sharded:
         raise ValueError(
@@ -795,65 +789,60 @@ def tune_network(network="vgg16", *, n: int = 1, dtype: str = "float32",
             raise ValueError(
                 f"duplicate layer name {layer.name!r} in topology; "
                 "give repeated blocks unique names")
-        if layer.kernel > MAX_NATIVE_K:
-            results[layer.name] = {
-                "skipped": f"K={layer.kernel} > {MAX_NATIVE_K}: "
-                           "kernel-tiled path (no cache)"}
-            continue
         # the shared layer -> executed-problem mapping (raises on
         # padding the execution path cannot reproduce)
         x_shape, pad, w_shape, _ = layer_kernel_problem(layer, n=n)
-        stride = layer.stride
-        if not sharded and op == "conv2d" \
-                and runs_depthwise(x_shape, w_shape, layer.groups, dtype):
-            key = depthwise_key(x_shape, layer.kernel, stride=stride,
-                                dtype=dtype, backend=backend)
-            if key not in seen:
-                seen[key] = dict(tune_depthwise(
-                    x_shape, layer.kernel, stride=stride, dtype=dtype,
-                    dtype_bytes=dtype_bytes, backend=backend, write=write,
-                    path=path), key=key)
-            results[layer.name] = seen[key]
-            if include_backward and "backward" not in seen[key]:
-                seen[key]["backward"] = tune_backward(
-                    x_shape, w_shape, stride=stride, pad=pad,
-                    groups=layer.groups, dtype=dtype,
-                    dtype_bytes=dtype_bytes, backend=backend, write=write,
-                    path=path)
+        route = conv_route(x_shape, w_shape, stride=layer.stride,
+                           groups=layer.groups, dtype=dtype)
+        if route.kind == "tiled":
+            results[layer.name] = {
+                "skipped": f"K={layer.kernel}: kernel-tiled path "
+                           "(no cache)"}
             continue
-        # the single-device float path runs a tap-folded layer as its
-        # 1x1 problem (ops.tap_fold), and looks that problem up
-        folded = None if sharded or op != "conv2d" else \
-            tap_fold(x_shape, w_shape, stride, layer.groups)
-        if folded is not None:
-            (x_shape, w_shape), stride = folded, 1
-        layer_op = op if not sharded \
-            else sharded_key_op(batch_shards, spatial_shards)
-        key = make_key(x_shape, w_shape, stride=stride, pad=pad,
-                       groups=layer.groups, dtype=dtype, backend=backend,
-                       op=layer_op)
+        if sharded or op != "conv2d":
+            # the sharded and int8 tiers run the layer unfolded on the
+            # MXU kernel, under their own namespaces
+            route = ConvRoute("mxu", x_shape, w_shape, layer.stride,
+                              layer.groups)
+            key = make_key(x_shape, w_shape, stride=layer.stride, pad=pad,
+                           groups=layer.groups, dtype=dtype,
+                           backend=backend,
+                           op=sharded_key_op(batch_shards, spatial_shards)
+                           if sharded else op)
+        else:
+            key = route.key(dtype, backend)
         if key in seen:
             results[layer.name] = seen[key]
             continue
-        common = dict(stride=stride, pad=pad, groups=layer.groups,
+        common = dict(stride=route.stride, pad=pad, groups=route.groups,
                       dtype=dtype, dtype_bytes=dtype_bytes,
-                      backend=backend, write=write, path=path)
+                      backend=backend)
         if sharded:
-            rec = tune_sharded(x_shape, w_shape,
+            rec = tune_sharded(route.x_shape, route.w_shape,
                                batch_shards=batch_shards,
-                               spatial_shards=spatial_shards, **common)
+                               spatial_shards=spatial_shards, write=False,
+                               **common)
+        elif route.kind == "depthwise":
+            rec = tune_depthwise(route.x_shape, route.w_shape[0],
+                                 stride=route.stride, dtype=dtype,
+                                 dtype_bytes=dtype_bytes)
         else:
-            rec = tune(x_shape, w_shape, measure=measure, op=layer_op,
-                       **common)
+            rec = tune(route.x_shape, route.w_shape, measure=measure,
+                       write=False, **common)
+        if write:
+            store(key, rec, path)
         rec = dict(rec, key=key)
         if include_backward and not sharded:
-            if folded is not None:     # the 1x1 kernel sees whole strips
+            bx_shape = route.x_shape
+            if route.kind == "folded":   # the 1x1 kernel sees whole strips
                 rows = ConvPlan.build(
-                    x_shape, w_shape, dtype_bytes=dtype,
+                    bx_shape, route.w_shape, dtype_bytes=dtype,
                     tile_h=rec["tile_h"], tile_cout=rec["tile_cout"],
                     dataflow=rec["dataflow"]).rows_padded
-                x_shape = (x_shape[0], rows) + x_shape[2:]
-            rec["backward"] = tune_backward(x_shape, w_shape, **common)
+                bx_shape = (bx_shape[0], rows) + bx_shape[2:]
+            rec["backward"] = tune_backward(bx_shape, route.w_shape,
+                                            write=write, path=path,
+                                            **common)
         seen[key] = rec
         results[layer.name] = rec
     return results

@@ -63,13 +63,8 @@ from repro.core import roofline
 from repro.core.conv_plan import KERNEL_VMEM_LIMIT
 from repro.core.netplan import (NetworkPlan, RESIDENCY_BUDGET, graph_nodes,
                                 infer_pools, layer_kernel_problem,
-                                network_layers, pool_between,
+                                layer_route, network_layers, pool_between,
                                 pooled_out_size)
-
-# Fused stages run the taps as native MXU matmuls, same ceiling as the
-# single-layer kernel (kernels/ops.MAX_NATIVE_K, re-stated here to keep
-# core/ free of kernel imports).
-MAX_FUSED_K = 8
 
 # The megakernel's working-set budget: half of the kernels' scoped-VMEM
 # limit (16 MiB).  Its resident model is not yet checked against the
@@ -338,9 +333,14 @@ def build_group(layers, start, *, n=1, strip_rows=1, dtype_bytes=4,
 # Whole-network partition
 # ---------------------------------------------------------------------------
 
-def _layer_eligible(layer) -> bool:
-    """Can this layer run *inside* a fused megakernel at all?"""
-    if layer.groups != 1 or layer.kernel > MAX_FUSED_K:
+def _layer_eligible(layer, dtype: str) -> bool:
+    """Can this layer run *inside* a fused megakernel at all?  Only an
+    ``mxu`` route (``ops.conv_route``) without groups: the stages run
+    the taps as native MXU matmuls, and a conv whose taps fold into the
+    lanes (DESIGN.md §13) runs per layer as a 1x1 over its folded input,
+    whose one dot sums in another order than the megakernel's K*K tap
+    dots and would break the bitwise guarantee."""
+    if layer.groups != 1:
         return False
     if layer.stride > 1 and layer.out_size == 1:
         # A strided stage collapsing to a single output row fuses as a
@@ -350,15 +350,9 @@ def _layer_eligible(layer) -> bool:
         # one-strip output gains nothing from strip fusion anyway.
         return False
     try:
-        x_shape, _, w_shape, _ = layer_kernel_problem(layer)
+        return layer_route(layer, dtype=dtype).kind == "mxu"
     except ValueError:
         return False
-    # A conv whose taps fold into the lanes (DESIGN.md §13) runs per
-    # layer as a 1x1 over its folded input: its one dot sums in another
-    # order than the megakernel's K*K tap dots, and would break the
-    # bitwise guarantee; the fold also spares it the K*K lane tiles.
-    from repro.kernels.ops import tap_fold
-    return tap_fold(x_shape, w_shape, layer.stride, layer.groups) is None
 
 
 def _strip_candidates(h_pool_last: int):
@@ -423,7 +417,7 @@ class FusedGroupPlan:
             """Best fused group over layers[i..j] and its bytes, or
             (None, inf) when the range can't fuse."""
             if j > i:
-                if not all(_layer_eligible(layers[k])
+                if not all(_layer_eligible(layers[k], dtype)
                            for k in range(i, j + 1)):
                     return None, math.inf
                 if not all(nplan.steps[k].resident_out

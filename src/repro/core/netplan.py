@@ -238,6 +238,15 @@ def layer_kernel_problem(layer: ConvLayer, *, n: int = 1):
     return x_shape, pad, w_shape, p
 
 
+def layer_route(layer: ConvLayer, *, n: int = 1, dtype: str = "float32"):
+    """The ``ops.conv_route`` of the problem :func:`layer_kernel_problem`
+    maps ``layer`` to: which kernel runs it (DESIGN.md §16)."""
+    from repro.kernels.ops import conv_route
+    x_shape, _, w_shape, _ = layer_kernel_problem(layer, n=n)
+    return conv_route(x_shape, w_shape, stride=layer.stride,
+                      groups=layer.groups, dtype=dtype)
+
+
 # ---------------------------------------------------------------------------
 # DAG topology helpers
 # ---------------------------------------------------------------------------
@@ -1077,24 +1086,26 @@ class NetworkGraph:
 def _cached_knobs(layer: ConvLayer, *, n: int, dtype: str,
                   backend: str | None, batch_shards: int,
                   spatial_shards: int) -> dict | None:
-    """The autotune record for one topology layer, looked up under the
-    same kernel-seen key ``ops.conv2d`` uses — derived by
-    :func:`layer_kernel_problem`, the shared mapping ``tune_network``
-    writes records with (the sharded namespace when a shard grid is
-    given)."""
+    """The autotune record for one topology layer, under the key
+    ``tune_network`` writes over :func:`layer_kernel_problem`'s shapes
+    (the sharded namespace when a shard grid is given).  The plan models
+    the layer as the conv it states, which has a record on one device
+    only for an ``mxu`` route, and sharded for any but ``tiled``."""
     from repro.core import autotune
-    from repro.kernels.ops import MAX_NATIVE_K
-    if layer.kernel > MAX_NATIVE_K:
-        return None                      # kernel-tiled path: no cache
     try:
         x_shape, pad, w_shape, _ = layer_kernel_problem(layer, n=n)
     except ValueError:
         return None          # not executable as planned: nothing cached
+    kind = layer_route(layer, n=n, dtype=dtype).kind
+    if kind == "tiled":
+        return None                      # the adder tree: no cache
     if batch_shards > 1 or spatial_shards > 1:
         return autotune.sharded_knobs_for(
             x_shape, w_shape, batch_shards=batch_shards,
             spatial_shards=spatial_shards, stride=layer.stride, pad=pad,
             groups=layer.groups, dtype=dtype, backend=backend)
+    if kind != "mxu":
+        return None
     return autotune.knobs_for(x_shape, w_shape, stride=layer.stride,
                               pad=pad, groups=layer.groups, dtype=dtype,
                               backend=backend)

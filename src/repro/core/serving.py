@@ -470,8 +470,15 @@ class ServingEngine:
         with guard events kept serving on a fallback tier — degraded,
         labeled, never silent (DESIGN.md §9/§10).  ``replica_chunks``:
         {bucket: programs a forward of that bucket runs}, from the
-        :func:`chunk_rows` rule every forward takes (DESIGN.md §15)."""
-        from repro.kernels import ops
+        :func:`chunk_rows` rule every forward takes (DESIGN.md §15).
+        ``tap_folded`` ({layer: K*K*Cin}) and ``depthwise`` ({layer: C})
+        name the topology's convs whose route (``ops.conv_route``,
+        DESIGN.md §16) folds their taps or runs the depthwise VPU
+        kernel."""
+        from repro.core.netplan import layer_route
+        dtype = self.tune_kwargs.get("dtype", "float32")
+        routes = {layer.name: layer_route(layer, dtype=dtype)
+                  for layer in self.topo or ()}
         with self._lock:
             per_replica = {
                 name: {"served": self._replica_served[name],
@@ -491,21 +498,13 @@ class ServingEngine:
                 "replicas": per_replica,
                 "replica_chunks": {b: b // self._chunk_rows(b)
                                    for b in self.grid.buckets},
-                "tap_folded": self._own(ops.tap_folded()),
-                "depthwise": self._own(ops.depthwise()),
+                "tap_folded": {name: r.w_shape[2]
+                               for name, r in routes.items()
+                               if r.kind == "folded"},
+                "depthwise": {name: r.x_shape[3]
+                              for name, r in routes.items()
+                              if r.kind == "depthwise"},
             }
-
-    def _own(self, by_layer: dict) -> dict:
-        """A per-layer counter of ``kernels.ops`` (``tap_folded``:
-        ``{layer: K*K*Cin}`` of the convs traced with their taps folded;
-        ``depthwise``: ``{layer: C}`` of those traced on the depthwise
-        VPU kernel) kept to the topology's layers; every named conv of
-        the process for an engine without a topology."""
-        if self.topo is None:
-            return by_layer
-        names = {layer.name for layer in self.topo}
-        return {k: v for k, v in by_layer.items() if k in names}
-
 
 # ---------------------------------------------------------------------------
 # Deterministic open-loop replay

@@ -57,14 +57,6 @@ from repro.kernels.trim_depthwise import trim_depthwise_conv2d
 
 MAX_NATIVE_K = 8
 
-# {layer name: K*K*C_in} of every named conv traced with its taps folded
-# (``tap_folded``); static per compiled program, filled at trace time
-_TAP_FOLDED: dict[str, int] = {}
-
-# {layer name: C} of every named conv traced on the depthwise VPU kernel
-# (``depthwise``), filled the same way
-_DEPTHWISE: dict[str, int] = {}
-
 
 # ---------------------------------------------------------------------------
 # Convolutions
@@ -256,39 +248,12 @@ def quantize_conv2d_weights(w: jax.Array, bias: jax.Array | None = None, *,
         input_scale=jnp.asarray(x_scale, jnp.float32))
 
 
-def tap_fold(x_shape, w_shape, stride: int, groups: int):
-    """The problem a conv runs as with its taps folded into the lanes
-    (DESIGN.md §13), or None when it is not folded.
-
-    ``x_shape`` is the kernel-seen input (:func:`kernel_input_shape`),
-    ``w_shape`` ``(K, K, Cin/groups, Cout)``.  A conv folds when
-    ``groups == 1``, ``1 < K <= MAX_NATIVE_K`` and ``K*K*Cin`` fits one
-    lane tile: its K*K shifted, strided views of the input, concatenated
-    on the channel axis (tap-major, then channel), make a stride-1 1x1
-    conv of ``(N, H_out, W_out, K*K*Cin)`` by ``(1, 1, K*K*Cin, Cout)``.
-    Returns that ``(x_shape, w_shape)``."""
-    n, h, w, cin = x_shape
-    kh, kw, _, cout = w_shape
-    if groups != 1 or not 1 < kh <= MAX_NATIVE_K \
-            or kh * kw * cin > LANES:
-        return None
-    h_out = (h - kh) // stride + 1
-    w_out = (w - kw) // stride + 1
-    return (n, h_out, w_out, kh * kw * cin), (1, 1, kh * kw * cin, cout)
-
-
-def tap_folded() -> dict[str, int]:
-    """``{layer: K*K*Cin}`` of every named conv this process traced
-    with its taps folded (:func:`tap_fold`)."""
-    return dict(_TAP_FOLDED)
-
-
 def _fold_taps(x: jax.Array, kh: int, kw: int, stride: int,
                rows: int) -> jax.Array:
-    """The tap-folded input of :func:`tap_fold` (an XLA im2col): the
-    KH*KW shifted, strided views of the pre-padded ``x``, on the channel
-    axis, tap-major, with zero rows below ``H_out`` up to ``rows`` (the
-    1x1 plan's ``rows_padded``: whole strips).
+    """The tap-folded input of a ``folded`` :func:`conv_route` (an XLA
+    im2col): the KH*KW shifted, strided views of the pre-padded ``x``,
+    on the channel axis, tap-major, with zero rows below ``H_out`` up to
+    ``rows`` (the 1x1 plan's ``rows_padded``: whole strips).
 
     The views are stacked channel-major, ``(N, KH*KW*C, rows, W_out)``,
     where each is a block of compact rows, and moved to the channel-last
@@ -313,22 +278,69 @@ def _fold_taps(x: jax.Array, kh: int, kw: int, stride: int,
     return jnp.moveaxis(jax.lax.optimization_barrier(taps), 1, 3)
 
 
-def runs_depthwise(x_shape, w_shape, groups: int, dtype) -> bool:
-    """Whether the single-device float path runs a conv on the depthwise
-    VPU kernel (DESIGN.md §14): one ``K x K`` filter per channel
-    (``groups == C_in == C_out > 1``), ``K <= MAX_NATIVE_K``, float
-    inputs.  Grouped convs with more than one channel per group, and
-    depthwise multipliers above 1, stay on the MXU kernel."""
-    k, _, cin_pg, cout = w_shape
-    return (groups > 1 and cin_pg == 1 and groups == x_shape[3] == cout
-            and k <= MAX_NATIVE_K
-            and jnp.issubdtype(jnp.dtype(dtype), jnp.floating))
+# the autotune namespace each route kind reads and the tuner writes (the
+# adder tree reads none: records describe the full-K problem)
+_ROUTE_OPS = {"depthwise": autotune.DEPTHWISE_OP, "folded": "conv2d",
+              "mxu": "conv2d", "tiled": None}
 
 
-def depthwise() -> dict[str, int]:
-    """``{layer: C}`` of every named conv this process traced on the
-    depthwise VPU kernel (:func:`runs_depthwise`)."""
-    return dict(_DEPTHWISE)
+@dataclasses.dataclass(frozen=True)
+class ConvRoute:
+    """Which kernel runs a conv (``kind``, :func:`conv_route`) and the
+    problem it runs: the folded stride-1 1x1 for ``"folded"``, the
+    conv's own otherwise (DESIGN.md §16)."""
+
+    kind: str        # "depthwise" | "folded" | "mxu" | "tiled"
+    x_shape: tuple
+    w_shape: tuple
+    stride: int
+    groups: int
+
+    def key(self, dtype: str, backend: str | None = None) -> str | None:
+        """The autotune key the route reads and the tuner writes."""
+        op = _ROUTE_OPS[self.kind]
+        return None if op is None else autotune.make_key(
+            self.x_shape, self.w_shape, stride=self.stride, pad=0,
+            groups=self.groups, dtype=dtype, backend=backend, op=op)
+
+    def knobs(self, dtype: str) -> dict | None:
+        """The validated record under :meth:`key`, or None."""
+        if self.kind == "depthwise":
+            return autotune.depthwise_knobs_for(
+                self.x_shape, self.w_shape[0], stride=self.stride,
+                dtype=dtype)
+        op = _ROUTE_OPS[self.kind]
+        return None if op is None else autotune.knobs_for(
+            self.x_shape, self.w_shape, stride=self.stride, pad=0,
+            groups=self.groups, dtype=dtype, op=op)
+
+
+def conv_route(x_shape, w_shape, *, stride: int, groups: int,
+               dtype) -> ConvRoute:
+    """The one place that decides which kernel the single-device float
+    path runs a conv on (DESIGN.md §16), over the kernel-seen input
+    (:func:`kernel_input_shape`) and ``(K, K, Cin/groups, Cout)``
+    weights, in order: ``depthwise`` (the VPU kernel, §14: ``groups ==
+    C_in == C_out > 1``, float), ``folded`` (§13: ``groups == 1``,
+    ``K > 1`` and ``K*K*Cin`` fits one lane tile, run as a 1x1 of
+    ``(N, H_out, W_out, K*K*Cin)`` by ``(1, 1, K*K*Cin, Cout)``), ``mxu``
+    (the MXU strip kernel), each for ``K <= MAX_NATIVE_K``, else
+    ``tiled`` (the adder tree)."""
+    n, h, w, cin = x_shape
+    k, kw, cin_pg, cout = w_shape
+    if k > MAX_NATIVE_K:
+        kind = "tiled"
+    elif (groups > 1 and cin_pg == 1 and groups == cin == cout
+          and jnp.issubdtype(jnp.dtype(dtype), jnp.floating)):
+        kind = "depthwise"
+    elif groups == 1 and k > 1 and k * kw * cin <= LANES:
+        taps = k * kw * cin
+        return ConvRoute("folded", (n, (h - k) // stride + 1,
+                                    (w - kw) // stride + 1, taps),
+                         (1, 1, taps, cout), 1, 1)
+    else:
+        kind = "mxu"
+    return ConvRoute(kind, tuple(x_shape), tuple(w_shape), stride, groups)
 
 
 def kernel_input_shape(x_shape, k: int, stride: int, padding):
@@ -547,26 +559,21 @@ def _depthwise_vjp_bwd(cfg: _DepthwiseConfig, res, gy):
 _depthwise_vjp_core.defvjp(_depthwise_vjp_fwd, _depthwise_vjp_bwd)
 
 
-def _depthwise_pallas(x: jax.Array, w: jax.Array, *, stride: int,
+def _depthwise_pallas(x: jax.Array, w: jax.Array, route: ConvRoute, *,
                       bias: jax.Array | None, activation: str | None,
                       use_autotune_cache: bool,
                       name: str | None) -> jax.Array:
     """The depthwise VPU kernel over the pre-padded ``x``, its knobs
-    from the ``conv2d_dw`` autotune records (defaults without one)."""
+    from the route's ``conv2d_dw`` record (defaults without one)."""
     knobs = dict(tile_h=None, tile_c=None, tile_n=None)
     if use_autotune_cache:
-        rec = autotune.depthwise_knobs_for(x.shape, w.shape[0],
-                                           stride=stride,
-                                           dtype=str(x.dtype))
+        rec = route.knobs(str(x.dtype))
         if rec is not None:
             knobs = {key: rec[key] for key in knobs}
-    cfg = _DepthwiseConfig(stride=stride, activation=activation,
+    cfg = _DepthwiseConfig(stride=route.stride, activation=activation,
                            use_autotune_cache=use_autotune_cache,
                            name=name, **knobs)
-    out = _depthwise_vjp_core(cfg, x, w, bias)
-    if name is not None:
-        _DEPTHWISE[name] = x.shape[3]
-    return out
+    return _depthwise_vjp_core(cfg, x, w, bias)
 
 
 def conv2d(x: jax.Array, w, *, stride: int = 1,
@@ -694,57 +701,53 @@ def _conv2d_pallas(x: jax.Array, w: jax.Array, *, stride: int,
                    use_autotune_cache: bool,
                    name: str | None = None) -> jax.Array:
     """The single-device Pallas tier: the pre-pad (:func:`conv_pads`),
-    then the depthwise VPU kernel (:func:`runs_depthwise`; the MXU
-    kernel's tile and dataflow knobs do not apply to it), or tap
-    folding (:func:`tap_fold`), autotune-cache knob fill,
-    differentiable kernel core — or the K > MAX_NATIVE_K adder-tree
+    then the kernel :func:`conv_route` picks — the depthwise VPU kernel
+    (the MXU kernel's tile and dataflow knobs do not apply to it), the
+    MXU kernel over the folded or the plain problem (autotune-cache knob
+    fill, differentiable kernel core), or the K > MAX_NATIVE_K adder-tree
     decomposition (every sub-kernel named ``name``)."""
     k = w.shape[0]
     x = _prepad(x, k, stride, padding)
-    if runs_depthwise(x.shape, w.shape, feature_group_count, x.dtype):
-        return _depthwise_pallas(x, w, stride=stride, bias=bias,
+    route = conv_route(x.shape, w.shape, stride=stride,
+                       groups=feature_group_count, dtype=x.dtype)
+    if route.kind == "depthwise":
+        return _depthwise_pallas(x, w, route, bias=bias,
                                  activation=activation,
                                  use_autotune_cache=use_autotune_cache,
                                  name=name)
-    folded = tap_fold(x.shape, w.shape, stride, feature_group_count)
-    x_shape, w_shape = folded or (x.shape, w.shape)
-    if k <= MAX_NATIVE_K:
+    if route.kind != "tiled":
         if use_autotune_cache and (tile_h is None or tile_cout is None
                                    or dataflow is None):
-            rec = autotune.knobs_for(x_shape, w_shape,
-                                     stride=1 if folded else stride,
-                                     pad=0, groups=feature_group_count,
-                                     dtype=str(x.dtype))
+            rec = route.knobs(str(x.dtype))
             if rec is not None:
                 tile_h = tile_h if tile_h is not None else rec["tile_h"]
                 tile_cout = tile_cout if tile_cout is not None \
                     else rec["tile_cout"]
                 dataflow = dataflow if dataflow is not None \
                     else rec["dataflow"]
-        if folded is not None:
+        if route.kind == "folded":
             # outside the custom_vjp core: autodiff runs through the
             # slices and the concat, then the 1x1 kernel's own backward
             # kernels.  The fold's fusion also writes the kernel's
             # bottom rows up to whole strips, so no second pad is left.
-            plan = ConvPlan.build(x_shape, w_shape, dtype_bytes=x.dtype,
-                                  tile_h=tile_h, tile_cout=tile_cout,
+            plan = ConvPlan.build(route.x_shape, route.w_shape,
+                                  dtype_bytes=x.dtype, tile_h=tile_h,
+                                  tile_cout=tile_cout,
                                   dataflow=dataflow or "carry")
             x = _fold_taps(x, k, k, stride, plan.rows_padded)
-            w, stride = w.reshape(w_shape), 1
+            w = w.reshape(route.w_shape)
             tile_h, tile_cout = plan.tile_h, plan.tile_cout
-        cfg = _ConvVjpConfig(stride=stride, pad=0,
-                             groups=feature_group_count,
+        cfg = _ConvVjpConfig(stride=route.stride, pad=0,
+                             groups=route.groups,
                              activation=activation, tile_h=tile_h,
                              tile_cout=tile_cout,
                              dataflow=dataflow or "carry",
                              use_autotune_cache=use_autotune_cache,
                              name=name)
         out = _conv2d_vjp_core(cfg, x, w, bias)
-        if folded is None:
+        if route.kind == "mxu":
             return out
-        if name is not None:
-            _TAP_FOLDED[name] = w_shape[2]
-        return out[:, :x_shape[1]]
+        return out[:, :route.x_shape[1]]
     # Kernel tiling (paper §III): split K x K into sub-kernels, accumulate.
     # The epilogue is applied once, after the adder tree.  Explicit tile
     # knobs apply to every sub-kernel; the autotune cache is NOT consulted
@@ -1016,7 +1019,7 @@ def depthwise_conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1,
     — fused bias/activation epilogue, autotune-cache consultation, the
     ``custom_vjp`` backward kernels, the ``mesh=`` sharded path — is
     inherited from :func:`conv2d`.  On one device a multiplier-1
-    depthwise conv runs the VPU kernel (:func:`runs_depthwise`); a
+    depthwise conv runs the VPU kernel (:func:`conv_route`); a
     higher multiplier rides the MXU kernel's group grid axis.
     """
     return conv2d(x, w, stride=stride, padding=padding, impl=impl,

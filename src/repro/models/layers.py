@@ -367,16 +367,25 @@ def cnn_pack_params(p: dict, layers_list, *, n: int = 1) -> dict:
     exact shape ``ops.conv2d`` will see — after an
     ``autotune.tune_network`` sweep the packed forward pass runs
     entirely on cached plans."""
-    from repro.core.netplan import layer_kernel_problem
+    return _pack_convs(p, [(f"conv{i}", l) for i, l in
+                           enumerate(layers_list)], n=n)
+
+
+def _pack_convs(p: dict, named_layers, *, n: int) -> dict:
+    """``p`` with each ``(name, layer)``'s conv weights packed, but for
+    the layers ``ops.conv_route`` runs on the adder tree, which
+    re-slices raw weights (§4)."""
+    from repro.core.netplan import layer_kernel_problem, layer_route
     packed = dict(p)
-    for i, l in enumerate(layers_list):
-        if l.kernel > ops.MAX_NATIVE_K:
-            continue    # kernel-tiled path re-slices raw weights (§4)
+    for name, l in named_layers:
         # the shared layer -> executed-problem mapping (validates that
         # the layer's padding is reproducible by the execution path)
         _, _, _, padding = layer_kernel_problem(l, n=n)
-        packed[f"conv{i}"] = conv2d_pack_params(
-            p[f"conv{i}"], groups=l.groups,
+        if layer_route(l, n=n, dtype=str(p[name]["w"].dtype)).kind \
+                == "tiled":
+            continue
+        packed[name] = conv2d_pack_params(
+            p[name], groups=l.groups,
             x_shape=(n, l.ifmap, l.ifmap, l.in_channels),
             stride=l.stride, padding=padding)
     return packed
@@ -536,18 +545,9 @@ def cnn_pack_params_from_graph(p: dict, graph, *, n: int = 1) -> dict:
     analogue of :func:`cnn_pack_params`: each conv node's kernel-seen
     shape keys the autotune cache, so a ``tune_graph`` sweep makes the
     packed forward pass run entirely on cached plans."""
-    from repro.core.netplan import graph_nodes, layer_kernel_problem
-    packed = dict(p)
-    for nd in graph_nodes(graph):
-        if nd.op != "conv" or nd.layer.kernel > ops.MAX_NATIVE_K:
-            continue
-        l = nd.layer
-        _, _, _, padding = layer_kernel_problem(l, n=n)
-        packed[nd.name] = conv2d_pack_params(
-            p[nd.name], groups=l.groups,
-            x_shape=(n, l.ifmap, l.ifmap, l.in_channels),
-            stride=l.stride, padding=padding)
-    return packed
+    from repro.core.netplan import graph_nodes
+    return _pack_convs(p, [(nd.name, nd.layer) for nd in graph_nodes(graph)
+                           if nd.op == "conv"], n=n)
 
 
 def _upsample_nearest(x: jax.Array, scale: int) -> jax.Array:

@@ -57,6 +57,32 @@ def _isolated_convtune_cache(tmp_path, monkeypatch):
     autotune.reset_memory_cache()
 
 
+@pytest.fixture
+def pallas_calls():
+    """``calls(fn, *args)``: ``[(name, operand shapes)]`` of every
+    ``pallas_call`` in the traced program of ``fn(*args)``, nested
+    jaxprs included, in trace order — which kernels the program runs,
+    under which names, over which problems.  ``args`` may be arrays or
+    ``jax.ShapeDtypeStruct``s."""
+    import jax
+
+    def calls(fn, *args):
+        found = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found.append((eqn.params["name"],
+                                  [tuple(v.aval.shape)
+                                   for v in eqn.invars]))
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jax.make_jaxpr(fn)(*args).jaxpr)
+        return found
+    return calls
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "guard_events: the test provokes conv-tier demotions "

@@ -63,27 +63,36 @@ def test_depthwise_kernel_matches_oracle(c, stride, size, epilogue):
 
 
 @pytest.mark.parametrize("stride", [1, 2])
-def test_conv2d_runs_depthwise_on_the_vpu_kernel(stride):
+def test_conv2d_runs_depthwise_on_the_vpu_kernel(pallas_calls, stride):
     """``ops.conv2d`` sends a multiplier-1 depthwise conv to the VPU
-    kernel (counted by its layer name) and the result is the oracle's."""
+    kernel (one kernel named after its layer, over the ``(K*K, C)``
+    taps) and the result is the oracle's."""
     x, w, b = _arr(2, 12, 12, 16), _arr(3, 3, 1, 16, scale=0.3), _arr(16)
-    got = ops.conv2d(x, w, stride=stride, padding=1, feature_group_count=16,
-                     bias=b, activation="relu", layer=f"dw_s{stride}")
+
+    def fwd(x, w, b):
+        return ops.conv2d(x, w, stride=stride, padding=1,
+                          feature_group_count=16, bias=b, activation="relu",
+                          layer=f"dw_s{stride}")
+
+    got = fwd(x, w, b)
     want = ref.conv2d(x, w, stride=stride, padding=1,
                       feature_group_count=16, bias=b, activation="relu")
     _close(got, want)
-    assert ops.depthwise()[f"dw_s{stride}"] == 16
+    [(name, shapes)] = pallas_calls(fwd, x, w, b)
+    assert name == f"dw_s{stride}" and (9, 16) in shapes
 
 
 def test_runs_depthwise_rule():
-    assert ops.runs_depthwise((1, 8, 8, 16), (3, 3, 1, 16), 16, "float32")
+    def kind(x_shape, w_shape, groups, dtype):
+        return ops.conv_route(x_shape, w_shape, stride=1, groups=groups,
+                              dtype=dtype).kind
+
+    assert kind((1, 8, 8, 16), (3, 3, 1, 16), 16, "float32") == "depthwise"
     # a depthwise multiplier of 2, grouped convs, int8, one group
-    assert not ops.runs_depthwise((1, 8, 8, 16), (3, 3, 1, 32), 16,
-                                  "float32")
-    assert not ops.runs_depthwise((1, 8, 8, 16), (3, 3, 2, 16), 8,
-                                  "float32")
-    assert not ops.runs_depthwise((1, 8, 8, 16), (3, 3, 1, 16), 16, "int8")
-    assert not ops.runs_depthwise((1, 8, 8, 1), (3, 3, 1, 1), 1, "float32")
+    assert kind((1, 8, 8, 16), (3, 3, 1, 32), 16, "float32") == "mxu"
+    assert kind((1, 8, 8, 16), (3, 3, 2, 16), 8, "float32") == "mxu"
+    assert kind((1, 8, 8, 16), (3, 3, 1, 16), 16, "int8") == "mxu"
+    assert kind((1, 8, 8, 1), (3, 3, 1, 1), 1, "float32") == "folded"
 
 
 @pytest.mark.parametrize("stride", [1, 2])

@@ -12,7 +12,7 @@ import pytest
 from repro.core import (ConvPlan, NetworkPlan, autotune, network_layers,
                         scale_layers)
 from repro.core.model import ConvLayer
-from repro.core.netplan import infer_pools, pool_between
+from repro.core.netplan import _cached_knobs, infer_pools, pool_between
 from repro.core.roofline import network_roofline
 
 APPROX = dict(rel=1e-6)
@@ -283,11 +283,16 @@ def test_tune_network_sweep_and_consumption(tmp_path):
                                pad=0)
     assert knobs is not None
     assert knobs["tile_h"] == recs["c2"]["tile_h"]
-    # ... and where NetworkPlan(use_autotune_cache=True) looks too (it
-    # plans the unfolded layers, so it finds c2's record, not c1's)
+    # ... and where NetworkPlan(use_autotune_cache=True) looks too: it
+    # plans the layers as stated, so it reads c2's record (an mxu route)
+    # and none for c1, whose folded record describes another problem
     plan = NetworkPlan.build(topo[:3], use_autotune_cache=True)
     assert plan.steps[1].plan.dataflow == recs["c2"]["dataflow"]
     assert plan.steps[1].plan.tile_h == recs["c2"]["tile_h"]
+    knobs = dict(n=1, dtype="float32", backend=None, batch_shards=1,
+                 spatial_shards=1)
+    assert _cached_knobs(topo[0], **knobs) is None
+    assert _cached_knobs(topo[1], **knobs)["tile_h"] == recs["c2"]["tile_h"]
 
 
 def test_tune_network_sharded_namespace():

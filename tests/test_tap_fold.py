@@ -31,20 +31,30 @@ def _arrays(cin, k, cout=8, shape=(2, 9, 8)):
 @pytest.mark.parametrize("stride", [1, 2])
 @pytest.mark.parametrize("k", [3, 5])
 @pytest.mark.parametrize("cin", [1, 3, 14])
-def test_folded_conv_matches_ref(cin, k, stride, padding, epilogue):
+def test_folded_conv_matches_ref(pallas_calls, cin, k, stride, padding,
+                                 epilogue):
     """Every (Cin, K) of the grid against ``ref.conv2d``; the fold
     engages exactly where K*K*Cin fits the lane tile (K=5 with Cin=14,
-    350 lanes, is the unfolded control)."""
+    350 lanes, is the unfolded control): the traced program's one
+    kernel runs a 1x1 over the K*K*Cin folded lanes."""
     x, w, b = _arrays(cin, k)
     kw = dict(bias=b, activation="relu") if epilogue else {}
     layer = f"fold_c{cin}k{k}s{stride}{padding}{int(epilogue)}"
-    got = ops.conv2d(x, w, stride=stride, padding=padding, layer=layer,
-                     **kw)
+
+    def fwd(x, w):
+        return ops.conv2d(x, w, stride=stride, padding=padding,
+                          layer=layer, **kw)
+
+    got = fwd(x, w)
     want = ref.conv2d(x, w, stride=stride, padding=padding, **kw)
     assert got.shape == want.shape
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
-    assert (layer in ops.tap_folded()) == (k * k * cin <= 128)
+    [(name, (xs, ws, *_))] = pallas_calls(fwd, x, w)
+    assert name == layer
+    folds = k * k * cin <= 128
+    assert xs[-1] == (k * k * cin if folds else cin)
+    assert ws[:3] == ((1, 1, k * k * cin) if folds else (k, k, cin))
 
 
 @pytest.mark.parametrize("cin,k,stride,padding", [
@@ -56,7 +66,8 @@ def test_folded_conv_matches_ref(cin, k, stride, padding, epilogue):
 def test_folded_conv_grads_match_ref(cin, k, stride, padding):
     """``jax.grad`` runs through the fold's slices and concat, then the
     1x1 kernel's backward kernels: dx, dw and db match the oracle's."""
-    assert ops.tap_fold((2, 11, 10, cin), (k, k, cin, 8), stride, 1)
+    assert ops.conv_route((2, 11, 10, cin), (k, k, cin, 8), stride=stride,
+                          groups=1, dtype="float32").kind == "folded"
     x, w, b = _arrays(cin, k, shape=(2, 11, 10))
 
     def loss(conv):
@@ -85,18 +96,25 @@ def test_folded_conv_grads_match_ref(cin, k, stride, padding):
 ])
 def test_fold_rule_boundary(w_shape, groups, folds):
     x_shape = (2, 12, 12, w_shape[2] * groups)
-    got = ops.tap_fold(x_shape, w_shape, 1, groups)
-    assert (got is not None) == folds
+    got = ops.conv_route(x_shape, w_shape, stride=1, groups=groups,
+                         dtype="float32")
+    assert (got.kind == "folded") == folds
     if folds:
         k, _, cin, cout = w_shape
-        assert got == ((2, 13 - k, 13 - k, k * k * cin),
-                       (1, 1, k * k * cin, cout))
+        assert (got.x_shape, got.w_shape, got.stride, got.groups) == \
+            ((2, 13 - k, 13 - k, k * k * cin), (1, 1, k * k * cin, cout),
+             1, 1)
+    else:        # the conv runs as it is
+        assert (got.x_shape, got.w_shape, got.stride, got.groups) == \
+            (x_shape, w_shape, 1, groups)
 
 
 def test_folded_shape_with_stride():
     # 'same' pre-padded 15x15 at stride 2: 7x7 outputs
-    assert ops.tap_fold((1, 15, 15, 3), (3, 3, 3, 64), 2, 1) == \
-        ((1, 7, 7, 27), (1, 1, 27, 64))
+    got = ops.conv_route((1, 15, 15, 3), (3, 3, 3, 64), stride=2, groups=1,
+                         dtype="float32")
+    assert (got.kind, got.x_shape, got.w_shape, got.stride) == \
+        ("folded", (1, 7, 7, 27), (1, 1, 27, 64), 1)
 
 
 @pytest.mark.parametrize("stride", [1, 2])
@@ -112,27 +130,18 @@ def test_fold_takes_its_views_without_a_gather(stride):
     assert jaxpr.count("slice[") == 9
 
 
-def test_folded_kernel_keeps_the_layer_name():
+def test_folded_kernel_keeps_the_layer_name(pallas_calls):
     """The folded conv is one Pallas kernel named after its layer, the
-    name a profiler trace shows (``%<layer>.<n>`` on the chip)."""
+    name a profiler trace shows (``%<layer>.<n>`` on the chip), over
+    the 27 folded lanes."""
     x, w, b = _arrays(3, 3)
 
     def fwd(x, w, b):
         return ops.conv2d(x, w, bias=b, activation="relu", layer="conv1")
 
-    jaxpr = jax.make_jaxpr(fwd)(x, w, b)
-    names = []
-
-    def walk(jp):
-        for eqn in jp.eqns:
-            if eqn.primitive.name == "pallas_call":
-                names.append(eqn.params["name"])
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jaxpr.jaxpr)
-    assert names == ["conv1"]
-    assert ops.tap_folded()["conv1"] == 27
+    calls = pallas_calls(fwd, x, w, b)
+    assert [name for name, _ in calls] == ["conv1"]
+    assert calls[0][1][0][-1] == 27
 
 
 def test_tune_network_writes_the_folded_key():

@@ -131,7 +131,7 @@ def test_conv_kernel_carries_its_layer_name(one_chip, monkeypatch,
         kernels
 
 
-# first convs whose taps fold into the lanes (ops.tap_fold), as their
+# first convs whose taps fold into the lanes (ops.conv_route), as their
 # layers see them: (layer, batch, size, C_in, C_out)
 FOLDED = {
     "vgg16_conv1_b32": ("conv1", 32, 224, 3, 64),
@@ -142,7 +142,8 @@ FOLDED = {
 
 @pytest.mark.parametrize("case", sorted(FOLDED))
 @pytest.mark.parametrize("plan", ["default", "tuned"])
-def test_folded_first_conv_compiles(one_chip, monkeypatch, case, plan):
+def test_folded_first_conv_compiles(one_chip, monkeypatch, pallas_calls,
+                                    case, plan):
     """The tap-folded first conv (XLA im2col, then the 1x1 TrIM kernel)
     compiles at published size with the default and the tuner-chosen
     plan, and its one kernel keeps the layer's name."""
@@ -159,13 +160,14 @@ def test_folded_first_conv_compiles(one_chip, monkeypatch, case, plan):
     def fwd(x, w, b):
         return ops.conv2d(x, w, bias=b, activation="relu", layer=layer)
 
-    text = jax.jit(fwd).lower(sds((n, hw, hw, cin)), sds((3, 3, cin, cout)),
-                              sds((cout,))).compile().as_text()
+    args = (sds((n, hw, hw, cin)), sds((3, 3, cin, cout)), sds((cout,)))
+    text = jax.jit(fwd).lower(*args).compile().as_text()
     kernels = [ln.split("=", 1)[0].strip() for ln in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(kernels) == 1 and kernels[0].startswith(f"%{layer}."), \
         kernels
-    assert ops.tap_folded()[layer] == 9 * cin
+    [(_, (xs, *_))] = pallas_calls(fwd, *args)
+    assert xs[-1] == 9 * cin            # the 1x1 over the folded lanes
 
 
 # MobileNetV1's depthwise convs as their layers see them, at the
@@ -179,7 +181,8 @@ DEPTHWISE = {
 
 @pytest.mark.parametrize("case", sorted(DEPTHWISE))
 @pytest.mark.parametrize("plan", ["default", "tuned"])
-def test_depthwise_conv_compiles(one_chip, monkeypatch, case, plan):
+def test_depthwise_conv_compiles(one_chip, monkeypatch, pallas_calls, case,
+                                 plan):
     """A MobileNetV1 depthwise conv through ``ops.conv2d`` compiles for
     the chip on the VPU kernel, strided loads and all, with the default
     and the tuner-chosen plan: one kernel, named after its layer, and
@@ -200,14 +203,15 @@ def test_depthwise_conv_compiles(one_chip, monkeypatch, case, plan):
                           feature_group_count=c, bias=b, activation="relu",
                           layer=layer)
 
-    text = jax.jit(fwd).lower(sds((n, hw, hw, c)), sds((3, 3, 1, c)),
-                              sds((c,))).compile().as_text()
+    args = (sds((n, hw, hw, c)), sds((3, 3, 1, c)), sds((c,)))
+    text = jax.jit(fwd).lower(*args).compile().as_text()
     kernels = [ln.split("=", 1)[0].strip() for ln in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(kernels) == 1 and kernels[0].startswith(f"%{layer}."), \
         kernels
     assert " convolution(" not in text
-    assert ops.depthwise()[layer] == c
+    [(_, shapes)] = pallas_calls(fwd, *args)
+    assert (9, c) in shapes             # the VPU kernel's (K*K, C) taps
 
 
 @pytest.mark.xfail(strict=True, raises=NotImplementedError,
