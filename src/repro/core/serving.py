@@ -474,11 +474,13 @@ class ServingEngine:
         ``tap_folded`` ({layer: K*K*Cin}) and ``depthwise`` ({layer: C})
         name the topology's convs whose route (``ops.conv_route``,
         DESIGN.md §16) folds their taps or runs the depthwise VPU
-        kernel."""
+        kernel; ``fold_strided`` ({layer: stride}) the folded convs
+        whose views are taken by selection, at stride > 1 (§15)."""
         from repro.core.netplan import layer_route
         dtype = self.tune_kwargs.get("dtype", "float32")
-        routes = {layer.name: layer_route(layer, dtype=dtype)
-                  for layer in self.topo or ()}
+        layers = {layer.name: layer for layer in self.topo or ()}
+        routes = {name: layer_route(layer, dtype=dtype)
+                  for name, layer in layers.items()}
         with self._lock:
             per_replica = {
                 name: {"served": self._replica_served[name],
@@ -501,6 +503,10 @@ class ServingEngine:
                 "tap_folded": {name: r.w_shape[2]
                                for name, r in routes.items()
                                if r.kind == "folded"},
+                "fold_strided": {name: layers[name].stride
+                                 for name, r in routes.items()
+                                 if r.kind == "folded"
+                                 and layers[name].stride > 1},
                 "depthwise": {name: r.x_shape[3]
                               for name, r in routes.items()
                               if r.kind == "depthwise"},

@@ -39,6 +39,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 import typing
 
@@ -260,12 +261,16 @@ def _fold_taps(x: jax.Array, kh: int, kw: int, stride: int,
     layout in one copy.  The barrier keeps XLA from choosing the
     kernel's 128-lane layout for the stack itself, which turned each
     view into a lane-padded copy (over 40 ms a step for U-Net's
-    ``(8, 512, 512, 1)`` input on a v5e).  Each view is a strided
-    ``lax.slice``: strided indexing lowers to a gather, which the chip's
-    compiler lays out with the batch in the lanes, 16x padded in a
-    program of 8 images (DESIGN.md §15)."""
+    ``(8, 512, 512, 1)`` input on a v5e).  At stride 1 each view is a
+    ``lax.slice``.  A stride > 1 would put a stride on W, the lane axis,
+    which the chip moves at a few percent of its HBM bandwidth; so there
+    the views are taken by :func:`_select_strided_taps`, two exact 0/1
+    contractions on the MXU (DESIGN.md §15)."""
     h_out = (x.shape[1] - kh) // stride + 1
     w_out = (x.shape[2] - kw) // stride + 1
+    if stride > 1:
+        return _select_strided_taps(jnp.moveaxis(x, 3, 1), kh, kw, stride,
+                                    rows, w_out)
     x = jnp.pad(jnp.moveaxis(x, 3, 1),
                 ((0, 0), (0, 0), (0, (rows - h_out) * stride), (0, 0)))
     n, c = x.shape[:2]
@@ -276,6 +281,31 @@ def _fold_taps(x: jax.Array, kh: int, kw: int, stride: int,
                        (1, 1, stride, stride))
          for ki in range(kh) for kj in range(kw)], axis=1)
     return jnp.moveaxis(jax.lax.optimization_barrier(taps), 1, 3)
+
+
+def _select_strided_taps(x: jax.Array, kh: int, kw: int, stride: int,
+                         rows: int, w_out: int) -> jax.Array:
+    """:func:`_fold_taps` of the channel-major ``x`` ``(N, C, H_p, W_p)``
+    at ``stride > 1``, with no stride on the lane axis: a contraction of
+    W with ``S`` ``(W_p, KW, W_out)``, ``S[w, kj, j] = 1`` iff ``w ==
+    stride*j + kj``, takes the KW column phases lane-dense, and one of H
+    with ``R`` ``(KH, rows, H_p)``, ``R[ki, i, h] = 1`` iff ``h ==
+    stride*i + ki``, the KH row phases, written straight in the tap-major
+    channel-last order; rows past ``H_p`` select nothing and stay zero,
+    as the strided slices of the zero-padded input read them.  Each
+    output sums one input value times 1 and zeros in f32, so at
+    ``HIGHEST`` every value comes back exactly."""
+    n, c, hp, wp = x.shape
+    sel_w = np.equal.outer(np.arange(wp),
+                           stride * np.arange(w_out) + np.arange(kw)[:, None])
+    sel_h = np.equal.outer(stride * np.arange(rows) + np.arange(kh)[:, None],
+                           np.arange(hp))
+    hi = jax.lax.Precision.HIGHEST
+    cols = jnp.einsum("nchw,wkj->nchkj", x, sel_w.astype(x.dtype),
+                      precision=hi)
+    taps = jnp.einsum("aih,nchkj->nijakc", sel_h.astype(x.dtype), cols,
+                      precision=hi)
+    return taps.reshape(n, rows, w_out, kh * kw * c)
 
 
 # the autotune namespace each route kind reads and the tuner writes (the
@@ -727,9 +757,9 @@ def _conv2d_pallas(x: jax.Array, w: jax.Array, *, stride: int,
                     else rec["dataflow"]
         if route.kind == "folded":
             # outside the custom_vjp core: autodiff runs through the
-            # slices and the concat, then the 1x1 kernel's own backward
-            # kernels.  The fold's fusion also writes the kernel's
-            # bottom rows up to whole strips, so no second pad is left.
+            # fold's views, then the 1x1 kernel's own backward kernels.
+            # The fold also writes the kernel's bottom rows up to whole
+            # strips, so no second pad is left.
             plan = ConvPlan.build(route.x_shape, route.w_shape,
                                   dtype_bytes=x.dtype, tile_h=tile_h,
                                   tile_cout=tile_cout,

@@ -2,8 +2,9 @@
 tile runs as a 1x1 TrIM conv over its tap-folded input.
 
 Interpret mode on the CPU: results against the oracle, gradients, the
-rule's boundary, the kernel's name, the tuner's key and the serving
-engine's ``tap_folded`` report."""
+rule's boundary, the stride-2 fold's selection views against strided
+slices, the kernel's name, the tuner's key and the serving engine's
+``tap_folded`` and ``fold_strided`` reports."""
 
 import jax
 import jax.numpy as jnp
@@ -64,8 +65,9 @@ def test_folded_conv_matches_ref(pallas_calls, cin, k, stride, padding,
     (5, 5, 2, "valid"),
 ])
 def test_folded_conv_grads_match_ref(cin, k, stride, padding):
-    """``jax.grad`` runs through the fold's slices and concat, then the
-    1x1 kernel's backward kernels: dx, dw and db match the oracle's."""
+    """``jax.grad`` runs through the fold's views (slices and concat at
+    stride 1, selection contractions at stride 2), then the 1x1 kernel's
+    backward kernels: dx, dw and db match the oracle's."""
     assert ops.conv_route((2, 11, 10, cin), (k, k, cin, 8), stride=stride,
                           groups=1, dtype="float32").kind == "folded"
     x, w, b = _arrays(cin, k, shape=(2, 11, 10))
@@ -117,17 +119,64 @@ def test_folded_shape_with_stride():
         ("folded", (1, 7, 7, 27), (1, 1, 27, 64), 1)
 
 
+def _slice_fold(x, kh, kw, stride, rows):
+    """The fold as K*K strided ``lax.slice`` views of the zero-padded,
+    channel-major input, stacked tap-major and moved channel-last: the
+    construction the selection path must reproduce bit for bit."""
+    h_out = (x.shape[1] - kh) // stride + 1
+    w_out = (x.shape[2] - kw) // stride + 1
+    x = jnp.pad(jnp.moveaxis(x, 3, 1),
+                ((0, 0), (0, 0), (0, (rows - h_out) * stride), (0, 0)))
+    n, c = x.shape[:2]
+    taps = jnp.concatenate(
+        [jax.lax.slice(x, (0, 0, ki, kj),
+                       (n, c, ki + (rows - 1) * stride + 1,
+                        kj + (w_out - 1) * stride + 1),
+                       (1, 1, stride, stride))
+         for ki in range(kh) for kj in range(kw)], axis=1)
+    return jnp.moveaxis(taps, 1, 3)
+
+
+@pytest.mark.parametrize("shape,rows", [
+    ((2, 226, 226, 3), 112),    # MobileNetV1's pre-padded stem
+    ((1, 15, 15, 3), 7),        # odd size
+    ((1, 15, 15, 3), 10),       # rows past H_out: the plan's strip rows
+    ((2, 17, 16, 1), 9),        # Cin 1: 9 lanes
+    ((2, 17, 16, 14), 8),       # Cin 14: 126 lanes
+])
+def test_strided_fold_equals_the_slice_fold(shape, rows):
+    """At stride 2 the fold's selection contractions give every value of
+    every view, and the rows past ``H_out``, exactly as the strided
+    slices do: same tap-major order, bit for bit."""
+    x = jnp.asarray(RNG.standard_normal(shape)
+                    * 10.0 ** RNG.integers(-6, 6, shape), jnp.float32)
+    got = jax.jit(lambda x: ops._fold_taps(x, 3, 3, 2, rows))(x)
+    want = jax.jit(lambda x: _slice_fold(x, 3, 3, 2, rows))(x)
+    assert got.shape == want.shape == (shape[0], rows, (shape[2] - 1) // 2,
+                                       9 * shape[3])
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
+
+
 @pytest.mark.parametrize("stride", [1, 2])
 def test_fold_takes_its_views_without_a_gather(stride):
-    """The im2col's K*K views are strided slices, never a gather: the
-    chip's compiler lays a gather out with the batch in the lanes, which
-    a chunk of 8 images fills to a sixteenth."""
+    """The im2col's K*K views are never a gather: the chip's compiler
+    lays a gather out with the batch in the lanes, which a chunk of 8
+    images fills to a sixteenth.  At stride 1 they are nine slices; at
+    stride 2 no slice has a stride on the last (lane) axis: the views
+    are selection contractions."""
     x = jnp.zeros((8, 17, 17, 3), jnp.float32)
     rows = (17 - 3) // stride + 1
-    jaxpr = str(jax.make_jaxpr(
-        lambda x: ops._fold_taps(x, 3, 3, stride, rows))(x))
-    assert "gather" not in jaxpr
-    assert jaxpr.count("slice[") == 9
+    jaxpr = jax.make_jaxpr(
+        lambda x: ops._fold_taps(x, 3, 3, stride, rows))(x)
+    assert "gather" not in str(jaxpr)
+    slices = [e for e in jaxpr.eqns if e.primitive.name == "slice"]
+    if stride == 1:
+        assert len(slices) == 9
+    else:
+        assert all((e.params["strides"] or (1,))[-1] == 1 for e in slices)
+        assert sum(e.primitive.name == "dot_general"
+                   for e in jaxpr.eqns) == 2
 
 
 def test_folded_kernel_keeps_the_layer_name(pallas_calls):
@@ -203,6 +252,22 @@ def test_engine_reports_the_folded_layers():
     eng = ServingEngine.for_topology(topo, params, buckets=(1, 2))
     eng.prewarm()
     assert eng.stats()["tap_folded"] == {"tf0": 27}
+
+
+@pytest.mark.parametrize("net,want", [("mobilenetv1", {"conv1": 2}),
+                                      ("vgg16", {})])
+def test_engine_reports_the_strided_folds(net, want):
+    """``ServingEngine.stats()["fold_strided"]`` names the folded convs
+    whose views are taken by selection, with their stride: MobileNetV1's
+    3x3/s2 stem, none of VGG-16's (its conv1 folds at stride 1)."""
+    from repro.core.model import mobilenet_v1_layers
+    from repro.core.netplan import network_layers
+    from repro.core.serving import ServingEngine
+    topo = (mobilenet_v1_layers() if net == "mobilenetv1"
+            else network_layers(net))
+    stats = ServingEngine.for_topology(topo, {}, buckets=(8,)).stats()
+    assert stats["fold_strided"] == want
+    assert stats["tap_folded"] == {"conv1": 27}
 
 
 def test_folded_conv_never_fuses_and_fused_stays_bitwise():

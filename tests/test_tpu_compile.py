@@ -16,6 +16,7 @@ test file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -132,11 +133,12 @@ def test_conv_kernel_carries_its_layer_name(one_chip, monkeypatch,
 
 
 # first convs whose taps fold into the lanes (ops.conv_route), as their
-# layers see them: (layer, batch, size, C_in, C_out)
+# layers see them: (layer, batch, size, C_in, C_out, stride)
 FOLDED = {
-    "vgg16_conv1_b32": ("conv1", 32, 224, 3, 64),
-    "vgg16_conv1_b1": ("conv1", 1, 224, 3, 64),
-    "unet_enc0a_b8": ("enc0a", 8, 512, 1, 64),
+    "vgg16_conv1_b32": ("conv1", 32, 224, 3, 64, 1),
+    "vgg16_conv1_b1": ("conv1", 1, 224, 3, 64, 1),
+    "unet_enc0a_b8": ("enc0a", 8, 512, 1, 64, 1),
+    "mobilenetv1_conv1_b8": ("conv1", 8, 224, 3, 32, 2),
 }
 
 
@@ -146,19 +148,22 @@ def test_folded_first_conv_compiles(one_chip, monkeypatch, pallas_calls,
                                     case, plan):
     """The tap-folded first conv (XLA im2col, then the 1x1 TrIM kernel)
     compiles at published size with the default and the tuner-chosen
-    plan, and its one kernel keeps the layer's name."""
+    plan, and its one kernel keeps the layer's name.  At stride 2 no
+    slice of the compiled program has a stride on the last (lane) axis:
+    the views are selection contractions."""
     from repro.core.model import ConvLayer
     from repro.kernels import ops, runtime
     monkeypatch.setattr(runtime, "on_tpu", lambda: True)
-    layer, n, hw, cin, cout = FOLDED[case]
+    layer, n, hw, cin, cout, stride = FOLDED[case]
     if plan == "tuned":
         autotune.tune_network([ConvLayer(layer, hw, cin, cout, kernel=3,
-                                         padding=1)], n=n)
+                                         stride=stride, padding=1)], n=n)
     sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,  # noqa
                                              sharding=one_chip)
 
     def fwd(x, w, b):
-        return ops.conv2d(x, w, bias=b, activation="relu", layer=layer)
+        return ops.conv2d(x, w, stride=stride, padding=1, bias=b,
+                          activation="relu", layer=layer)
 
     args = (sds((n, hw, hw, cin)), sds((3, 3, cin, cout)), sds((cout,)))
     text = jax.jit(fwd).lower(*args).compile().as_text()
@@ -168,6 +173,8 @@ def test_folded_first_conv_compiles(one_chip, monkeypatch, pallas_calls,
         kernels
     [(_, (xs, *_))] = pallas_calls(fwd, *args)
     assert xs[-1] == 9 * cin            # the 1x1 over the folded lanes
+    assert "gather(" not in text
+    assert not re.search(r"slice=\{[^}]*:2\]\}", text)
 
 
 # MobileNetV1's depthwise convs as their layers see them, at the
