@@ -4,10 +4,25 @@ the peaks table.
 
 Everything here is the yardstick and reads nothing of the program under
 test.  A configuration file describes a convolutional network as a list
-of nodes (``conv``, ``pool``, ``concat``, ``upsample``) plus an optional
-global-mean-pool linear ``head``; shapes are propagated from the input,
-so only each conv's kernel size, padding and output width are written
-down.  The work counts follow from those shapes alone:
+of nodes plus an optional global-mean-pool linear ``head``; shapes are
+propagated from the input, so only each conv's kernel size, padding and
+output width are written down.  A node reads the node before it, or the
+nodes it names under ``"in"``.  The ops:
+
+* ``conv``: ``k``, ``cout``, optional ``pad``, ``stride``, ``groups``;
+  a ReLU follows unless ``"relu": false`` (ResNet's last conv of a
+  block and its projection shortcut carry none);
+* ``pool``: a max pool of ``window`` and ``stride``, optionally padded
+  by ``pad`` with -inf on each side, as PyTorch's ``MaxPool2d`` pads
+  (``pad`` at most ``window // 2``, so no window sees padding alone);
+* ``add``: the elementwise sum of the nodes under ``"in"``, all of one
+  size and width; a ReLU follows only with ``"relu": true``
+  (a residual join);
+* ``concat``: the nodes under ``"in"`` joined along the channels, all of
+  one size;
+* ``upsample``: nearest-neighbour, by ``scale``.
+
+The work counts follow from those shapes alone:
 
 * FLOPs of a conv: ``2 * H_out * W_out * C_out * C_in / groups * K^2``
   per image (the algorithm's, whatever implements it);
@@ -113,18 +128,19 @@ class Node:
     """One node with its propagated shapes (square spatial dims)."""
 
     name: str
-    op: str                     # conv | pool | concat | upsample
+    op: str                     # conv | pool | add | concat | upsample
     inputs: tuple[str, ...]     # () for the node reading the network input
     size_in: int
     cin: int
     size: int                   # output spatial size
     channels: int               # output channels
     k: int = 1
-    pad: int = 0
+    pad: int = 0                # a conv's or a pool's, on each side
     groups: int = 1
     window: int = 1
     stride: int = 1
     scale: int = 1
+    relu: bool = False          # a ReLU after the node's output
 
     @property
     def flops(self) -> int:
@@ -139,6 +155,24 @@ class Node:
             return 0
         return self.k * self.k * (self.cin // self.groups) * self.channels \
             + self.channels
+
+
+#: whether a node's output passes a ReLU when it says nothing
+_RELU_DEFAULT = {"conv": True, "add": False}
+
+
+def _relu(raw: dict) -> bool:
+    """A node's ``"relu"``: only a conv or an add may state it, and only
+    as a boolean."""
+    op = raw["op"]
+    if "relu" not in raw:
+        return _RELU_DEFAULT.get(op, False)
+    if op not in _RELU_DEFAULT:
+        raise ValueError(f"{raw['name']}: op {op!r} takes no \"relu\"")
+    if not isinstance(raw["relu"], bool):
+        raise ValueError(f"{raw['name']}: \"relu\" is {raw['relu']!r}, "
+                         "not true or false")
+    return raw["relu"]
 
 
 class NetSpec:
@@ -167,19 +201,31 @@ class NetSpec:
                 size_in, cin = shape[ins[0]]
             else:
                 size_in, cin = self.image, self.in_channels
-            kw = {}
+            kw = {"relu": _relu(raw)}
             if op == "conv":
                 k, pad = int(raw["k"]), int(raw.get("pad", 0))
                 groups = int(raw.get("groups", 1))
                 s = int(raw.get("stride", 1))
                 cout = max(1, int(raw["cout"]) // channel_divisor)
                 size = (size_in + 2 * pad - k) // s + 1
-                kw = dict(k=k, pad=pad, groups=groups, stride=s)
+                kw.update(k=k, pad=pad, groups=groups, stride=s)
                 out = (size, cout)
             elif op == "pool":
                 w, s = int(raw["window"]), int(raw["stride"])
-                out = ((size_in - w) // s + 1, cin)
-                kw = dict(window=w, stride=s)
+                pad = int(raw.get("pad", 0))
+                if not 0 <= pad <= w // 2:
+                    raise ValueError(f"{raw['name']}: pool pad {pad} is not "
+                                     f"in [0, window // 2 = {w // 2}]")
+                out = ((size_in + 2 * pad - w) // s + 1, cin)
+                kw.update(window=w, stride=s, pad=pad)
+            elif op == "add":
+                shapes = {shape[i] for i in ins}
+                if len(ins) < 2 or len(shapes) != 1:
+                    raise ValueError(f"{raw['name']}: add of shapes "
+                                     f"{[shape[i] for i in ins]} (size, "
+                                     "channels): needs two or more inputs "
+                                     "of one shape")
+                out = (size_in, cin)
             elif op == "concat":
                 sizes = {shape[i][0] for i in ins}
                 if len(sizes) != 1:
@@ -189,7 +235,7 @@ class NetSpec:
             elif op == "upsample":
                 sc = int(raw["scale"])
                 out = (size_in * sc, cin)
-                kw = dict(scale=sc)
+                kw.update(scale=sc)
             else:
                 raise ValueError(f"{raw['name']}: unknown op {op!r}")
             node = Node(name=raw["name"], op=op, inputs=ins,

@@ -2,7 +2,8 @@
 
 The reference every answer of a run is compared with: each node of the
 configuration (``cellspec.NetSpec``) as one ``lax`` operation (conv,
-max pool, concat, nearest upsample), ReLU after every conv, and the
+max pool padded with -inf, residual add summed in float32, concat,
+nearest upsample), a ReLU after each node that states one, and the
 global-mean-pool linear head.  It imports nothing of the program under
 test and takes only what the benchmark made: the seeded weights and
 inputs.
@@ -70,16 +71,23 @@ def forward(spec, params, x, *, precision: str = "highest"):
         if nd.op == "conv":
             p = params[nd.name]
             y = _conv(src[0], p["w"], nd, precision) + p["b"]
-            y = jnp.maximum(y, 0.0)
         elif nd.op == "pool":
             y = lax.reduce_window(src[0], -jnp.inf, lax.max,
                                   (1, nd.window, nd.window, 1),
-                                  (1, nd.stride, nd.stride, 1), "VALID")
+                                  (1, nd.stride, nd.stride, 1),
+                                  [(0, 0), (nd.pad, nd.pad),
+                                   (nd.pad, nd.pad), (0, 0)])
+        elif nd.op == "add":
+            y = src[0].astype(jnp.float32)
+            for s in src[1:]:
+                y = y + s.astype(jnp.float32)
         elif nd.op == "concat":
             y = jnp.concatenate(src, axis=-1)
         elif nd.op == "upsample":
             y = jnp.repeat(jnp.repeat(src[0], nd.scale, axis=1),
                            nd.scale, axis=2)
+        if nd.relu:
+            y = jnp.maximum(y, 0.0)
         outs[nd.name] = y
     if spec.classes is None:
         return y

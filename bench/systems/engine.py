@@ -12,12 +12,18 @@ from __future__ import annotations
 
 def chain_layers(spec):
     """The program's topology for a conv/pool chain, checked against the
-    pools the program infers between consecutive layers."""
+    pools the program infers between consecutive layers.  The chain
+    puts a ReLU after every conv and pads no pool: a node that states
+    otherwise is an error naming it."""
     from repro.core.model import ConvLayer
     from repro.core.netplan import infer_pools
 
     layers, pools = [], []
     for nd in spec.nodes:
+        if nd.op == "conv" and not nd.relu or nd.op == "pool" and nd.pad:
+            raise ValueError(f"{spec.name}: node {nd.name} ({nd.op}) has no "
+                             "ReLU or a padded pool, which the chain "
+                             "program cannot serve; use entry 'graph'")
         if nd.op == "conv":
             layers.append(ConvLayer(nd.name, ifmap=nd.size_in,
                                     in_channels=nd.cin,

@@ -3,6 +3,7 @@ size: sound runs are correct, runs with the timed path broken underneath
 are not, a run without a TPU or without the program prints no result,
 and a new cell made of new files alone runs."""
 
+import copy
 import dataclasses
 import json
 import os
@@ -10,6 +11,7 @@ import shutil
 import subprocess
 import sys
 import time
+import types
 
 import numpy as np
 import pytest
@@ -176,3 +178,111 @@ def test_new_cell_from_new_files_alone(tmp_path):
             assert 1 <= got["cpu.engine.rows_per_step"]["value"] <= 4
         else:
             assert set(got) == {"cpu.latency_p50_ms", "cpu.setup_s"}
+
+
+RESIDUAL_TRAFFIC = {"kind": "batch_loop", "batch": 2, "buckets": [2],
+                    "pool": 8, "warmup_s": 0.3}
+
+
+def _residual_root(tmp_path):
+    """A checkout with a residual cell, ``resnet-tiny-f32.duo``, made of
+    new files (a configuration with a padded pool, convs without ReLU
+    and an add with one; a traffic mix) and their entries alone."""
+    root = str(tmp_path)
+    _copy_benchmark(root)
+    bench = os.path.join(root, "bench")
+    shutil.copy(os.path.join(cellspec.BENCH_DIR, "tests", "data",
+                             "resnet-tiny-f32.json"),
+                os.path.join(bench, "configs"))
+    with open(os.path.join(bench, "traffic", "duo.json"), "w") as f:
+        json.dump(RESIDUAL_TRAFFIC, f)
+    bj = os.path.join(root, "BENCHMARK.json")
+    b = cellspec.read_json(bj)
+    b["configs"].append({"name": "resnet-tiny-f32", "source": "a test",
+                         "file": "bench/configs/resnet-tiny-f32.json",
+                         "reduced": [], "why": "test"})
+    b["workloads"].append({"name": "resnet-tiny-f32.duo",
+                           "config": "resnet-tiny-f32", "traffic": "duo",
+                           "chips": 1, "why": "test"})
+    for m in b["end_to_end"]:
+        if "vgg16-f32.saturate" in m.get("workloads", ()):
+            m["workloads"].append("resnet-tiny-f32.duo")
+    with open(bj, "w") as f:
+        json.dump(b, f)
+    return root
+
+
+def test_residual_cell_from_new_files_alone(tmp_path):
+    """The residual cell runs from new files alone up to the system under
+    test.  The program serves it correctly; or, while the program's
+    ``GraphNode`` has no field to carry a conv without ReLU, an add with
+    one or a padded pool, the run stops at set-up naming the node and
+    prints no result: never a wrong answer."""
+    from repro.core.model import GraphNode
+    root = _residual_root(tmp_path)
+    p = _run(root, "--workload", "resnet-tiny-f32.duo", "--seed",
+             str(2**35 + 1), "--seconds", "0.5", "--trace", "0",
+             "--rehearse")
+    if {"relu", "pool_pad"} <= {f.name for f in
+                                dataclasses.fields(GraphNode)}:
+        assert p.returncode == 0, p.stderr[-3000:]
+        res = json.loads(p.stdout.strip().splitlines()[-1])
+        assert res["correct"], res["checks"]
+        assert set(res["rehearsal_metrics"]) == {"cpu.images_per_s",
+                                                 "cpu.setup_s"}
+    else:
+        assert p.returncode != 0
+        assert p.stdout == ""
+        assert "node pool1 (pool)" in p.stderr
+        assert "GraphNode has no field pool_pad" in p.stderr
+
+
+def _reference_in_programs_place(relu_on_every_conv):
+    """A system that serves the reference forward: as the configuration
+    states it, or with a ReLU after every conv, as the program's
+    executor puts its default activation on each."""
+    def build(spec, params, buckets):
+        import jax.numpy as jnp
+        from repro.core.serving import Replica, ServingEngine
+        ref = cellspec.load_module("reference", "cnn")
+        served = copy.copy(spec)
+        if relu_on_every_conv:
+            served.nodes = [dataclasses.replace(n, relu=True)
+                            if n.op == "conv" else n for n in spec.nodes]
+        fwd = ref.make_forward(served)
+
+        def fn(batch):
+            return np.asarray(fwd(params, jnp.asarray(np.asarray(batch))))
+        engine = ServingEngine([Replica("replica0", fn)], tuple(buckets),
+                               input_shape=spec.input_shape)
+        engine.prewarm(tune=False)
+        return engine
+    return types.SimpleNamespace(build=build)
+
+
+@pytest.mark.parametrize("relu_on_every_conv", [False, True])
+def test_residual_cell_sees_a_relu_on_every_conv(tmp_path, monkeypatch,
+                                                 relu_on_every_conv):
+    """The residual cell, the reference in the program's place: correct
+    as the configuration states it, not correct with a ReLU after the
+    block's last conv and its projection."""
+    root = _residual_root(tmp_path)
+    load = cellspec.load_module
+
+    def load_module(kind, name, *args, **kw):
+        if (kind, name) == ("systems", "graph"):
+            return _reference_in_programs_place(relu_on_every_conv)
+        return load(kind, name, *args, **kw)
+    monkeypatch.setattr(cellspec, "load_module", load_module)
+    monkeypatch.setattr(cellspec, "BENCH_DIR", os.path.join(root, "bench"))
+    cell = cellspec.find_cell("resnet-tiny-f32.duo", root)
+    res = harness.run(cell, seed=2**36 + 3, seconds=0.5, trace=False,
+                      rehearse=True, t_start=time.monotonic(),
+                      log=lambda m: None)
+    err = res["checks"]["max_rel_err"]
+    if relu_on_every_conv:
+        assert res["correct"] is False
+        assert err["value"] > 100 * err["limit"]
+    else:
+        assert res["correct"], res["checks"]
+        assert res["failed"] == 0 and res["attempted"] > 0

@@ -80,6 +80,126 @@ def test_shapes_are_checked():
         cellspec.NetSpec(cat)
 
 
+def resnet50_v15():
+    """ResNet-50 v1.5 in the configuration format: Table 1's 50-layer
+    column of arXiv:1512.03385 with each stage's stride on the
+    bottleneck's 3x3 conv (torchvision's ``resnet50``), batch norm
+    folded into one bias per conv."""
+    nodes = [{"name": "conv1", "op": "conv", "k": 7, "stride": 2, "pad": 3,
+              "cout": 64},
+             {"name": "pool1", "op": "pool", "window": 3, "stride": 2,
+              "pad": 1}]
+    prev = "pool1"
+    for stage, (blocks, width) in enumerate([(3, 64), (4, 128), (6, 256),
+                                             (3, 512)]):
+        for i in range(blocks):
+            s = 2 if i == 0 and stage > 0 else 1
+            b = f"s{stage + 2}b{i + 1}"
+            nodes += [
+                {"name": f"{b}a", "op": "conv", "in": [prev], "k": 1,
+                 "cout": width},
+                {"name": f"{b}b", "op": "conv", "k": 3, "stride": s,
+                 "pad": 1, "cout": width},
+                {"name": f"{b}c", "op": "conv", "k": 1, "cout": 4 * width,
+                 "relu": False}]
+            short = prev
+            if i == 0:
+                short = f"{b}p"
+                nodes.append({"name": short, "op": "conv", "in": [prev],
+                              "k": 1, "stride": s, "cout": 4 * width,
+                              "relu": False})
+            nodes.append({"name": b, "op": "add", "in": [f"{b}c", short],
+                          "relu": True})
+            prev = b
+    return {"name": "resnet50-v1.5", "dtype": "float32", "reference": "cnn",
+            "input": {"size": 224, "channels": 3}, "nodes": nodes,
+            "head": {"classes": 1000}}
+
+
+def test_resnet50_work():
+    spec = cellspec.NetSpec(resnet50_v15())
+    assert spec.by_name["pool1"].size == 56
+    last = spec.nodes[-1]
+    assert (last.op, last.size, last.channels, last.relu) == \
+        ("add", 7, 2048, True)
+    convs = spec.convs
+    assert len(convs) == 53
+    assert sorted({n.k for n in convs}) == [1, 3, 7]
+    assert [sum(n.k == k for n in convs) for k in (1, 3, 7)] == [36, 16, 1]
+    assert sum(not n.relu for n in convs) == 16 + 4
+    assert spec.conv_flops_per_image == 2 * 4_087_136_256
+    assert sum(n.n_params for n in convs) == 23_481_472
+    assert spec.n_params == 25_530_472
+    assert spec.out_shape == (1000,)
+
+
+def _net(*nodes):
+    return {"name": "t", "dtype": "float32", "reference": "cnn",
+            "input": {"size": 112, "channels": 8}, "nodes": list(nodes)}
+
+
+STEM = {"name": "c0", "op": "conv", "k": 3, "pad": 1, "cout": 8}
+
+
+@pytest.mark.parametrize("pad,size", [(0, 55), (1, 56)])
+def test_padded_pool_size(pad, size):
+    spec = cellspec.NetSpec(_net(STEM, {"name": "p", "op": "pool",
+                                        "window": 3, "stride": 2,
+                                        "pad": pad}))
+    node = spec.by_name["p"]
+    assert (node.size, node.channels, node.pad) == (size, 8, pad)
+
+
+def test_add_and_relu_defaults():
+    spec = cellspec.NetSpec(_net(
+        STEM, {"name": "c1", "op": "conv", "k": 1, "cout": 8, "relu": False},
+        {"name": "a", "op": "add", "in": ["c0", "c1"]},
+        {"name": "r", "op": "add", "in": ["a", "c0"], "relu": True}))
+    got = {n.name: (n.relu, n.size, n.channels, n.flops, n.n_params)
+           for n in spec.nodes}
+    assert got["c0"][0] is True and got["c1"][0] is False
+    assert got["a"] == (False, 112, 8, 0, 0)
+    assert got["r"] == (True, 112, 8, 0, 0)
+
+
+@pytest.mark.parametrize("bad,match", [
+    ({"name": "bad", "op": "add", "in": ["c0", "wide"]}, "add of shapes"),
+    ({"name": "bad", "op": "add", "in": ["c0", "half"]}, "add of shapes"),
+    ({"name": "bad", "op": "add", "in": ["half"]}, "two or more"),
+    ({"name": "bad", "op": "pool", "window": 2, "stride": 2, "relu": True},
+     "takes no"),
+    ({"name": "bad", "op": "concat", "in": ["c0", "wide"], "relu": False},
+     "takes no"),
+    ({"name": "bad", "op": "conv", "k": 1, "cout": 8, "relu": 0},
+     "not true or false"),
+    ({"name": "bad", "op": "pool", "window": 3, "stride": 2, "pad": 2},
+     "pool pad"),
+    ({"name": "bad", "op": "pool", "window": 2, "stride": 2, "pad": -1},
+     "pool pad"),
+])
+def test_malformed_nodes_are_refused_by_name(bad, match):
+    net = _net(STEM,
+               {"name": "wide", "op": "conv", "in": ["c0"], "k": 1,
+                "cout": 16},
+               {"name": "half", "op": "conv", "in": ["c0"], "k": 1,
+                "stride": 2, "cout": 8},
+               bad)
+    with pytest.raises(ValueError, match=f"^bad: .*{match}"):
+        cellspec.NetSpec(net)
+
+
+@pytest.mark.parametrize("node", [
+    {"name": "bad", "op": "conv", "k": 1, "cout": 8, "relu": False},
+    {"name": "bad", "op": "pool", "window": 3, "stride": 2, "pad": 1},
+    {"name": "bad", "op": "add", "in": ["c0", "c1"]},
+])
+def test_chain_refuses_what_it_cannot_serve(node):
+    net = _net(STEM, {"name": "c1", "op": "conv", "k": 1, "cout": 8}, node)
+    sys_engine = cellspec.load_module("systems", "engine")
+    with pytest.raises(ValueError, match="node bad "):
+        sys_engine.chain_layers(cellspec.NetSpec(net))
+
+
 def test_configurations_are_the_programs_networks():
     """The configurations describe the networks the program builds by
     name: VGG-16's conv chain and the U-Net graph of ``unet_graph``."""
@@ -103,6 +223,9 @@ def test_configurations_are_the_programs_networks():
         shape = None if n.layer is None else (
             n.layer.ifmap, n.layer.in_channels, n.layer.out_channels,
             n.layer.kernel, n.layer.stride, n.layer.padding)
+        # relu / pool_pad: the graph adapter's fields for a departure
+        # from the executor's defaults (None: the op's default)
         return (n.name, n.op, tuple(n.inputs), shape, n.pool,
-                n.pool_window, n.scale)
+                n.pool_window, n.scale, getattr(n, "relu", None),
+                getattr(n, "pool_pad", 0))
     assert [key(n) for n in mine] == [key(n) for n in theirs]
