@@ -452,6 +452,17 @@ class ConvPlan:
         return (1, self.tile_h + self.kh - 1, self.wp, self.cin_per_group)
 
     @property
+    def lane_chunks(self) -> tuple[int, int]:
+        """``(lanes, chunks)`` of a ``stride > 1`` window (DESIGN.md
+        §17): the chip loads a ref with a stride only where its base
+        buffer is at most one lane tile wide, so the kernel takes the
+        window as ``chunks`` refs of ``lanes`` channels — one lane tile
+        (128) each, or the whole group where it is narrower — padding
+        the channels with zeros up to ``lanes * chunks``."""
+        lanes = min(self.cin_per_group, LANES)
+        return lanes, -(-self.cin_per_group // lanes)
+
+    @property
     def halo_padded_input_shape(self) -> tuple[int, int, int, int]:
         """Padded input with K-1 extra zero rows on top so strip 0's
         overlapping window starts at element row 0."""
@@ -470,7 +481,12 @@ class ConvPlan:
         value the taps slice, one tap's matmul operand and the f32 (or
         int32) accumulator; for f32 operands the full-precision matmul's
         split operands and partial products (:data:`F32_MATMUL_BUFFERS`).
-        The int8 route writes f32 outputs.
+        The int8 route writes f32 outputs.  At ``stride > 1`` the taps
+        are strided loads (the window value is then the carry's staged
+        scratch), and Mosaic holds every tap's loaded view at once: one
+        ``(TH_out, W_out, lanes)`` buffer per tap and lane chunk
+        (measured on the v5e compiler: the 7x7/s2 stem needs 14, 29 and
+        53 MiB at ``tile_h`` 8, 16 and 28).
         """
         db = self.dtype_bytes
         cin, tc = self.cin_per_group, self.tile_cout
@@ -487,6 +503,10 @@ class ConvPlan:
             + vmem_tile_bytes((rows_out, tc), 4))
         if self.dataflow == "carry":
             total += vmem_tile_bytes(self.carry_shape, db)
+        if self.stride > 1:
+            lanes, chunks = self.lane_chunks
+            total += self.kh * self.kw * chunks * vmem_tile_bytes(
+                (self.th_out, self.w_out, lanes), db)
         if db == 4:
             total += F32_MATMUL_BUFFERS * vmem_tile_bytes(
                 (rows_out, max(cin, tc)), 4)

@@ -313,6 +313,14 @@ class GraphNode:
     network input (exactly one such source node per graph).  Joins
     perform no MACs — their cost is pure activation traffic, which is
     the quantity the residency pass arbitrates.
+
+    ``relu`` departs from an op's default activation: ``None`` keeps it
+    (a conv takes the executor's ``activation``, an add none), a conv's
+    ``False`` runs it with no activation (ResNet's last conv of a block
+    and its projection shortcut), an add's ``True`` puts a ReLU after
+    the sum.  ``pool_pad`` pads the max pool (a pool node's, or a conv's
+    epilogue pool) with -inf on each side, as PyTorch's
+    ``MaxPool2d(padding=...)`` (ResNet's 3x3/s2 pool pads 1).
     """
 
     name: str
@@ -322,6 +330,8 @@ class GraphNode:
     pool: int = 1
     pool_window: int = 1
     scale: int = 1
+    relu: bool | None = None
+    pool_pad: int = 0
 
     def __post_init__(self):
         if self.op not in ("conv", "pool", "add", "concat", "upsample"):
@@ -332,6 +342,20 @@ class GraphNode:
                              " a ConvLayer")
         if self.op != "conv" and not self.inputs:
             raise ValueError(f"node {self.name}: op={self.op!r} needs inputs")
+        if self.relu is not None and self.op not in ("conv", "add"):
+            raise ValueError(f"node {self.name}: op={self.op!r} takes no "
+                             f"relu (got {self.relu!r})")
+        if not 0 <= self.pool_pad <= self.pool_window // 2:
+            raise ValueError(f"node {self.name}: pool_pad={self.pool_pad} "
+                             f"is not in [0, pool_window // 2 = "
+                             f"{self.pool_window // 2}]")
+
+    def activation(self, default: str | None) -> str | None:
+        """The activation after this node's output, given the
+        executor's ``default`` for convs."""
+        if self.relu is None:
+            return default if self.op == "conv" else None
+        return "relu" if self.relu else None
 
 
 def resnet18_graph(image: int = 224, base: int = 64) -> list[GraphNode]:
@@ -364,6 +388,46 @@ def resnet18_graph(image: int = 224, base: int = 64) -> list[GraphNode]:
                 skip = ds.name
             nodes.append(GraphNode(f"{tag}_add", "add", (c2.name, skip)))
             prev, size, cin = f"{tag}_add", c1.out_size, cout
+    return nodes
+
+
+def resnet50_graph(image: int = 224, base: int = 64) -> list[GraphNode]:
+    """ResNet-50 v1.5 feature extractor as a DAG (He et al.,
+    arXiv:1512.03385, Table 1, 50-layer column): a 7x7/s2 stem, a 3x3/s2
+    max pool padded by 1, then 3-4-6-3 bottleneck blocks of widths
+    ``base`` x 1/2/4/8 with outputs x4.  A block is 1x1 -> 3x3 -> 1x1,
+    the last without ReLU, summed with its shortcut (a 1x1 projection
+    without ReLU on each stage's first block) and then a ReLU.  "v1.5"
+    (torchvision's ``resnet50``, MLPerf Inference) puts a stage's stride
+    on the 3x3 conv.  Block ``s<stage>b<i>`` has convs ``...a``, ``...b``,
+    ``...c``, projection ``...p`` and the add ``s<stage>b<i>`` itself.
+    ``image``/``base`` shrink it for the CPU tests."""
+    stem = ConvLayer("conv1", image, 3, base, kernel=7, stride=2, padding=3)
+    nodes = [GraphNode("conv1", "conv", (), stem),
+             GraphNode("pool1", "pool", ("conv1",), pool=2, pool_window=3,
+                       pool_pad=1)]
+    prev, size, cin = "pool1", (stem.out_size + 2 - 3) // 2 + 1, base
+    for stage, blocks in enumerate((3, 4, 6, 3)):
+        width = base << stage
+        for i in range(blocks):
+            s = 2 if i == 0 and stage > 0 else 1
+            b = f"s{stage + 2}b{i + 1}"
+            a = ConvLayer(f"{b}a", size, cin, width, kernel=1)
+            m = ConvLayer(f"{b}b", size, width, width, kernel=3, stride=s,
+                          padding=1)
+            c = ConvLayer(f"{b}c", m.out_size, width, 4 * width, kernel=1)
+            nodes += [GraphNode(a.name, "conv", (prev,), a),
+                      GraphNode(m.name, "conv", (a.name,), m),
+                      GraphNode(c.name, "conv", (m.name,), c, relu=False)]
+            short = prev
+            if i == 0:
+                p = ConvLayer(f"{b}p", size, cin, 4 * width, kernel=1,
+                              stride=s)
+                nodes.append(GraphNode(p.name, "conv", (prev,), p,
+                                       relu=False))
+                short = p.name
+            nodes.append(GraphNode(b, "add", (c.name, short), relu=True))
+            prev, size, cin = b, m.out_size, 4 * width
     return nodes
 
 

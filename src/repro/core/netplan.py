@@ -187,15 +187,17 @@ def infer_pools(layers) -> list[tuple[int, int]]:
     return out + [(1, 1)]
 
 
-def pooled_out_size(h_out: int, stride: int, window: int) -> int:
-    """Spatial size after the (stride, window) max pool — the single
-    place the pooled-size rule lives (LayerStep.out_size and the
-    residency decision in NetworkPlan.build both read it).  ``(1, 1)``
-    is the no-pool identity; ``(1, window > 1)`` is a genuine stride-1
-    overlapping pool (a sub-2x boundary like 5 -> 3 via 3x3/s1)."""
+def pooled_out_size(h_out: int, stride: int, window: int,
+                    pad: int = 0) -> int:
+    """Spatial size after the (stride, window) max pool padded by
+    ``pad`` on each side — the single place the pooled-size rule lives
+    (LayerStep.out_size, the residency decisions of NetworkPlan.build
+    and NetworkGraph.build read it).  ``(1, 1)`` is the no-pool
+    identity; ``(1, window > 1)`` is a genuine stride-1 overlapping
+    pool (a sub-2x boundary like 5 -> 3 via 3x3/s1)."""
     if stride == 1 and window == 1:
         return h_out
-    return (h_out - window) // stride + 1
+    return (h_out + 2 * pad - window) // stride + 1
 
 
 def layer_kernel_problem(layer: ConvLayer, *, n: int = 1):
@@ -245,6 +247,18 @@ def layer_route(layer: ConvLayer, *, n: int = 1, dtype: str = "float32"):
     x_shape, _, w_shape, _ = layer_kernel_problem(layer, n=n)
     return conv_route(x_shape, w_shape, stride=layer.stride,
                       groups=layer.groups, dtype=dtype)
+
+
+def mxu_strided(nodes, *, n: int = 1, dtype: str = "float32") -> dict:
+    """``{layer: stride}`` of the convs whose route (:func:`layer_route`)
+    runs strided taps in the MXU kernel: an ``mxu`` route at stride > 1
+    (DESIGN.md §17).  ``nodes`` are a graph's ``GraphNode``s or a chain's
+    ``ConvLayer``s: ``ServingEngine.stats()`` and a graph without an
+    engine topology count by this one rule."""
+    layers = [nd.layer if isinstance(nd, GraphNode) else nd for nd in nodes]
+    return {l.name: l.stride for l in layers
+            if l is not None and l.stride > 1
+            and layer_route(l, n=n, dtype=dtype).kind == "mxu"}
 
 
 # ---------------------------------------------------------------------------
@@ -336,10 +350,10 @@ class LayerStep:
       ``mode="trim"`` halo re-fetch) are not billed.
     * ``resident_out`` — the (pooled) ofmap stays on-chip as the next
       layer's ifmap: its HBM output bytes are not billed.
-    * ``pool`` / ``pool_window`` — max-pooling folded into the epilogue;
-      with ``fold_pooling`` the output bytes billed are the *pooled*
-      activation (the elements the network actually keeps), else the
-      full ofmap the plan writes.
+    * ``pool`` / ``pool_window`` / ``pool_pad`` — max-pooling folded
+      into the epilogue; with ``fold_pooling`` the output bytes billed
+      are the *pooled* activation (the elements the network actually
+      keeps), else the full ofmap the plan writes.
     """
 
     index: int
@@ -351,12 +365,13 @@ class LayerStep:
     resident_in: bool = False
     resident_out: bool = False
     fold_pooling: bool = True
+    pool_pad: int = 0
 
     @property
     def out_size(self) -> int:
         """Spatial size of the (pooled) activation this step hands on."""
         return pooled_out_size(self.plan.h_out, self.pool,
-                               self.pool_window)
+                               self.pool_window, self.pool_pad)
 
     @property
     def out_elements(self) -> int:
@@ -850,16 +865,18 @@ class NetworkGraph:
                             f"{out_size[src]}^2x{channels[src]}")
                 else:
                     sources += 1
-                sz = pooled_out_size(l.out_size, nd.pool, nd.pool_window)
+                sz = pooled_out_size(l.out_size, nd.pool, nd.pool_window,
+                                     nd.pool_pad)
                 chn = l.out_channels
             elif nd.op == "pool":
                 (src,) = nd.inputs
-                if nd.pool_window > out_size[src]:
+                if nd.pool_window > out_size[src] + 2 * nd.pool_pad:
                     raise ValueError(
                         f"pool {nd.name}: window {nd.pool_window} > "
-                        f"input size {out_size[src]}")
+                        f"input size {out_size[src]} padded by "
+                        f"{nd.pool_pad}")
                 sz = pooled_out_size(out_size[src], nd.pool,
-                                     nd.pool_window)
+                                     nd.pool_window, nd.pool_pad)
                 chn = channels[src]
             elif nd.op == "upsample":
                 (src,) = nd.inputs
@@ -942,7 +959,8 @@ class NetworkGraph:
                     index=i, name=nd.name, layer=nd.layer,
                     plan=plans[nd.name], pool=nd.pool,
                     pool_window=nd.pool_window, resident_in=r_in,
-                    resident_out=not spilled, fold_pooling=fold_pooling))
+                    resident_out=not spilled, fold_pooling=fold_pooling,
+                    pool_pad=nd.pool_pad))
             else:
                 steps.append(JoinStep(
                     index=i, name=nd.name, op=nd.op, n=n,

@@ -475,8 +475,10 @@ class ServingEngine:
         name the topology's convs whose route (``ops.conv_route``,
         DESIGN.md §16) folds their taps or runs the depthwise VPU
         kernel; ``fold_strided`` ({layer: stride}) the folded convs
-        whose views are taken by selection, at stride > 1 (§15)."""
-        from repro.core.netplan import layer_route
+        whose views are taken by selection, at stride > 1 (§15);
+        ``mxu_strided`` ({layer: stride}) the convs whose taps the MXU
+        kernel loads with a stride (``netplan.mxu_strided``, §17)."""
+        from repro.core.netplan import layer_route, mxu_strided
         dtype = self.tune_kwargs.get("dtype", "float32")
         layers = {layer.name: layer for layer in self.topo or ()}
         routes = {name: layer_route(layer, dtype=dtype)
@@ -510,6 +512,8 @@ class ServingEngine:
                 "depthwise": {name: r.x_shape[3]
                               for name, r in routes.items()
                               if r.kind == "depthwise"},
+                "mxu_strided": mxu_strided(list(layers.values()),
+                                           dtype=dtype),
             }
 
 # ---------------------------------------------------------------------------

@@ -352,18 +352,20 @@ def conv_route(x_shape, w_shape, *, stride: int, groups: int,
     (:func:`kernel_input_shape`) and ``(K, K, Cin/groups, Cout)``
     weights, in order: ``depthwise`` (the VPU kernel, §14: ``groups ==
     C_in == C_out > 1``, float), ``folded`` (§13: ``groups == 1``,
-    ``K > 1`` and ``K*K*Cin`` fits one lane tile, run as a 1x1 of
-    ``(N, H_out, W_out, K*K*Cin)`` by ``(1, 1, K*K*Cin, Cout)``), ``mxu``
-    (the MXU strip kernel), each for ``K <= MAX_NATIVE_K``, else
-    ``tiled`` (the adder tree)."""
+    ``K > 1`` and ``K*K*Cin`` fits one lane tile, two at stride > 1,
+    run as a 1x1 of ``(N, H_out, W_out, K*K*Cin)`` by ``(1, 1, K*K*Cin,
+    Cout)``), ``mxu`` (the MXU strip kernel; strided taps at stride > 1,
+    §17), each for ``K <= MAX_NATIVE_K``, else ``tiled`` (the adder
+    tree)."""
     n, h, w, cin = x_shape
     k, kw, cin_pg, cout = w_shape
+    fold_lanes = LANES if stride == 1 else 2 * LANES
     if k > MAX_NATIVE_K:
         kind = "tiled"
     elif (groups > 1 and cin_pg == 1 and groups == cin == cout
           and jnp.issubdtype(jnp.dtype(dtype), jnp.floating)):
         kind = "depthwise"
-    elif groups == 1 and k > 1 and k * kw * cin <= LANES:
+    elif groups == 1 and k > 1 and k * kw * cin <= fold_lanes:
         taps = k * kw * cin
         return ConvRoute("folded", (n, (h - k) // stride + 1,
                                     (w - kw) // stride + 1, taps),

@@ -126,6 +126,35 @@ def _tap_matmuls(window, w_ref, *, kh: int, kw: int, stride: int,
     return acc
 
 
+def _strided_tap_matmuls(load, w_ref, *, kh: int, kw: int, stride: int,
+                         th_out: int, w_out: int, n_out: int, chunks: int):
+    """The K x K taps at ``stride > 1``, read by strided ref loads.
+
+    The chip's compiler slices no loaded value with a stride on the
+    sublane axis ("Only 2D gather is supported"), but loads a ref with a
+    stride on any axis whose base buffer is at most one lane tile wide.
+    So the window comes as ``chunks`` refs of at most 128 channels each
+    and ``load(c, rows, cols)`` reads chunk ``c`` of tap ``(ki, kj)``'s
+    rows ``ki + r + s*i`` and columns ``kj + s*j``; the chunks are joined
+    along the lanes into one ``(TH_out * W_out, Cin)`` MXU operand."""
+    s = stride
+    r = (kh - 1) % s
+    integer = jnp.issubdtype(w_ref.dtype, jnp.integer)
+    acc_dtype = jnp.int32 if integer else jnp.float32
+    precision = F32_DOT_PRECISION if w_ref.dtype == jnp.float32 else None
+    acc = jnp.zeros((th_out * w_out, n_out), acc_dtype)
+    for ki in range(kh):
+        for kj in range(kw):
+            rows = pl.ds(ki + r, th_out, stride=s)
+            cols = pl.ds(kj, w_out, stride=s)
+            parts = [load(c, rows, cols) for c in range(chunks)]
+            v = parts[0] if chunks == 1 else jnp.concatenate(parts, axis=-1)
+            acc += jnp.dot(v.reshape(th_out * w_out, -1), w_ref[ki, kj],
+                           precision=precision,
+                           preferred_element_type=acc_dtype)
+    return acc
+
+
 def _epilogue_store(acc, s_ref, b_ref, o_ref, *, th_out: int, w_out: int,
                     activation: str | None):
     """Fused epilogue: (dequant) + bias + activation on the accumulator,
@@ -196,6 +225,85 @@ def _halo_kernel(x_ref, w_ref, *rest, kh: int, kw: int, stride: int,
                        th_out=th_out, w_out=w_out, n_out=o_ref.shape[-1])
     _epilogue_store(acc, s_ref, b_ref, o_ref, th_out=th_out, w_out=w_out,
                     activation=activation)
+
+
+def _strided_carry_kernel(*refs, chunks: int, kh: int, kw: int,
+                          stride: int, tile_h: int, th_out: int, w_out: int,
+                          activation: str | None, has_bias: bool,
+                          has_scale: bool = False):
+    """The carry dataflow at ``stride > 1``: the window is staged in a
+    VMEM scratch (``win_ref``, one slab per channel chunk) so the taps
+    can load it with a stride.  Its top K-1 rows are the shadow
+    registers: at the first cout tile of a strip they take the previous
+    window's last K-1 rows (zeros for the first strip), and the strip's
+    fresh rows go below them."""
+    x_refs, w_ref = refs[:chunks], refs[chunks]
+    rest = refs[chunks + 1:]
+    s_ref = rest[0] if has_scale else None
+    b_ref = rest[has_scale] if has_bias else None
+    o_ref = rest[has_scale + has_bias]
+    if kh > 1:
+        win_ref = rest[has_scale + has_bias + 1]
+        g = pl.program_id(2)
+        co = pl.program_id(3)
+
+        @pl.when(jnp.logical_and(co == 0, g == 0))
+        def _first_strip():
+            win_ref[:, :kh - 1] = jnp.zeros(
+                (chunks, kh - 1) + win_ref.shape[2:], win_ref.dtype)
+
+        @pl.when(jnp.logical_and(co == 0, g > 0))
+        def _shadow_rows():
+            win_ref[:, :kh - 1] = win_ref[:, tile_h:tile_h + kh - 1]
+
+        @pl.when(co == 0)
+        def _fresh_rows():
+            for c in range(chunks):
+                win_ref[c, kh - 1:] = x_refs[c][0]
+
+        def load(c, rows, cols):
+            return win_ref[c, rows, cols, :]
+    else:
+        def load(c, rows, cols):
+            return x_refs[c][0, rows, cols, :]
+
+    acc = _strided_tap_matmuls(load, w_ref, kh=kh, kw=kw, stride=stride,
+                               th_out=th_out, w_out=w_out,
+                               n_out=o_ref.shape[-1], chunks=chunks)
+    _epilogue_store(acc, s_ref, b_ref, o_ref, th_out=th_out, w_out=w_out,
+                    activation=activation)
+
+
+def _strided_halo_kernel(*refs, chunks: int, kh: int, kw: int, stride: int,
+                         th_out: int, w_out: int, activation: str | None,
+                         has_bias: bool, has_scale: bool = False):
+    """The halo dataflow at ``stride > 1``: each chunk's overlapping
+    window is loaded with a stride straight from its input ref."""
+    x_refs, w_ref = refs[:chunks], refs[chunks]
+    rest = refs[chunks + 1:]
+    s_ref = rest[0] if has_scale else None
+    b_ref = rest[has_scale] if has_bias else None
+    (o_ref,) = rest[has_scale + has_bias:]
+    acc = _strided_tap_matmuls(
+        lambda c, rows, cols: x_refs[c][rows, cols, :], w_ref, kh=kh,
+        kw=kw, stride=stride, th_out=th_out, w_out=w_out,
+        n_out=o_ref.shape[-1], chunks=chunks)
+    _epilogue_store(acc, s_ref, b_ref, o_ref, th_out=th_out, w_out=w_out,
+                    activation=activation)
+
+
+def _pad_channels(a, axis: int, groups: int, cin_pg: int, padded: int):
+    """``a`` with each group's ``cin_pg`` channels on ``axis`` padded
+    with zeros to ``padded``."""
+    if padded == cin_pg:
+        return a
+    shape = a.shape[:axis] + (groups, cin_pg) + a.shape[axis + 1:]
+    a = a.reshape(shape)
+    pads = [(0, 0)] * len(shape)
+    pads[axis + 1] = (0, padded - cin_pg)
+    a = jnp.pad(a, pads)
+    return a.reshape(a.shape[:axis] + (groups * padded,)
+                     + a.shape[axis + 2:])
 
 
 def make_plan(x_shape, w_shape, *, stride: int = 1, pad: int = 0,
@@ -302,7 +410,46 @@ def trim_conv2d(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
         wk = w
 
     co_tiles = plan.co_tiles
-    if plan.dataflow == "halo":
+    if plan.stride > 1:
+        # strided taps (DESIGN.md §17): the window in chunks of at most
+        # one lane tile, each chunk a ref of its own
+        lanes, chunks = plan.lane_chunks
+        cin_pad = lanes * chunks
+        z = _pad_channels(z, 3, groups, plan.cin_per_group, cin_pad)
+        wk = _pad_channels(wk, 2, 1, plan.cin_per_group, cin_pad)
+        common = dict(chunks=chunks, kh=plan.kh, kw=plan.kw,
+                      stride=plan.stride, th_out=plan.th_out,
+                      w_out=plan.w_out, activation=activation,
+                      has_bias=bias is not None, has_scale=quantized)
+        if plan.dataflow == "halo":
+            z = jnp.pad(z, ((0, 0), (plan.kh - 1, 0), (0, 0), (0, 0)))
+            th = plan.tile_h
+            block = plan.halo_in_block[:3] + (lanes,)
+            in_specs = [
+                pl.BlockSpec((None,) + tuple(map(pl.Element, block[1:])),
+                             lambda ni, gr, g, co, c=c: (
+                                 ni, g * th, 0,
+                                 (gr * chunks + c) * lanes if groups > 1
+                                 else c * lanes))
+                for c in range(chunks)]
+            kernel = functools.partial(_strided_halo_kernel, **common)
+            scratch_shapes = []
+        else:
+            in_specs = [
+                pl.BlockSpec(plan.in_block[:3] + (lanes,),
+                             lambda ni, gr, g, co, c=c: (
+                                 ni, g, 0, gr * chunks + c))
+                for c in range(chunks)]
+            kernel = functools.partial(_strided_carry_kernel,
+                                       tile_h=plan.tile_h, **common)
+            scratch_shapes = [] if plan.kh == 1 else [pltpu.VMEM(
+                (chunks, plan.tile_h + plan.kh - 1, plan.wp, lanes),
+                x.dtype)]
+        in_specs.append(pl.BlockSpec(
+            plan.w_block[:2] + (cin_pad, plan.tile_cout),
+            lambda ni, gr, g, co: (0, 0, 0, gr * co_tiles + co)))
+        inputs = [z] * chunks + [wk]
+    elif plan.dataflow == "halo":
         # Overlapping strip windows (element-indexed row axis):
         # strip g reads rows [g*TH, g*TH + TH + K-1) of the halo-padded
         # input, whose K-1 extra top zero rows are this strip-level image
@@ -335,10 +482,12 @@ def trim_conv2d(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
             has_scale=quantized)
         scratch_shapes = [pltpu.VMEM(plan.carry_shape, x.dtype)]
 
-    # stationary weight tile of this group's cout block
-    in_specs.append(pl.BlockSpec(
-        plan.w_block, lambda ni, gr, g, co: (0, 0, 0, gr * co_tiles + co)))
-    inputs = [z, wk]
+    if plan.stride == 1:
+        # stationary weight tile of this group's cout block
+        in_specs.append(pl.BlockSpec(
+            plan.w_block,
+            lambda ni, gr, g, co: (0, 0, 0, gr * co_tiles + co)))
+        inputs = [z, wk]
 
     def _cout_row(v):
         """Pad a per-out-channel row (bias / dequant scale) to the plan's

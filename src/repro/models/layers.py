@@ -391,11 +391,15 @@ def _pack_convs(p: dict, named_layers, *, n: int) -> dict:
     return packed
 
 
-def _maxpool(x: jax.Array, stride: int, window: int) -> jax.Array:
-    """Max pooling (VGG 2x2/s2, AlexNet overlapping 3x3/s2)."""
+def _maxpool(x: jax.Array, stride: int, window: int,
+             pad: int = 0) -> jax.Array:
+    """Max pooling (VGG 2x2/s2, AlexNet overlapping 3x3/s2; ResNet's
+    3x3/s2 padded by ``pad`` = 1 with -inf, as PyTorch's ``MaxPool2d``
+    pads)."""
     return jax.lax.reduce_window(
         x, -jnp.inf, jax.lax.max, (1, window, window, 1),
-        (1, stride, stride, 1), "VALID")
+        (1, stride, stride, 1),
+        ((0, 0), (pad, pad), (pad, pad), (0, 0)) if pad else "VALID")
 
 
 def _cnn_apply_layer_range(p: dict, layers_list, pools, x: jax.Array,
@@ -563,10 +567,10 @@ def _graph_conv_node(p: dict, nd, x: jax.Array, *, activation, impl,
     l = nd.layer
     _, _, _, padding = layer_kernel_problem(l, n=x.shape[0])
     y = conv2d_apply(p[nd.name], x, stride=l.stride, padding=padding,
-                     groups=l.groups, activation=activation, impl=impl,
-                     mesh=mesh, rules=rules, layer=l.name)
+                     groups=l.groups, activation=nd.activation(activation),
+                     impl=impl, mesh=mesh, rules=rules, layer=l.name)
     if nd.pool > 1 or nd.pool_window > 1:
-        y = _maxpool(y, nd.pool, nd.pool_window)
+        y = _maxpool(y, nd.pool, nd.pool_window, nd.pool_pad)
     return y
 
 
@@ -592,7 +596,14 @@ def cnn_apply_from_graph(p: dict, graph, x: jax.Array, *,
     per-node execution are bit-identical (tested).  Pass ``fuse_plan``
     (a prebuilt :class:`~repro.core.fuse_plan.GraphFusePlan`) to reuse
     tuned segment plans.  The fused path needs raw conv params and is
-    single-device."""
+    single-device; it runs every conv of a segment with ``activation``
+    and unpadded pools, so a segment holding a node with ``relu`` or
+    ``pool_pad`` set is a ``ValueError``, never run another way.
+
+    A node's ``relu`` (``GraphNode.activation``) departs from the
+    defaults: a conv with ``relu=False`` runs without ``activation``,
+    an add with ``relu=True`` is followed by a ReLU; a pool's
+    ``pool_pad`` pads it with -inf."""
     from repro.core.netplan import graph_nodes
     nodes = graph_nodes(graph)
     by = {nd.name: nd for nd in nodes}
@@ -608,6 +619,14 @@ def cnn_apply_from_graph(p: dict, graph, x: jax.Array, *,
             from repro.core.fuse_plan import graph_segments
             segs = [(names, None) for names, _ in graph_segments(nodes)]
         for names, plan in segs:
+            odd = [nm for nm in names
+                   if by[nm].relu is not None or by[nm].pool_pad]
+            if odd:
+                raise ValueError(
+                    f"fused execution runs every conv of a segment with "
+                    f"activation={activation!r} and unpadded pools; "
+                    f"nodes {odd} set relu or pool_pad — run this graph "
+                    "per node (fused=False)")
             seg_of[names[0]] = (names, plan)
 
     outs: dict[str, jax.Array] = {}
@@ -639,11 +658,14 @@ def cnn_apply_from_graph(p: dict, graph, x: jax.Array, *,
             y = _graph_conv_node(p, nd, xin, activation=activation,
                                  impl=impl, mesh=mesh, rules=rules)
         elif nd.op == "pool":
-            y = _maxpool(outs[nd.inputs[0]], nd.pool, nd.pool_window)
+            y = _maxpool(outs[nd.inputs[0]], nd.pool, nd.pool_window,
+                         nd.pool_pad)
         elif nd.op == "add":
             y = outs[nd.inputs[0]]
             for s in nd.inputs[1:]:
                 y = y + outs[s]
+            if nd.relu:
+                y = jnp.maximum(y, 0.0)
         elif nd.op == "concat":
             y = jnp.concatenate([outs[s] for s in nd.inputs], axis=-1)
         else:                                     # upsample

@@ -2,9 +2,9 @@
 that decides which kernel runs a conv, and the tuner writes exactly the
 autotune keys the forward reads.
 
-* the kind of every conv of the benchmark's three networks as the
-  program builds them (VGG-16, U-Net 512²/64/depth 4, MobileNetV1 224²)
-  and of AlexNet's K = 11 conv1;
+* the kind of every conv of the benchmark's four networks as the
+  program builds them (VGG-16, U-Net 512²/64/depth 4, MobileNetV1 224²,
+  ResNet-50 v1.5 224²) and of AlexNet's K = 11 conv1;
 * per network, at batch 8: the keys ``tune_network`` / ``tune_graph``
   write equal the keys the forward looks up while it is traced.
 """
@@ -16,23 +16,28 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import autotune
-from repro.core.model import mobilenet_v1_layers, unet_graph
+from repro.core.model import mobilenet_v1_layers, resnet50_graph, \
+    unet_graph
 from repro.core.netplan import layer_kernel_problem, network_layers
 from repro.kernels import ops
 
 UNET = unet_graph(512, 64, 1, 2, 4)
+RESNET50 = resnet50_graph()
 
 NETWORKS = {
     "vgg16": network_layers("vgg16"),
     "unet": [nd.layer for nd in UNET if nd.op == "conv"],
     "mobilenetv1": mobilenet_v1_layers(),
+    "resnet50": [nd.layer for nd in RESNET50 if nd.op == "conv"],
     "alexnet": network_layers("alexnet")[:1],
 }
 
 # the kinds stated by hand; every other conv of the table runs on the
-# MXU strip kernel
+# MXU strip kernel (ResNet-50's 3x3/s2 convs and 1x1/s2 projections with
+# strided taps); ResNet-50's 7x7/s2 stem folds into two lane tiles
 KINDS = {("vgg16", "conv1"): "folded", ("unet", "enc0a"): "folded",
          ("mobilenetv1", "conv1"): "folded", ("alexnet", "conv1"): "tiled",
+         ("resnet50", "conv1"): "folded",
          **{("mobilenetv1", f"dw{i}"): "depthwise" for i in range(1, 14)}}
 
 CASES = [(net, layer.name) for net, layers in NETWORKS.items()
@@ -73,17 +78,20 @@ def _forward(net):
     """``(tune(n), fwd(p, x), params)`` of one network as the program
     builds and tunes it."""
     from repro.models import layers as mlayers
-    if net == "unet":
-        return (lambda n: autotune.tune_graph(UNET, n=n),
-                lambda p, x: mlayers.cnn_apply_from_graph(p, UNET, x),
-                mlayers.cnn_params_from_graph(UNET))
+    graphs = {"unet": UNET, "resnet50": RESNET50}
+    if net in graphs:
+        g = graphs[net]
+        return (lambda n: autotune.tune_graph(g, n=n),
+                lambda p, x: mlayers.cnn_apply_from_graph(p, g, x),
+                mlayers.cnn_params_from_graph(g))
     topo = NETWORKS[net]
     return (lambda n: autotune.tune_network(topo, n=n),
             lambda p, x: mlayers.cnn_apply_from_layers(p, topo, x),
             mlayers.cnn_params_from_layers(topo))
 
 
-@pytest.mark.parametrize("net", ["vgg16", "unet", "mobilenetv1"])
+@pytest.mark.parametrize("net", ["vgg16", "unet", "mobilenetv1",
+                                 "resnet50"])
 def test_tuned_keys_are_the_keys_the_forward_reads(monkeypatch, net):
     """At batch 8, the set of keys the tuner writes equals the set the
     traced forward looks up: no record is written where no conv reads

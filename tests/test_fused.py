@@ -35,8 +35,9 @@ def _close(a, b, tol=1e-5):
 # count: XLA:CPU (interpret mode) picks shape-dependent dot algorithms
 # for narrow outputs, so the chains that fuse past their first conv
 # emit 64 channels, where its results are row-stable.  Every conv takes
-# more than one lane tile of folded taps (K*K*C_in > 128): a conv whose
-# taps fold runs per layer, folded, and never fuses (DESIGN.md §13).
+# more folded taps than the fold rule admits (K*K*C_in > 128, > 256 at
+# stride 2): a conv whose taps fold runs per layer, folded, and never
+# fuses (DESIGN.md §13).
 def _chain_same():
     return [ConvLayer("c0", 12, 16, 16, 3, 1, 1),
             ConvLayer("c1", 12, 16, 16, 3, 1, 1),   # pool 2/2 -> 6
@@ -44,7 +45,7 @@ def _chain_same():
 
 
 def _chain_strided():
-    return [ConvLayer("s0", 17, 8, 64, 5, 2, 0),    # valid -> 7, pool 2/3
+    return [ConvLayer("s0", 17, 16, 64, 5, 2, 0),   # valid -> 7, pool 2/3
             ConvLayer("s1", 3, 64, 64, 1, 1, 0),    # pointwise
             ConvLayer("s2", 3, 64, 64, 3, 1, 1)]
 

@@ -7,8 +7,9 @@ These tests compile, for one chip of a ``v5e:2x2`` topology described
 without the chip, the kernels of the VGG-16 serving path at published
 widths (with the default and the tuner-chosen plans), the tap-folded
 first convs of VGG-16 and U-Net, MobileNetV1's depthwise convs on the
-VPU kernel, the weight-grad kernel and the int8 kernel.  Nothing runs:
-they prove compilation, not results or times.
+VPU kernel, ResNet-50's stride-2 convs (strided taps), the weight-grad
+kernel and the int8 kernel.  Nothing runs: they prove compilation, not
+results or times.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and pytest-xdist workers import every
@@ -44,6 +45,21 @@ def topo():
 def one_chip(topo):
     from jax.sharding import SingleDeviceSharding
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def native(monkeypatch):
+    """Kernels lowered natively (``runtime.on_tpu`` is True) on a clean
+    trace cache.  The jitted kernels take ``interpret=None`` and resolve
+    it while they are traced, so a problem an earlier test of the same
+    process traced on the CPU would come back from JAX's cache as an
+    interpreted kernel (no ``tpu_custom_call`` in the program); the
+    caches are cleared before the test and after it."""
+    from repro.kernels import runtime
+    monkeypatch.setattr(runtime, "on_tpu", lambda: True)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
 
 
 def _compile(fn, *args):
@@ -109,13 +125,11 @@ def test_q8_conv_compiles(one_chip):
 
 
 @pytest.mark.parametrize("dataflow", ["carry", "halo"])
-def test_conv_kernel_carries_its_layer_name(one_chip, monkeypatch,
-                                            dataflow):
+def test_conv_kernel_carries_its_layer_name(one_chip, native, dataflow):
     """``ops.conv2d(layer=...)`` lowered for the chip names its Pallas
     kernel after the layer: the compiled custom call is ``%<layer>.<n>``,
     the text a profiler trace shows for the kernel."""
-    from repro.kernels import ops, runtime
-    monkeypatch.setattr(runtime, "on_tpu", lambda: True)
+    from repro.kernels import ops
     sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,  # noqa
                                              sharding=one_chip)
     layer = f"enc0{dataflow}"
@@ -144,16 +158,15 @@ FOLDED = {
 
 @pytest.mark.parametrize("case", sorted(FOLDED))
 @pytest.mark.parametrize("plan", ["default", "tuned"])
-def test_folded_first_conv_compiles(one_chip, monkeypatch, pallas_calls,
-                                    case, plan):
+def test_folded_first_conv_compiles(one_chip, native, pallas_calls, case,
+                                    plan):
     """The tap-folded first conv (XLA im2col, then the 1x1 TrIM kernel)
     compiles at published size with the default and the tuner-chosen
     plan, and its one kernel keeps the layer's name.  At stride 2 no
     slice of the compiled program has a stride on the last (lane) axis:
     the views are selection contractions."""
     from repro.core.model import ConvLayer
-    from repro.kernels import ops, runtime
-    monkeypatch.setattr(runtime, "on_tpu", lambda: True)
+    from repro.kernels import ops
     layer, n, hw, cin, cout, stride = FOLDED[case]
     if plan == "tuned":
         autotune.tune_network([ConvLayer(layer, hw, cin, cout, kernel=3,
@@ -188,15 +201,14 @@ DEPTHWISE = {
 
 @pytest.mark.parametrize("case", sorted(DEPTHWISE))
 @pytest.mark.parametrize("plan", ["default", "tuned"])
-def test_depthwise_conv_compiles(one_chip, monkeypatch, pallas_calls, case,
+def test_depthwise_conv_compiles(one_chip, native, pallas_calls, case,
                                  plan):
     """A MobileNetV1 depthwise conv through ``ops.conv2d`` compiles for
     the chip on the VPU kernel, strided loads and all, with the default
     and the tuner-chosen plan: one kernel, named after its layer, and
     no XLA convolution."""
     from repro.core.model import ConvLayer
-    from repro.kernels import ops, runtime
-    monkeypatch.setattr(runtime, "on_tpu", lambda: True)
+    from repro.kernels import ops
     layer, n, hw, c, stride = DEPTHWISE[case]
     if plan == "tuned":
         autotune.tune_network([ConvLayer(layer, hw, c, c, kernel=3,
@@ -237,12 +249,102 @@ def test_fused_vgg16_group_compiles(one_chip):
         x, ws, bs, group=group, interpret=False), x, ws, bs)
 
 
-@pytest.mark.xfail(strict=True, raises=NotImplementedError,
-                   reason="Mosaic: 'Only 2D gather is supported' (strided "
-                          "tap slices, trim_conv2d._tap_matmuls)")
 def test_stride2_7x7_stem_compiles(one_chip):
     x = jax.ShapeDtypeStruct((1, 229, 229, 3), jnp.float32,
                              sharding=one_chip)
     w = jax.ShapeDtypeStruct((7, 7, 3, 64), jnp.float32, sharding=one_chip)
     _compile(lambda x, w: trim_conv2d(x, w, stride=2, interpret=False),
              x, w)
+
+
+# ResNet-50 v1.5's seven stride-2 convs at the benchmark's batch, as
+# their layers state them: (layer, batch, size, C_in, C_out, K, pad)
+STRIDED = {
+    "conv1": ("conv1", 128, 224, 3, 64, 7, 3),
+    "s3b1b": ("s3b1b", 128, 56, 128, 128, 3, 1),
+    "s4b1b": ("s4b1b", 128, 28, 256, 256, 3, 1),
+    "s5b1b": ("s5b1b", 128, 14, 512, 512, 3, 1),
+    "s3b1p": ("s3b1p", 128, 56, 256, 512, 1, 0),
+    "s4b1p": ("s4b1p", 128, 28, 512, 1024, 1, 0),
+    "s5b1p": ("s5b1p", 128, 14, 1024, 2048, 1, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STRIDED))
+@pytest.mark.parametrize("plan", ["default", "tuned"])
+def test_resnet50_strided_conv_compiles(one_chip, native, case, plan):
+    """Each stride-2 conv of ResNet-50 through ``ops.conv2d`` compiles
+    for the chip on the route ``ops.conv_route`` gives it, with the
+    default and the tuner-chosen plan: one kernel, named after its
+    layer, and no gather.  The six on the ``mxu`` route (strided taps)
+    have no XLA convolution; the stem folds (147 lanes, two lane tiles
+    at stride 2), and its only two are the selection contractions of
+    its views."""
+    from repro.core.model import ConvLayer
+    from repro.core.netplan import layer_route
+    from repro.kernels import ops
+    layer, n, hw, cin, cout, k, pad = STRIDED[case]
+    kind = layer_route(ConvLayer(layer, hw, cin, cout, kernel=k, stride=2,
+                                 padding=pad), n=n).kind
+    assert kind == ("folded" if layer == "conv1" else "mxu")
+    if plan == "tuned":
+        autotune.tune_network([ConvLayer(layer, hw, cin, cout, kernel=k,
+                                         stride=2, padding=pad)], n=n)
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,  # noqa
+                                             sharding=one_chip)
+
+    def fwd(x, w, b):
+        return ops.conv2d(x, w, stride=2, padding=pad, bias=b,
+                          activation="relu", layer=layer)
+
+    text = jax.jit(fwd).lower(sds((n, hw, hw, cin)), sds((k, k, cin, cout)),
+                              sds((cout,))).compile().as_text()
+    kernels = [ln.split("=", 1)[0].strip() for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(kernels) == 1 and kernels[0].startswith(f"%{layer}."), \
+        kernels
+    assert text.count(" convolution(") == (2 if kind == "folded" else 0)
+    assert "gather(" not in text
+
+
+@pytest.mark.parametrize("k,hw,cin,cout", [(1, 56, 256, 512),
+                                           (3, 58, 128, 128),
+                                           (7, 230, 3, 64)])
+@pytest.mark.parametrize("dataflow", ["carry", "halo"])
+def test_stride2_taps_compile(one_chip, k, hw, cin, cout, dataflow):
+    """The MXU kernel's strided taps on both dataflows at K = 1, 3, 7:
+    the window is read by strided ref loads, which the chip's compiler
+    takes where it refuses a strided slice of a loaded value."""
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,  # noqa
+                                             sharding=one_chip)
+    _compile(lambda x, w, b: trim_conv2d(x, w, b, stride=2,
+                                         activation="relu",
+                                         dataflow=dataflow,
+                                         interpret=False),
+             sds((8, hw, hw, cin)), sds((k, k, cin, cout)), sds((cout,)))
+
+
+def test_resnet50_kernels_carry_their_layer_names(one_chip, native):
+    """ResNet-50 v1.5 at published widths, tuned and compiled for the
+    chip as one program: 53 Pallas kernels, one per conv, each named
+    after its layer (what a profiler trace reads); its only XLA
+    convolutions are the folded stem's two selection contractions."""
+    from repro.core.model import resnet50_graph
+    from repro.models import layers as mlayers
+    from repro.models.base import init_params
+    nodes = resnet50_graph()
+    autotune.tune_graph(nodes, n=8)
+    decls = mlayers.cnn_params_from_graph(nodes, n_classes=1000)
+    p = jax.eval_shape(lambda: init_params(decls, jax.random.PRNGKey(0)))
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                    sharding=one_chip), p)
+    x = jax.ShapeDtypeStruct((8, 224, 224, 3), jnp.float32,
+                             sharding=one_chip)
+    text = jax.jit(lambda p, x: mlayers.cnn_apply_from_graph(
+        p, nodes, x)).lower(p, x).compile().as_text()
+    kernels = [ln.split("=", 1)[0].strip().lstrip("%").rsplit(".", 1)[0]
+               for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sorted(kernels) == sorted(nd.name for nd in nodes
+                                     if nd.op == "conv")
+    assert text.count(" convolution(") == 2
